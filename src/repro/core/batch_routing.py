@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bm25
 from repro.core.dataset import Server
 from repro.core.qos import (
     QosParams,
@@ -249,7 +250,7 @@ def _route_pipeline(
     if use_kernels:
         s_scores = ops.bm25_scores(q_server, w_server, interpret=interpret)
     else:
-        s_scores = q_server @ w_server.T
+        s_scores = bm25.bm25_scores(w_server, q_server)
     # SONAR-FT: demote known-failed servers below every live one before
     # the top-s, so failover escapes an all-dead candidate set (mirrors
     # the scalar `_candidates` masking; NEG ties re-fill in index order)
@@ -269,9 +270,9 @@ def _route_pipeline(
     # materializes the [n_q, n_tools] matrices — the kernel path streams
     # them stripe-by-stripe inside `ops.fused_score_select` below --
     if not use_kernels:
-        t_scores = q_tool @ w_tool.T
+        t_scores = bm25.bm25_scores(w_tool, q_tool)
         sel = jnp.where(in_cand, t_scores, NEG)
-        val = (q_rerank @ w_tool.T) if rerank else sel
+        val = bm25.bm25_scores(w_tool, q_rerank) if rerank else sel
 
     # -- QoS N per tool (Eq. 6-7): Pallas kernel over the telemetry matrix --
     if use_network and latency_hist is not None:
@@ -670,6 +671,66 @@ class BatchRoutingEngine:
                 expertise=z, network=z, fused=z,
                 select_latency_ms=self.select_latency_ms(),
             )
+        operands, statics = self._pipeline_args(
+            batch, latency_hist, server_load, telemetry_age_s, failed_mask,
+            client_rtt_ms, client_region, region_rtt_ms, affinity,
+        )
+        if self.adapt_state is not None and self.adapt_cfg.lr != 0.0:
+            # fused update + route: one program, no extra dispatch.  At
+            # lr == 0 we fall through to the static path below, whose
+            # compiled program is byte-identical to the hand-tuned
+            # variant's (the weights can never leave their init).
+            fb_r, fb_f, fb_v = self._drain_feedback()
+            with obs_trace.annotate("netmcp.route_adaptive"):
+                server_idx, tool_idx, c, n, s, self.adapt_state = (
+                    _route_adaptive(
+                        self.adapt_state, fb_r, fb_f, fb_v, *operands,
+                        acfg=self.adapt_cfg, **statics,
+                    )
+                )
+        else:
+            with obs_trace.annotate("netmcp.route_pipeline"):
+                server_idx, tool_idx, c, n, s = _route_pipeline(
+                    *operands, **statics,
+                )
+        if route_stats is not None:
+            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
+        return BatchDecisions(
+            server_idx=np.asarray(server_idx),
+            tool_idx=np.asarray(tool_idx),
+            expertise=np.asarray(c),
+            network=np.asarray(n),
+            fused=np.asarray(s),
+            select_latency_ms=self.select_latency_ms(),
+        )
+
+    def lower(self, batch: EncodedBatch, *args, **kw) -> jax.stages.Lowered:
+        """The program `route` would run on these inputs (same arguments,
+        without ``route_stats``/``n_real``), lowered but not run.  Its
+        ``.compile().as_text()`` names every Pallas kernel compiled into
+        the route (``tpu_custom_call`` on a TPU)."""
+        operands, statics = self._pipeline_args(batch, *args, **kw)
+        if self.adapt_state is not None and self.adapt_cfg.lr != 0.0:
+            fb = _adaptive.pad_feedback([], [], _adaptive.FEEDBACK_BUCKET)
+            return _route_adaptive.lower(
+                self.adapt_state, *fb, *operands, acfg=self.adapt_cfg,
+                **statics,
+            )
+        return _route_pipeline.lower(*operands, **statics)
+
+    def _pipeline_args(
+        self,
+        batch: EncodedBatch,
+        latency_hist=None,
+        server_load=None,
+        telemetry_age_s=None,
+        failed_mask=None,
+        client_rtt_ms=None,
+        client_region=None,
+        region_rtt_ms=None,
+        affinity=None,
+    ) -> tuple:
+        """(operands, static kwargs) of the jit pipeline for one call."""
         lat = None
         if self.uses_network and latency_hist is not None:
             lat = jnp.asarray(latency_hist, jnp.float32)
@@ -733,34 +794,7 @@ class BatchRoutingEngine:
             reg_rtt,
             aff,
         )
-        if self.adapt_state is not None and self.adapt_cfg.lr != 0.0:
-            # fused update + route: one program, no extra dispatch.  At
-            # lr == 0 we fall through to the static path below, whose
-            # compiled program is byte-identical to the hand-tuned
-            # variant's (the weights can never leave their init).
-            fb_r, fb_f, fb_v = self._drain_feedback()
-            with obs_trace.annotate("netmcp.route_adaptive"):
-                server_idx, tool_idx, c, n, s, self.adapt_state = (
-                    _route_adaptive(
-                        self.adapt_state, fb_r, fb_f, fb_v, *operands,
-                        acfg=self.adapt_cfg, **statics,
-                    )
-                )
-        else:
-            with obs_trace.annotate("netmcp.route_pipeline"):
-                server_idx, tool_idx, c, n, s = _route_pipeline(
-                    *operands, **statics,
-                )
-        if route_stats is not None:
-            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
-        return BatchDecisions(
-            server_idx=np.asarray(server_idx),
-            tool_idx=np.asarray(tool_idx),
-            expertise=np.asarray(c),
-            network=np.asarray(n),
-            fused=np.asarray(s),
-            select_latency_ms=self.select_latency_ms(),
-        )
+        return operands, statics
 
     def route_texts(
         self,

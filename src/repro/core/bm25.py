@@ -126,6 +126,12 @@ def build_corpus_tiled(
     return Bm25Corpus(vocab=vocab, weights=weights, n_docs=int(n_docs))
 
 
+# Every routing matmul has f32 operands.  A TPU's default f32 matmul is a
+# single bf16 pass, which rounds the corpus weights and can flip an argmax
+# against the f32 scalar reference; HIGHEST keeps them exact (CPU ignores it).
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def bm25_scores(weights: jnp.ndarray, qcounts: jnp.ndarray) -> jnp.ndarray:
     """Score queries against the corpus: [n_docs, V] x [n_q, V] -> [n_q, n_docs].
 
@@ -133,7 +139,10 @@ def bm25_scores(weights: jnp.ndarray, qcounts: jnp.ndarray) -> jnp.ndarray:
     the standard query-side BM25 (count clipped at 1 works for short queries;
     we keep raw counts to match rank-bm25 behaviour for repeated terms).
     """
-    return qcounts.astype(jnp.float32) @ weights.astype(jnp.float32).T
+    return jnp.matmul(
+        qcounts.astype(jnp.float32), weights.astype(jnp.float32).T,
+        precision=HIGHEST,
+    )
 
 
 def topk(scores: jnp.ndarray, k: int):

@@ -65,8 +65,8 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
 from repro.core import adaptive as _adaptive
 from repro.core import bm25, quantize
@@ -322,7 +322,7 @@ class _StaticCfg(NamedTuple):
 def _bm25_2d(q: jax.Array, w: jax.Array, sc: _StaticCfg) -> jax.Array:
     if sc.use_kernels:
         return ops.bm25_scores(q, w, interpret=sc.interpret)
-    return q @ w.T
+    return bm25.bm25_scores(w, q)
 
 
 def _qos_2d(lat: jax.Array, sc: _StaticCfg) -> jax.Array:
@@ -345,7 +345,8 @@ def _stage1_stacked(d: dict, sc: _StaticCfg) -> tuple:
             s = _bm25_2d(d["q_server"], w.reshape(J * S, V), sc)
             s = s.reshape(-1, J, S).transpose(1, 0, 2)
         else:
-            s = jnp.einsum("qv,jsv->jqs", d["q_server"], w)
+            s = jnp.einsum("qv,jsv->jqs", d["q_server"], w,
+                           precision=bm25.HIGHEST)
     if sc.use_failover and "dead" in d:
         s = jnp.where(d["dead"] > 0.0, NEG, s)           # [J, B, s_pad] bcast
     s = jnp.where(d["server_valid"][:, None, :], s, PAD_NEG)
@@ -373,7 +374,8 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
             t = _bm25_2d(d["q_tool"], w.reshape(J * T, V), sc)
             t = t.reshape(-1, J, T).transpose(1, 0, 2)
         else:
-            t = jnp.einsum("qv,jtv->jqt", d["q_tool"], w)
+            t = jnp.einsum("qv,jtv->jqt", d["q_tool"], w,
+                           precision=bm25.HIGHEST)
     J, n_q, t_pad = t.shape
 
     in_cand = jnp.any(
@@ -393,7 +395,8 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
                 d["q_rerank"], w.reshape(J * t_pad, -1), sc
             ).reshape(-1, J, t_pad).transpose(1, 0, 2)
         else:
-            val_full = jnp.einsum("qv,jtv->jqt", d["q_rerank"], d["w_tool"])
+            val_full = jnp.einsum("qv,jtv->jqt", d["q_rerank"], d["w_tool"],
+                                  precision=bm25.HIGHEST)
     else:
         val_full = sel
 
@@ -483,7 +486,7 @@ def _gflat(x: jax.Array) -> jax.Array:
 
 def _stage2_compact(
     d: dict, t_full: jax.Array, v_full, nt, cand_gids: jax.Array,
-    sc: _StaticCfg,
+    sc: _StaticCfg, kmesh: Optional[Mesh] = None,
 ) -> tuple:
     """Candidate-compacted stage 2 for tiled mega fleets.
 
@@ -510,6 +513,7 @@ def _stage2_compact(
     Returns eight flattened [n_q, W] arrays (sel, val, qos, load, rtt,
     dead, aff, gid) with ``W = top_s_eff * k_slot`` (padded up to the
     final top-k width so the merge semantics match the full path).
+    ``kmesh`` is the mesh the QoS kernel call is replicated over.
     """
     n_q = t_full.shape[0]
     m_docs = t_full.shape[1]
@@ -546,6 +550,7 @@ def _stage2_compact(
         ).reshape(n_q, W)
 
     net_active = sc.use_network and (nt is not None or "lat" in d)
+    qos_fn = functools.partial(_qos_2d, sc=sc)
     if net_active:
         if nt is not None:                                 # template QoS
             tmf = d["tel_map"].reshape(-1)                 # [J*s_pad]
@@ -556,11 +561,13 @@ def _stage2_compact(
             rows = jnp.take_along_axis(
                 flat, cand[:, :, None], axis=1
             )                                              # [n_q, S, T]
-            qos_s = _qos_2d(rows.reshape(n_q * S, T), sc).reshape(n_q, S)
+            qos_s = _replicated(qos_fn, kmesh)(
+                rows.reshape(n_q * S, T)
+            ).reshape(n_q, S)
         else:                                              # shared snapshot
             J, Sp, T = d["lat"].shape
             rows = d["lat"].reshape(J * Sp, T)[cand.reshape(-1)]
-            qos_s = _qos_2d(rows, sc).reshape(n_q, S)
+            qos_s = _replicated(qos_fn, kmesh)(rows).reshape(n_q, S)
         if sc.use_staleness and "age" in d:
             qos_s = qos_s * staleness_discount(gath(d["age"]), sc.stale_half_life)
         qos = expand(qos_s)
@@ -648,10 +655,21 @@ def _run_stage(fn, mesh: Optional[Mesh], arrays, layouts, n_out: int):
     out_spec = logical_to_spec(
         ("shard", None, None), (mesh.devices.size, 1, 1), mesh, FLEET_RULES
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=_specs_for(mesh, layouts, arrays),
-        out_specs=tuple([out_spec] * n_out), check_rep=False,
+        out_specs=tuple([out_spec] * n_out), check_vma=False,
     )(*arrays)
+
+
+def _replicated(fn, mesh: Optional[Mesh]):
+    """``fn`` run once per device on replicated operands.  XLA cannot
+    partition a Mosaic kernel, so on a real mesh each kernel call outside
+    the per-shard stages goes through a fully replicated shard_map (every
+    device computes the same small result); without a mesh, ``fn`` as is."""
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def _flatten_shards(x: jax.Array) -> jax.Array:
@@ -683,19 +701,23 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
     # upcast to f32 is exact (bf16 ⊂ f32), so scoring matches scoring the
     # rounded-f32 weights bit-for-bit.  All accumulation stays f32.
     compact2 = sc.compact2 and "tool_doc_map" in dyn
+    # kernel calls outside the per-shard stages need a replicated
+    # shard_map on a real mesh (the jnp path keeps XLA's own partitioning)
+    kmesh = mesh if sc.use_kernels else None
+    bm25_fn = _replicated(functools.partial(_bm25_2d, sc=sc), kmesh)
     pre: dict = {}
     t_full = v_full = nt = None
     if "server_doc_map" in dyn:
         w_server_t = dyn["w_server_t"].astype(jnp.float32)
-        s_full = _bm25_2d(dyn["q_server"], w_server_t, sc)
+        s_full = bm25_fn(dyn["q_server"], w_server_t)
         pre["s_pre"] = jnp.transpose(
             jnp.take(s_full, dyn["server_doc_map"], axis=1), (1, 0, 2)
         )
     if "tool_doc_map" in dyn:
         w_tool_t = dyn["w_tool_t"].astype(jnp.float32)
-        t_full = _bm25_2d(dyn["q_tool"], w_tool_t, sc)
+        t_full = bm25_fn(dyn["q_tool"], w_tool_t)
         if sc.rerank:
-            v_full = _bm25_2d(dyn["q_rerank"], w_tool_t, sc)
+            v_full = bm25_fn(dyn["q_rerank"], w_tool_t)
         if not compact2:
             pre["t_pre"] = jnp.transpose(
                 jnp.take(t_full, dyn["tool_doc_map"], axis=1), (1, 0, 2)
@@ -705,7 +727,9 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
                     jnp.take(v_full, dyn["tool_doc_map"], axis=1), (1, 0, 2)
                 )
     if "lat_t" in dyn:
-        nt = _qos_2d(dyn["lat_t"].astype(jnp.float32), sc)  # [M_t]
+        nt = _replicated(functools.partial(_qos_2d, sc=sc), kmesh)(
+            dyn["lat_t"].astype(jnp.float32)
+        )                                                     # [M_t]
         if not compact2:
             pre["qos_pre"] = jnp.transpose(
                 jnp.take(nt[None, :], dyn["tel_map"], axis=1), (1, 0, 2)
@@ -743,7 +767,7 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
         # gather or top-k anywhere (see _stage2_compact for the parity
         # argument).  Runs outside shard_map, like the merges.
         sel, val, qos, load, rtt, dead, aff, gid = _stage2_compact(
-            dyn, t_full, v_full, nt, cand_gids, sc
+            dyn, t_full, v_full, nt, cand_gids, sc, kmesh
         )
     else:
         layout2, specs2 = [], []
@@ -840,13 +864,24 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
 
     k_final = min(sc.top_k, sc.n_tools)
     if sc.use_kernels:
-        pos, c, n, s = ops.fused_select(
-            sel, val, qos, load, dead_arg,
-            k=k_final, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
-            tool_rtt=rtt, delta=eff_delta,
-            tool_aff=aff_arg, eps=eff_eps,
-            temp=sc.temp, interpret=sc.interpret,
-        )
+        # static weights stay Python floats (constant-folded kernel);
+        # traced ones (SONAR-ADAPT) enter the shard_map as operands
+        weights = dict(alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
+                       delta=eff_delta)
+        traced = {k: v for k, v in weights.items()
+                  if isinstance(v, jax.Array)}
+
+        def tail(arrs, w):
+            return ops.fused_select(
+                arrs["sel"], arrs["val"], arrs["qos"], arrs["load"],
+                arrs["dead"], k=k_final, tool_rtt=arrs["rtt"],
+                tool_aff=arrs["aff"], eps=eff_eps, temp=sc.temp,
+                interpret=sc.interpret, **{**weights, **w},
+            )
+
+        arrs = dict(sel=sel, val=val, qos=qos, load=load, dead=dead_arg,
+                    rtt=rtt, aff=aff_arg)
+        pos, c, n, s = _replicated(tail, kmesh)(arrs, traced)
     else:
         pos, c, n, s = kref.fused_select_ref(
             sel, val, qos, load, dead_arg,
@@ -880,8 +915,8 @@ class ShardedRoutingEngine:
         A 1-D device mesh with axis ``"fleet"`` of size `n_shards` runs
         the per-shard stages under ``shard_map``.  ``"auto"`` builds one
         via `launch.mesh.make_fleet_mesh` when enough devices exist, else
-        falls back to the (bit-identical) single-device emulation.  None
-        always emulates.
+        falls back to the (bit-identical) single-device emulation and sets
+        ``emulated``.  None always emulates.
     index : ToolIndex | TiledFleetIndex, optional
         Pre-built index; a `TiledFleetIndex` enables template-gathered
         scoring (no fleet-sized weight matrices anywhere).
@@ -926,6 +961,10 @@ class ShardedRoutingEngine:
             np.asarray(index.tool_server), self.n_servers, n_shards
         )
         self.mesh = self._resolve_mesh(mesh)
+        # True when several shards run stacked on one device (``mesh=None``,
+        # or ``"auto"`` with too few devices): callers that asked for a
+        # real mesh check this instead of trusting the shard count
+        self.emulated = self.mesh is None and self.plan.n_shards > 1
 
         # device-resident static arrays
         self._tool_server = jnp.asarray(index.tool_server, jnp.int32)
@@ -1149,6 +1188,50 @@ class ShardedRoutingEngine:
                 expertise=z, network=z, fused=z,
                 select_latency_ms=self.select_latency_ms(),
             )
+        dyn = self._dyn(
+            batch, latency_hist, server_load, telemetry_age_s, failed_mask,
+            client_rtt_ms, client_region, region_rtt_ms, affinity,
+            telemetry_templates=telemetry_templates,
+        )
+        with obs_trace.annotate("netmcp.route_sharded"):
+            server_idx, tool_idx, c, n, s = _route_sharded(
+                dyn, mesh=self.mesh, sc=self._sc
+            )
+        if route_stats is not None:
+            # fold this call's device outputs into the jit-safe stats
+            # buffer (donated .at[].add) before any host conversion
+            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
+        return BatchDecisions(
+            server_idx=np.asarray(server_idx, np.int32),
+            tool_idx=np.asarray(tool_idx, np.int32),
+            expertise=np.asarray(c), network=np.asarray(n),
+            fused=np.asarray(s),
+            select_latency_ms=self.select_latency_ms(),
+        )
+
+    def lower(self, batch: EncodedBatch, *args, **kw) -> jax.stages.Lowered:
+        """The program `route` would run on these inputs (same arguments,
+        without ``route_stats``/``n_real``), lowered but not run; see
+        `BatchRoutingEngine.lower`."""
+        return _route_sharded.lower(
+            self._dyn(batch, *args, **kw), mesh=self.mesh, sc=self._sc
+        )
+
+    def _dyn(
+        self,
+        batch: EncodedBatch,
+        latency_hist=None,
+        server_load=None,
+        telemetry_age_s=None,
+        failed_mask=None,
+        client_rtt_ms=None,
+        client_region=None,
+        region_rtt_ms=None,
+        affinity=None,
+        *,
+        telemetry_templates=None,
+    ) -> dict:
+        """The dynamic operands of `_route_sharded` for one call."""
         dyn: dict = {
             "tool_server": self._tool_server,
             "server_gid": self._server_gid,
@@ -1216,21 +1299,7 @@ class ShardedRoutingEngine:
             # byte-identical to the hand-tuned variant's)
             self._apply_feedback()
             dyn["adapt_w"] = self.adapt_state.weights
-        with obs_trace.annotate("netmcp.route_sharded"):
-            server_idx, tool_idx, c, n, s = _route_sharded(
-                dyn, mesh=self.mesh, sc=self._sc
-            )
-        if route_stats is not None:
-            # fold this call's device outputs into the jit-safe stats
-            # buffer (donated .at[].add) before any host conversion
-            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
-        return BatchDecisions(
-            server_idx=np.asarray(server_idx, np.int32),
-            tool_idx=np.asarray(tool_idx, np.int32),
-            expertise=np.asarray(c), network=np.asarray(n),
-            fused=np.asarray(s),
-            select_latency_ms=self.select_latency_ms(),
-        )
+        return dyn
 
     def route_texts(
         self,

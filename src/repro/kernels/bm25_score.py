@@ -40,7 +40,9 @@ def _bm25_kernel(q_ref, w_ref, out_ref, acc_ref, *, n_v_blocks: int):
     q = q_ref[...].astype(jnp.float32)      # [BQ, BV]
     w = w_ref[...].astype(jnp.float32)      # [BD, BV]
     acc_ref[...] += jax.lax.dot_general(
-        q, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, w, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,     # f32 operands stay exact
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(kv == n_v_blocks - 1)

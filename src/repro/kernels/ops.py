@@ -39,6 +39,23 @@ def _pad_to(x: np.ndarray | jax.Array, axis: int, mult: int, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _row_arg(x, n_t: int):
+    """(row operand, per_query): a per-tool row as [1, n_t] (zeros when
+    absent) or a per-query matrix [n_q, n_t] as is, in f32."""
+    if x is None:
+        return jnp.zeros((1, n_t), jnp.float32), False
+    x = jnp.asarray(x, jnp.float32)
+    per_query = x.ndim == 2
+    return (x if per_query else x[None, :]), per_query
+
+
+def _pad_rows(x, per_query: bool, lanes: int, q_tile: int, value=0.0):
+    """Pad the tool axis to ``lanes`` and, for per-query rows, the query
+    axis to ``q_tile``."""
+    x = _pad_to(x, 1, lanes, value=value)
+    return _pad_to(x, 0, q_tile, value=value) if per_query else x
+
+
 # ---------------------------------------------------------------------------
 # QoS
 # ---------------------------------------------------------------------------
@@ -135,48 +152,29 @@ def fused_select(
     failed-server argmax exclusion when tool_dead is given)."""
     n_q, n_t = sel_scores.shape
     k = min(k, n_t)
-    per_query_qos = tool_qos.ndim == 2
     sel = jnp.maximum(jnp.asarray(sel_scores, jnp.float32), _sel.NEG)
     val = jnp.asarray(val_scores, jnp.float32)
-    qos = jnp.asarray(tool_qos, jnp.float32)
-    if not per_query_qos:
-        qos = qos[None, :]
-
-    def _row_arg(x):
-        if x is None:
-            return jnp.zeros((1, n_t), jnp.float32), False
-        x = jnp.asarray(x, jnp.float32)
-        per_query = x.ndim == 2
-        return (x if per_query else x[None, :]), per_query
-
-    load, per_query_load = _row_arg(tool_load)
-    rtt, per_query_rtt = _row_arg(tool_rtt)
-    dead, per_query_dead = _row_arg(tool_dead)
+    # tool axis: one lane-aligned stripe up to STRIPE tools, else padded
+    # to a multiple of STRIPE (the kernel streams one stripe at a time)
+    stripe = min(_sel.STRIPE, -(-n_t // 128) * 128)
+    pad = functools.partial(_pad_rows, lanes=stripe, q_tile=_sel.QUERY_TILE)
+    qos, per_query_qos = _row_arg(tool_qos, n_t)
+    load, per_query_load = _row_arg(tool_load, n_t)
+    rtt, per_query_rtt = _row_arg(tool_rtt, n_t)
+    dead, per_query_dead = _row_arg(tool_dead, n_t)
     use_aff = tool_aff is not None
     if use_aff:
-        aff, per_query_aff = _row_arg(tool_aff)
-        aff = _pad_to(aff, 1, 128)
-        if per_query_aff:
-            aff = _pad_to(aff, 0, _sel.QUERY_TILE)
+        aff, per_query_aff = _row_arg(tool_aff, n_t)
+        aff = pad(aff, per_query_aff)
     else:
         aff, per_query_aff = None, False
 
-    sel = _pad_to(_pad_to(sel, 1, 128, value=_sel.NEG), 0, _sel.QUERY_TILE,
-                  value=_sel.NEG)
-    val = _pad_to(_pad_to(val, 1, 128, value=_sel.NEG), 0, _sel.QUERY_TILE,
-                  value=_sel.NEG)
-    qos = _pad_to(qos, 1, 128)
-    if per_query_qos:
-        qos = _pad_to(qos, 0, _sel.QUERY_TILE)
-    load = _pad_to(load, 1, 128)
-    if per_query_load:
-        load = _pad_to(load, 0, _sel.QUERY_TILE)
-    rtt = _pad_to(rtt, 1, 128)
-    if per_query_rtt:
-        rtt = _pad_to(rtt, 0, _sel.QUERY_TILE)
-    dead = _pad_to(dead, 1, 128)
-    if per_query_dead:
-        dead = _pad_to(dead, 0, _sel.QUERY_TILE)
+    sel = pad(sel, True, value=_sel.NEG)
+    val = pad(val, True, value=_sel.NEG)
+    qos = pad(qos, per_query_qos)
+    load = pad(load, per_query_load)
+    rtt = pad(rtt, per_query_rtt)
+    dead = pad(dead, per_query_dead)
     wrow, dyn_w = _weights_operand(alpha, beta, gamma, delta)
     aff_kw = dict(
         aff=aff, use_aff=use_aff, per_query_aff=per_query_aff,
@@ -258,29 +256,20 @@ def fused_score_select(
         jnp.asarray(cand_servers, jnp.int32), 0, _scf.QUERY_TILE, value=-1
     )
 
-    def _row_arg(x):
-        if x is None:
-            return jnp.zeros((1, n_t), jnp.float32), False
-        x = jnp.asarray(x, jnp.float32)
-        per_query = x.ndim == 2
-        return (x if per_query else x[None, :]), per_query
-
-    def _pad_rows(x, per_query):
-        x = _pad_to(x, 1, _scf.STRIPE)
-        return _pad_to(x, 0, _scf.QUERY_TILE) if per_query else x
-
-    qos, per_query_qos = _row_arg(tool_qos)
-    load, per_query_load = _row_arg(tool_load)
-    rtt, per_query_rtt = _row_arg(tool_rtt)
-    dead, per_query_dead = _row_arg(tool_dead)
-    qos = _pad_rows(qos, per_query_qos)
-    load = _pad_rows(load, per_query_load)
-    rtt = _pad_rows(rtt, per_query_rtt)
-    dead = _pad_rows(dead, per_query_dead)
+    pad = functools.partial(_pad_rows, lanes=_scf.STRIPE,
+                            q_tile=_scf.QUERY_TILE)
+    qos, per_query_qos = _row_arg(tool_qos, n_t)
+    load, per_query_load = _row_arg(tool_load, n_t)
+    rtt, per_query_rtt = _row_arg(tool_rtt, n_t)
+    dead, per_query_dead = _row_arg(tool_dead, n_t)
+    qos = pad(qos, per_query_qos)
+    load = pad(load, per_query_load)
+    rtt = pad(rtt, per_query_rtt)
+    dead = pad(dead, per_query_dead)
     use_aff = tool_aff is not None
     if use_aff:
-        aff, per_query_aff = _row_arg(tool_aff)
-        aff = _pad_rows(aff, per_query_aff)
+        aff, per_query_aff = _row_arg(tool_aff, n_t)
+        aff = pad(aff, per_query_aff)
     else:
         aff, per_query_aff = None, False
 

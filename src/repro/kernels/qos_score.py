@@ -37,9 +37,10 @@ def _qos_kernel(lat_ref, out_ref, *, p: QosParams, T: int, T_pad: int):
     lat = lat_ref[...].astype(jnp.float32)  # [S_TILE, T_pad]
 
     # ages: newest sample (last col) has age 0 (in-kernel iota; Pallas
-    # kernels may not capture trace-time array constants)
-    pos = jax.lax.broadcasted_iota(jnp.float32, (1, T_pad), 1)
-    k = (T_pad - 1.0) - pos
+    # kernels may not capture trace-time array constants, and Mosaic builds
+    # integer iotas only — the cast is exact for any window length)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, T_pad), 1)
+    k = (T_pad - 1.0) - pos.astype(jnp.float32)
 
     # --- EWMA (closed form; initial-state mass on the oldest real sample).
     # Pad columns (age k >= T) carry zero weight; the (1-a)^T carry mass is
@@ -86,8 +87,11 @@ def _qos_kernel(lat_ref, out_ref, *, p: QosParams, T: int, T_pad: int):
         * (1.0 - p.w_outage * p_outage)
         * (1.0 - p.w_instab * p_instab)
     )
-    offline = lat[:, -1] >= p.offline_ms
-    out_ref[...] = jnp.where(offline, -1.0, score)[:, None]
+    # newest sample (age 0) by a masked lane max: Mosaic has no dynamic
+    # slice for a single unaligned column, and the max over one live lane
+    # returns that sample exactly
+    newest = jnp.max(jnp.where(k == 0.0, lat, -jnp.inf), axis=-1)
+    out_ref[...] = jnp.where(newest >= p.offline_ms, -1.0, score)[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("p", "T", "interpret"))

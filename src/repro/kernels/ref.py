@@ -103,13 +103,13 @@ def fused_score_select_ref(
     stage-2 score matrix (BM25 matmul + candidate-server mask) and feed
     it to `fused_select_ref` — exactly the unfused two-pass pipeline the
     single-pass kernel replaces."""
-    t = q_tool.astype(jnp.float32) @ w_tool.astype(jnp.float32).T
+    t = bm25_ref(w_tool, q_tool)
     in_cand = jnp.any(
         tool_server[None, None, :] == cand_servers[:, :, None], axis=1
     )                                                        # [n_q, n_tools]
     sel = jnp.where(in_cand, t, NEG)
     if q_rerank is not None:
-        val = q_rerank.astype(jnp.float32) @ w_tool.astype(jnp.float32).T
+        val = bm25_ref(w_tool, q_rerank)
     else:
         val = sel
     return fused_select_ref(

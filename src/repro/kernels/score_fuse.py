@@ -29,8 +29,11 @@ the scalar `Router.select`): the running top-k orders candidates by
 the full tool axis — because each stripe merge re-peels the combined
 (scratch ∪ stripe) pool with a min-global-id tie-break; scratch entries
 from earlier stripes always carry lower gids than the current stripe, so
-stability is preserved.  The softmax / fusion / argmax finale mirrors
-`select_fuse._select_kernel` term for term.  One caveat: a query whose
+stability is preserved.  The running top-k (`topk_init`, `topk_merge`,
+`topk_finale`) is shared with `select_fuse`, which streams materialized
+score stripes through the same merge and finale.  Every index (lane, gid)
+comes from an int32 iota cast to f32, exact below 2**24; the stripe flags
+ride in SMEM, one scalar per grid step.  One caveat: a query whose
 candidate servers host zero tools (every stripe skipped) returns tool 0
 with neutral (zero) metadata — reachable only on degenerate pools where
 stage-1 candidates have no tools at all.
@@ -50,6 +53,169 @@ K_MAX = 128         # running top-k scratch width (one lane register row)
 NEG = -1e30         # finite -inf stand-in
 
 
+def lane_index(shape, dim: int = 1) -> jax.Array:
+    """Index iota along ``dim`` as f32.  Mosaic builds integer iotas only;
+    every index here stays below 2**24, where f32 holds integers exactly,
+    so the cast loses nothing."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
+
+
+def weight_lanes(w_ref) -> tuple:
+    """Live (alpha, beta, gamma, delta) from lanes 0..3 of a (1, 128) f32
+    row, each as a (1, 1) value: one-hot lane reductions keep this pure
+    VPU work (no scalar-memory gathers)."""
+    wrow = w_ref[...].astype(jnp.float32)
+    wl = lane_index(wrow.shape)
+    return tuple(
+        jnp.sum(jnp.where(wl == float(i), wrow, 0.0), axis=-1, keepdims=True)
+        for i in range(4)
+    )
+
+
+def _pick(x, onehot):
+    """The one lane of ``x`` that ``onehot`` marks, per row ([QT, 1]).  A
+    select, not a multiply: -inf * 0 would poison the sum with NaN."""
+    return jnp.sum(jnp.where(onehot, x, 0.0), axis=-1, keepdims=True)
+
+
+def topk_scratch(use_aff: bool) -> list:
+    """VMEM scratch of the running top-k, one (QUERY_TILE, K_MAX) f32 row
+    block each: selection score, softmax value, the per-tool rows (QoS,
+    load, RTT, dead, [affinity]) and the global tool id, in that order."""
+    n = 8 if use_aff else 7
+    return [pltpu.VMEM((QUERY_TILE, K_MAX), jnp.float32)] * n
+
+
+def topk_init(scr, t_total: int) -> None:
+    """Empty running top-k: NEG scores, sentinel gids above every real tool
+    id so they lose every min-gid tie-break."""
+    shape = (QUERY_TILE, K_MAX)
+    scr[0][...] = jnp.full(shape, NEG, jnp.float32)
+    scr[1][...] = jnp.full(shape, NEG, jnp.float32)
+    for ref in scr[2:-1]:
+        ref[...] = jnp.zeros(shape, jnp.float32)
+    scr[-1][...] = float(t_total) + lane_index(shape)
+
+
+def topk_merge(
+    scr, stripe_sel, stripe_val, rows, stripe_gid, *,
+    k: int, t_total: int, stripe: int,
+) -> None:
+    """Fold one (QUERY_TILE, TS) stripe into the running top-k.
+
+    ``rows`` are the stripe's per-tool rows ([QT or 1, TS], scratch order)
+    and ``stripe_gid`` its global tool ids as f32.  The combined pool
+    (scratch + stripe) is peeled k times in (score desc, gid asc) order.
+    Gids are unique across the pool (stripes are disjoint ranges; scratch
+    holds earlier stripes' gids or sentinels), so the min-gid one-hot
+    selects exactly one entry per step."""
+    QT, TS = stripe_sel.shape
+    lane = lane_index((QT, K_MAX))
+
+    def comb(ref, x):
+        return jnp.concatenate([ref[...], jnp.broadcast_to(x, (QT, TS))], axis=1)
+
+    comb_sel = comb(scr[0], stripe_sel)
+    comb_val = comb(scr[1], stripe_val)
+    comb_rows = [comb(ref, x) for ref, x in zip(scr[2:-1], rows)]
+    comb_gid = comb(scr[-1], stripe_gid)
+    big = float(t_total + K_MAX + stripe)
+
+    news = []
+    for _ in range(k):
+        m = jnp.max(comb_sel, axis=-1, keepdims=True)        # [QT, 1]
+        is_max = comb_sel >= m
+        g = jnp.min(jnp.where(is_max, comb_gid, big), axis=-1, keepdims=True)
+        onehot = comb_gid == g                               # [QT, C]
+        news.append(
+            [m] + [_pick(x, onehot) for x in [comb_val] + comb_rows] + [g]
+        )
+        # retire the peeled entry from BOTH pools: score AND gid — leaving
+        # the gid live would let a later all-NEG tie re-pick it,
+        # duplicating gids in scratch and double-counting the gid-keyed
+        # one-hot sums on the next merge
+        comb_sel = jnp.where(onehot, NEG, comb_sel)
+        comb_gid = jnp.where(onehot, big, comb_gid)
+
+    # write the re-sorted top-k back into scratch lanes [0, k)
+    def pack(vals, fill):
+        acc = jnp.where(lane >= float(k), fill, 0.0)
+        for slot, v in enumerate(vals):
+            acc = acc + jnp.where(lane == float(slot), v, 0.0)
+        return acc
+
+    for col, ref in enumerate(scr[:-1]):
+        ref[...] = pack([e[col] for e in news], NEG if col < 2 else 0.0)
+    scr[-1][...] = pack([e[-1] for e in news], float(t_total)) + jnp.where(
+        lane >= float(k), lane, 0.0
+    )
+
+
+def topk_finale(
+    scr, out_refs, weights: tuple, *,
+    k: int, t_total: int, temp: float, eps: float, use_aff: bool,
+) -> None:
+    """Softmax (Eq. 5) + fusion (Eq. 8) + argmax (Eq. 9) over the k
+    running candidates.  ``weights`` is (alpha, beta, gamma, delta) as
+    Python floats or (1, 1) values.  The argmax is seeded with candidate 0
+    at score NEG, so an all-excluded row returns the top-selection
+    candidate, like np.argmax over an all--inf vector."""
+    QT = QUERY_TILE
+    lane = lane_index((QT, K_MAX))
+    cands = []                               # per slot: [m, v, rows.., gid]
+    for slot in range(k):
+        onehot = lane == float(slot)
+        cands.append([_pick(ref[...], onehot) for ref in scr])
+    cand_val = [jnp.where(c[0] > NEG / 2.0, c[1], NEG) for c in cands]
+
+    vmax = cand_val[0]                       # extraction is value-sorted only
+    for v in cand_val[1:]:                   # when val == sel; reduce
+        vmax = jnp.maximum(vmax, v)          # explicitly
+    exps = [jnp.exp((v - vmax) / temp) for v in cand_val]
+    denom = exps[0]
+    for e in exps[1:]:
+        denom = denom + e
+    denom = jnp.maximum(denom, 1e-30)
+
+    alpha, beta, gamma, delta = weights
+    best_s = jnp.full((QT, 1), NEG, jnp.float32)
+    best_c = exps[0] / denom
+    best_n = cands[0][2]
+    best_i = cands[0][-1]
+    for v, e, c_ in zip(cand_val, exps, cands):
+        n, u, r, d = c_[2:6]
+        c = e / denom
+        s = alpha * c + beta * n - gamma * u - delta * r
+        if use_aff:
+            s = s + eps * c_[6]
+        s = jnp.where(v > NEG / 2.0, s, NEG)
+        s = jnp.where(d > 0.0, NEG, s)
+        take = s > best_s                    # strict: earliest winner
+        best_c = jnp.where(take, c, best_c)
+        best_n = jnp.where(take, n, best_n)
+        best_i = jnp.where(take, c_[-1], best_i)
+        best_s = jnp.where(take, s, best_s)
+
+    # rows whose every stripe was skipped still hold the sentinel gid:
+    # clamp to tool 0, matching np.argmax over an all--inf vector
+    best_i = jnp.where(best_i >= float(t_total), 0.0, best_i)
+    idx_ref, c_ref, n_ref, s_ref = out_refs
+    idx_ref[...] = best_i.astype(jnp.int32)
+    c_ref[...] = best_c
+    n_ref[...] = best_n
+    s_ref[...] = best_s
+
+
+def _dot_t(a, b):
+    """[QT, V] x [TS, V]^T in f32.  HIGHEST keeps f32 operands exact on
+    the MXU (the default is one bf16 pass)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _score_kernel(
     *refs,
     k: int, n_stripes: int, t_total: int, top_s: int,
@@ -57,58 +223,29 @@ def _score_kernel(
     rerank: bool, eps: float = 0.0, use_aff: bool = False,
     dyn_weights: bool = False,
 ):
+    # operands: q, qr, w, host, cand, rows (qos, load, rtt, dead, [aff]),
+    # flags, [wvec]; then the 4 outputs and the top-k scratch
     refs = list(refs)
-    (q_ref, qr_ref, w_ref, host_ref, cand_ref,
-     qos_ref, load_ref, rtt_ref, dead_ref) = refs[:9]
-    pos = 9
-    if use_aff:
-        # warm-affinity row (SONAR-SESSION): operand + an 8th scratch
-        # buffer, both absent unless use_aff so zero-affinity callers
-        # compile exactly the historical graph
-        aff_ref = refs[pos]
-        pos += 1
-    else:
-        aff_ref = None
-    flag_ref = refs[pos]
-    pos += 1
-    if dyn_weights:
-        wvec_ref = refs[pos]
-        pos += 1
-    else:
-        wvec_ref = None
-    idx_ref, c_ref, n_ref, s_ref = refs[pos:pos + 4]
-    pos += 4
-    sel_s, val_s, qos_s, load_s, rtt_s, dead_s, gid_s = refs[pos:pos + 7]
-    pos += 7
-    aff_s = refs[pos] if use_aff else None
+    q_ref, qr_ref, w_ref, host_ref, cand_ref = refs[:5]
+    pos = 5 + (5 if use_aff else 4)
+    row_refs = refs[5:pos]
+    flag_ref = refs[pos]                     # [1, n_stripes] i32 in SMEM
+    wvec_ref = refs[pos + 1] if dyn_weights else None
+    pos += 2 if dyn_weights else 1
+    out_refs, scr = refs[pos:pos + 4], refs[pos + 4:]
     j = pl.program_id(1)
-    QT = QUERY_TILE
-    lane = jax.lax.broadcasted_iota(jnp.float32, (QT, K_MAX), 1)
 
-    # --- scratch init: empty running top-k (NEG scores, sentinel gids
-    # above every real tool id so they lose every min-gid tie-break) ---
     @pl.when(j == 0)
     def _init():
-        sel_s[...] = jnp.full((QT, K_MAX), NEG, jnp.float32)
-        val_s[...] = jnp.full((QT, K_MAX), NEG, jnp.float32)
-        qos_s[...] = jnp.zeros((QT, K_MAX), jnp.float32)
-        load_s[...] = jnp.zeros((QT, K_MAX), jnp.float32)
-        rtt_s[...] = jnp.zeros((QT, K_MAX), jnp.float32)
-        dead_s[...] = jnp.zeros((QT, K_MAX), jnp.float32)
-        if use_aff:
-            aff_s[...] = jnp.zeros((QT, K_MAX), jnp.float32)
-        gid_s[...] = float(t_total) + lane
+        topk_init(scr, t_total)
 
     # --- stripe merge: only when the stripe hosts candidate tools ---
-    @pl.when(flag_ref[0, 0] > 0)
+    @pl.when(flag_ref[0, j] > 0)
     def _merge():
         q = q_ref[...].astype(jnp.float32)                   # [QT, V]
         w = w_ref[...].astype(jnp.float32)                   # [TS, V]
-        scores = jax.lax.dot_general(
-            q, w, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [QT, TS]
-        TS = scores.shape[1]
+        scores = _dot_t(q, w)                                # [QT, TS]
+        QT, TS = scores.shape
         host = host_ref[...].astype(jnp.int32)               # [1, TS]
         cand = cand_ref[...].astype(jnp.int32)               # [QT, top_s]
         member = jnp.zeros((QT, TS), jnp.bool_)
@@ -116,171 +253,24 @@ def _score_kernel(
             member = member | (host == cand[:, s_i:s_i + 1])
         stripe_sel = jnp.where(member, scores, NEG)
         if rerank:
-            qr = qr_ref[...].astype(jnp.float32)
-            stripe_val = jax.lax.dot_general(
-                qr, w, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            stripe_val = _dot_t(qr_ref[...].astype(jnp.float32), w)
         else:
             stripe_val = stripe_sel
-        stripe_lane = jax.lax.broadcasted_iota(jnp.float32, (QT, TS), 1)
-        stripe_gid = float(STRIPE) * j.astype(jnp.float32) + stripe_lane
-
-        def row(ref):                                        # [QT|1, TS]
-            return ref[...].astype(jnp.float32)
-
-        comb_sel = jnp.concatenate([sel_s[...], stripe_sel], axis=1)
-        comb_val = jnp.concatenate([val_s[...], stripe_val], axis=1)
-        comb_qos = jnp.concatenate(
-            [qos_s[...], jnp.broadcast_to(row(qos_ref), (QT, TS))], axis=1
-        )
-        comb_load = jnp.concatenate(
-            [load_s[...], jnp.broadcast_to(row(load_ref), (QT, TS))], axis=1
-        )
-        comb_rtt = jnp.concatenate(
-            [rtt_s[...], jnp.broadcast_to(row(rtt_ref), (QT, TS))], axis=1
-        )
-        comb_dead = jnp.concatenate(
-            [dead_s[...], jnp.broadcast_to(row(dead_ref), (QT, TS))], axis=1
-        )
-        if use_aff:
-            comb_aff = jnp.concatenate(
-                [aff_s[...], jnp.broadcast_to(row(aff_ref), (QT, TS))],
-                axis=1,
-            )
-        comb_gid = jnp.concatenate(
-            [gid_s[...], jnp.broadcast_to(stripe_gid, (QT, TS))], axis=1
-        )
-        big = float(t_total + K_MAX + STRIPE)
-
-        # peel the combined pool k times: (score desc, gid asc) order —
-        # gids are unique across scratch ∪ stripe (stripes are disjoint
-        # ranges; scratch holds earlier stripes' gids or sentinels), so
-        # the min-gid one-hot selects exactly one entry per step
-        news = []
-        for _ in range(k):
-            m = jnp.max(comb_sel, axis=-1, keepdims=True)    # [QT, 1]
-            is_max = comb_sel >= m
-            g = jnp.min(jnp.where(is_max, comb_gid, big), axis=-1,
-                        keepdims=True)
-            onehot = (comb_gid == g).astype(jnp.float32)     # [QT, C]
-            entry = [
-                m,
-                jnp.sum(comb_val * onehot, axis=-1, keepdims=True),
-                jnp.sum(comb_qos * onehot, axis=-1, keepdims=True),
-                jnp.sum(comb_load * onehot, axis=-1, keepdims=True),
-                jnp.sum(comb_rtt * onehot, axis=-1, keepdims=True),
-                jnp.sum(comb_dead * onehot, axis=-1, keepdims=True),
-            ]
-            if use_aff:
-                entry.append(
-                    jnp.sum(comb_aff * onehot, axis=-1, keepdims=True)
-                )
-            entry.append(g)
-            news.append(entry)
-            # retire the peeled entry from BOTH pools: score AND gid —
-            # leaving the gid live would let a later all-NEG tie re-pick
-            # it, duplicating gids in scratch and double-counting the
-            # gid-keyed one-hot sums on the next stripe merge
-            comb_sel = jnp.where(onehot > 0.0, NEG, comb_sel)
-            comb_gid = jnp.where(onehot > 0.0, big, comb_gid)
-
-        # write the re-sorted top-k back into scratch lanes [0, k)
-        def pack(vals, fill):
-            acc = jnp.where(lane >= float(k), fill, 0.0)
-            for slot, v in enumerate(vals):
-                acc = acc + jnp.where(lane == float(slot), v, 0.0)
-            return acc
-
-        sel_s[...] = pack([t[0] for t in news], NEG)
-        val_s[...] = pack([t[1] for t in news], NEG)
-        qos_s[...] = pack([t[2] for t in news], 0.0)
-        load_s[...] = pack([t[3] for t in news], 0.0)
-        rtt_s[...] = pack([t[4] for t in news], 0.0)
-        dead_s[...] = pack([t[5] for t in news], 0.0)
-        if use_aff:
-            aff_s[...] = pack([t[6] for t in news], 0.0)
-        gid_s[...] = pack([t[-1] for t in news], float(t_total)) + jnp.where(
-            lane >= float(k), lane, 0.0
+        gid = STRIPE * j + jax.lax.broadcasted_iota(jnp.int32, (QT, TS), 1)
+        topk_merge(
+            scr, stripe_sel, stripe_val,
+            [ref[...].astype(jnp.float32) for ref in row_refs],
+            gid.astype(jnp.float32), k=k, t_total=t_total, stripe=STRIPE,
         )
 
-    # --- finale on the last stripe: softmax + fusion + argmax over the
-    # k running candidates (mirrors select_fuse._select_kernel) ---
     @pl.when(j == n_stripes - 1)
     def _finale():
-        cand_val, cand_qos, cand_load, cand_rtt, cand_dead, cand_idx = (
-            [], [], [], [], [], []
+        weights = (
+            weight_lanes(wvec_ref) if dyn_weights
+            else (alpha, beta, gamma, delta)
         )
-        cand_aff = []
-        for slot in range(k):
-            onehot = (lane == float(slot)).astype(jnp.float32)
-            m = jnp.sum(sel_s[...] * onehot, axis=-1, keepdims=True)
-            v = jnp.sum(val_s[...] * onehot, axis=-1, keepdims=True)
-            valid = m > NEG / 2.0
-            cand_val.append(jnp.where(valid, v, NEG))
-            cand_qos.append(jnp.sum(qos_s[...] * onehot, axis=-1,
-                                    keepdims=True))
-            cand_load.append(jnp.sum(load_s[...] * onehot, axis=-1,
-                                     keepdims=True))
-            cand_rtt.append(jnp.sum(rtt_s[...] * onehot, axis=-1,
-                                    keepdims=True))
-            cand_dead.append(jnp.sum(dead_s[...] * onehot, axis=-1,
-                                     keepdims=True))
-            if use_aff:
-                cand_aff.append(jnp.sum(aff_s[...] * onehot, axis=-1,
-                                        keepdims=True))
-            cand_idx.append(jnp.sum(gid_s[...] * onehot, axis=-1,
-                                    keepdims=True))
-
-        vmax = cand_val[0]
-        for v in cand_val[1:]:
-            vmax = jnp.maximum(vmax, v)
-        exps = [jnp.exp((v - vmax) / temp) for v in cand_val]
-        denom = exps[0]
-        for e in exps[1:]:
-            denom = denom + e
-        denom = jnp.maximum(denom, 1e-30)
-
-        if dyn_weights:
-            # live fusion weights in lanes 0..3 of a (1, 128) f32 row;
-            # one-hot lane reductions keep this pure VPU work
-            wrow = wvec_ref[...].astype(jnp.float32)
-            wl = jax.lax.broadcasted_iota(jnp.float32, wrow.shape, 1)
-
-            def _w(i: int):
-                return jnp.sum(jnp.where(wl == float(i), wrow, 0.0))
-
-            alpha_v, beta_v, gamma_v, delta_v = _w(0), _w(1), _w(2), _w(3)
-        else:
-            alpha_v, beta_v, gamma_v, delta_v = alpha, beta, gamma, delta
-
-        best_s = jnp.full((QT, 1), NEG, jnp.float32)
-        best_c = exps[0] / denom
-        best_n = cand_qos[0]
-        best_i = cand_idx[0]
-        for slot, (v, e, n, u, r, d, i) in enumerate(zip(
-            cand_val, exps, cand_qos, cand_load, cand_rtt, cand_dead,
-            cand_idx,
-        )):
-            c = e / denom
-            s = alpha_v * c + beta_v * n - gamma_v * u - delta_v * r
-            if use_aff:
-                s = s + eps * cand_aff[slot]
-            s = jnp.where(v > NEG / 2.0, s, NEG)
-            s = jnp.where(d > 0.0, NEG, s)
-            take = s > best_s
-            best_c = jnp.where(take, c, best_c)
-            best_n = jnp.where(take, n, best_n)
-            best_i = jnp.where(take, i, best_i)
-            best_s = jnp.where(take, s, best_s)
-
-        # all-stripes-skipped rows still hold the sentinel gid: clamp to
-        # tool 0, matching np.argmax over an all--inf vector
-        best_i = jnp.where(best_i >= float(t_total), 0.0, best_i)
-        idx_ref[...] = best_i.astype(jnp.int32)
-        c_ref[...] = best_c
-        n_ref[...] = best_n
-        s_ref[...] = best_s
+        topk_finale(scr, out_refs, weights, k=k, t_total=t_total,
+                    temp=temp, eps=eps, use_aff=use_aff)
 
 
 @functools.partial(
@@ -342,8 +332,6 @@ def fused_score_select_pallas(
 
     out_spec = pl.BlockSpec((QUERY_TILE, 1), lambda i, j: (i, 0))
     out_shape = jax.ShapeDtypeStruct((n_q, 1), jnp.float32)
-    n_scratch = 8 if use_aff else 7
-    scratch = [pltpu.VMEM((QUERY_TILE, K_MAX), jnp.float32)] * n_scratch
     assert (wvec is not None) == dyn_weights
     assert (aff is not None) == use_aff
     in_specs = [
@@ -361,8 +349,15 @@ def fused_score_select_pallas(
     if use_aff:
         in_specs.append(_row_spec(per_query_aff))
         operands.append(aff)
-    in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, j)))
-    operands.append(flags)
+    # the query tile's row of flags rides in SMEM (read as scalars for the
+    # pl.when guard): [n_q_tiles, 1, n_stripes] with the tile axis squeezed
+    # keeps the block's last two dims whole, and the footprint is one row
+    # whatever the batch size (SMEM is 1 MiB on v5e)
+    in_specs.append(pl.BlockSpec(
+        (None, 1, n_stripes), lambda i, j: (i, 0, 0),
+        memory_space=pltpu.SMEM,
+    ))
+    operands.append(flags[:, None, :])
     if dyn_weights:
         in_specs.append(pl.BlockSpec((1, 128), lambda i, j: (0, 0)))
         operands.append(wvec)
@@ -380,7 +375,7 @@ def fused_score_select_pallas(
             jax.ShapeDtypeStruct((n_q, 1), jnp.int32),
             out_shape, out_shape, out_shape,
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=topk_scratch(use_aff),
         interpret=interpret,
     )(*operands)
     return idx[:, 0], c[:, 0], n[:, 0], s[:, 0]

@@ -3,13 +3,12 @@
 Collapses the per-query tail of Algorithm 1 — stage-2 top-k over the masked
 tool scores (Eq. 4), softmax expertise over the candidate set (Eq. 5), QoS
 fusion S = alpha*C + beta*N (Eq. 8) and the final argmax (Eq. 9) — into one
-pass over a (QUERY_TILE x n_tools) score stripe resident in VMEM.
+pass over a materialized [n_q, n_tools] score matrix.
 
 Why fuse: the unfused pipeline materializes the [n_q, k] candidate tensors
-(indices, scores, gathered QoS) in HBM between five separate ops; at fleet
-scale (10^3-10^4 tools, scored per request batch) the candidate traffic
-dominates.  Here each score stripe is streamed once and the k-step
-extraction, softmax and fusion happen in-register.
+(indices, scores, gathered QoS) in HBM between five separate ops; here each
+score stripe is streamed once and the k-step extraction, softmax and fusion
+happen in-register.
 
 Inputs per query row
   sel  [n_tools]  — stage-2 scores, already masked to NEG outside the
@@ -29,6 +28,13 @@ Inputs per query row
 
 Outputs per query row: winning global tool index + (C, N, S) at the winner.
 
+Tiling: grid (query tiles, tool stripes).  Each (QUERY_TILE, stripe) block
+is folded into the running per-query top-k that `kernels/score_fuse` keeps
+in VMEM scratch (`topk_merge`), and the last stripe runs the shared
+softmax / fusion / argmax finale (`topk_finale`).  VMEM holds one stripe
+per operand, so the tool axis has no size limit below the 2**24 f32 gid
+horizon (a 500k-tool mesh shard streams like a 500-tool one).
+
 Selection semantics replicate the scalar `Router.select` exactly:
 top-k ties break toward the lower tool index (stable argsort), the softmax
 normalizes over the valid candidate set only, candidates whose selection
@@ -37,19 +43,11 @@ excluded from the argmax, the final argmax tie-breaks toward the earlier
 (higher-ranked) candidate, and when *every* candidate is excluded the
 top-selection candidate is returned (np.argmax over all -inf picks 0).
 
-Gather-free trick: per-candidate values come from one-hot reductions over
-the stripe (sum(onehot * row)) instead of dynamic gathers, which keeps the
-kernel pure VPU work with lane-aligned reductions.
-
-Quantized operands: inputs may arrive physically stored as bf16 (the
-wrapper upcasts with `.astype(jnp.float32)` at entry, which is exact for
-every bf16 value) and all in-kernel arithmetic is f32, so this kernel
-sits inside the quantized-scoring parity contract — operands rounded once
-at build, decisions argmax-identical across paths (docs/benchmarks.md
-"Quantized scoring carve-out").  The single-pass variant that also fuses
-the stage-2 BM25 matmul and streams the corpus in stripes lives in
-`kernels/score_fuse.py`; this kernel remains the tail for callers that
-already hold a materialized [n_q, n_tools] score stripe.
+Quantized operands: inputs may arrive physically stored as bf16 (upcast
+with `.astype(jnp.float32)` at block load, exact for every bf16 value) and
+all in-kernel arithmetic is f32, so this kernel sits inside the
+quantized-scoring parity contract (docs/benchmarks.md "Quantized scoring
+carve-out").
 """
 from __future__ import annotations
 
@@ -59,119 +57,60 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-QUERY_TILE = 8      # f32 sublane granularity
-NEG = -1e30         # finite -inf stand-in (avoids inf-inf NaNs in VMEM math)
+from repro.kernels.score_fuse import (
+    K_MAX,
+    NEG,
+    QUERY_TILE,
+    STRIPE,
+    topk_finale,
+    topk_init,
+    topk_merge,
+    topk_scratch,
+    weight_lanes,
+)
+
+__all__ = ["K_MAX", "NEG", "QUERY_TILE", "STRIPE", "fused_select_pallas"]
 
 
 def _select_kernel(
     *refs,
-    k: int, alpha: float, beta: float, gamma: float, delta: float,
+    k: int, n_stripes: int, t_total: int, stripe: int,
+    alpha: float, beta: float, gamma: float, delta: float,
     temp: float, eps: float = 0.0, use_aff: bool = False,
     dyn_weights: bool = False,
 ):
+    # operands: sel, val, rows (qos, load, rtt, dead, [aff]), [w]; then
+    # the 4 outputs and the top-k scratch
     refs = list(refs)
-    sel_ref, val_ref, qos_ref, load_ref, rtt_ref, dead_ref = refs[:6]
-    pos = 6
-    if use_aff:
-        aff_ref = refs[pos]
-        pos += 1
-    else:
-        aff_ref = None
+    sel_ref, val_ref = refs[:2]
+    pos = 2 + (5 if use_aff else 4)
+    row_refs = refs[2:pos]
     w_ref = refs[pos] if dyn_weights else None
-    idx_ref, c_ref, n_ref, s_ref = refs[-4:]
-    sel = sel_ref[...].astype(jnp.float32)   # [QT, T_pad]
-    val = val_ref[...].astype(jnp.float32)   # [QT, T_pad]
-    qos = qos_ref[...].astype(jnp.float32)   # [QT or 1, T_pad]
-    load = load_ref[...].astype(jnp.float32)  # [QT or 1, T_pad] — U penalty
-    rtt = rtt_ref[...].astype(jnp.float32)   # [QT or 1, T_pad] — R penalty
-    dead = dead_ref[...].astype(jnp.float32)  # [QT or 1, T_pad] — failover mask
-    # warm-affinity bonus W (SONAR-SESSION); absent unless use_aff, so
-    # zero-affinity callers compile exactly the historical graph
-    aff = aff_ref[...].astype(jnp.float32) if use_aff else None
-    QT, T_pad = sel.shape
+    pos += 1 if dyn_weights else 0
+    out_refs, scr = refs[pos:pos + 4], refs[pos + 4:]
+    j = pl.program_id(1)
 
-    if dyn_weights:
-        # live weights ride in lanes 0..3 of a (1, 128) f32 row; extract
-        # with one-hot lane reductions (no scalar-memory gathers on TPU)
-        wrow = w_ref[...].astype(jnp.float32)
-        wlane = jax.lax.broadcasted_iota(jnp.float32, wrow.shape, 1)
+    @pl.when(j == 0)
+    def _init():
+        topk_init(scr, t_total)
 
-        def _w(i: int):
-            return jnp.sum(jnp.where(wlane == float(i), wrow, 0.0))
-
-        alpha_v, beta_v, gamma_v, delta_v = _w(0), _w(1), _w(2), _w(3)
-    else:
-        alpha_v, beta_v, gamma_v, delta_v = alpha, beta, gamma, delta
-
-    lane = jax.lax.broadcasted_iota(jnp.float32, (QT, T_pad), 1)
-
-    # --- k-step extraction: peel the row maximum k times (ties -> lowest
-    # index, matching a stable descending argsort) ---
-    cand_val, cand_qos, cand_load, cand_rtt, cand_dead, cand_idx = (
-        [], [], [], [], [], []
+    QT, TS = sel_ref.shape
+    gid = stripe * j + jax.lax.broadcasted_iota(jnp.int32, (QT, TS), 1)
+    topk_merge(
+        scr, sel_ref[...].astype(jnp.float32),
+        val_ref[...].astype(jnp.float32),
+        [ref[...].astype(jnp.float32) for ref in row_refs],
+        gid.astype(jnp.float32), k=k, t_total=t_total, stripe=stripe,
     )
-    cand_aff = []
-    cur = sel
-    for _ in range(k):
-        m = jnp.max(cur, axis=-1, keepdims=True)                    # [QT, 1]
-        is_max = cur >= m
-        idx = jnp.min(jnp.where(is_max, lane, float(T_pad)), axis=-1,
-                      keepdims=True)                                # first max
-        onehot = (lane == idx).astype(jnp.float32)
-        v = jnp.sum(val * onehot, axis=-1, keepdims=True)
-        n = jnp.sum(qos * onehot, axis=-1, keepdims=True)
-        u = jnp.sum(load * onehot, axis=-1, keepdims=True)
-        r = jnp.sum(rtt * onehot, axis=-1, keepdims=True)
-        d = jnp.sum(dead * onehot, axis=-1, keepdims=True)
-        valid = m > NEG / 2.0
-        cand_val.append(jnp.where(valid, v, NEG))
-        cand_qos.append(n)
-        cand_load.append(u)
-        cand_rtt.append(r)
-        cand_dead.append(d)
-        cand_idx.append(idx)
-        if use_aff:
-            cand_aff.append(jnp.sum(aff * onehot, axis=-1, keepdims=True))
-        cur = jnp.where(onehot > 0.0, NEG, cur)
 
-    # --- Eq. 5 softmax over the valid candidates (invalid -> zero mass) ---
-    vmax = cand_val[0]                       # extraction is value-sorted only
-    for v in cand_val[1:]:                   # when val==sel; reduce explicitly
-        vmax = jnp.maximum(vmax, v)
-    exps = [jnp.exp((v - vmax) / temp) for v in cand_val]
-    denom = exps[0]
-    for e in exps[1:]:
-        denom = denom + e
-    denom = jnp.maximum(denom, 1e-30)
-
-    # --- Eq. 8 fusion (+ SONAR-LB load term + SONAR-FT dead mask) + Eq. 9
-    # argmax (strict > keeps the earliest winner, matching np.argmax over
-    # the rank-ordered list).  Seeded with candidate 0 at score NEG so an
-    # all-excluded row returns the top-selection candidate, exactly like
-    # np.argmax over an all--inf vector (and like the jnp oracle). ---
-    best_s = jnp.full((QT, 1), NEG, jnp.float32)
-    best_c = exps[0] / denom
-    best_n = cand_qos[0]
-    best_i = cand_idx[0]
-    for j, (v, e, n, u, r, d, i) in enumerate(zip(
-        cand_val, exps, cand_qos, cand_load, cand_rtt, cand_dead, cand_idx
-    )):
-        c = e / denom
-        s = alpha_v * c + beta_v * n - gamma_v * u - delta_v * r
-        if use_aff:
-            s = s + eps * cand_aff[j]
-        s = jnp.where(v > NEG / 2.0, s, NEG)
-        s = jnp.where(d > 0.0, NEG, s)
-        take = s > best_s
-        best_c = jnp.where(take, c, best_c)
-        best_n = jnp.where(take, n, best_n)
-        best_i = jnp.where(take, i, best_i)
-        best_s = jnp.where(take, s, best_s)
-
-    idx_ref[...] = best_i.astype(jnp.int32)
-    c_ref[...] = best_c
-    n_ref[...] = best_n
-    s_ref[...] = best_s
+    @pl.when(j == n_stripes - 1)
+    def _finale():
+        weights = (
+            weight_lanes(w_ref) if dyn_weights
+            else (alpha, beta, gamma, delta)
+        )
+        topk_finale(scr, out_refs, weights, k=k, t_total=t_total,
+                    temp=temp, eps=eps, use_aff=use_aff)
 
 
 @functools.partial(
@@ -183,7 +122,7 @@ def _select_kernel(
     ),
 )
 def fused_select_pallas(
-    sel: jax.Array,   # [n_q_pad, T_pad] f32, NEG-padded
+    sel: jax.Array,   # [n_q_pad, T_pad] f32, NEG-padded to a stripe multiple
     val: jax.Array,   # [n_q_pad, T_pad] f32
     qos: jax.Array,   # [n_q_pad or 1, T_pad] f32
     load: jax.Array,  # [n_q_pad or 1, T_pad] f32 — per-tool U penalty
@@ -211,21 +150,24 @@ def fused_select_pallas(
     interpret: bool = False,
 ):
     n_q, T_pad = sel.shape
-    assert n_q % QUERY_TILE == 0 and T_pad % 128 == 0
+    stripe = min(STRIPE, T_pad)
+    assert n_q % QUERY_TILE == 0 and T_pad % stripe == 0 and stripe % 128 == 0
+    assert 0 < k <= K_MAX and T_pad + K_MAX + stripe < 2 ** 24
     assert (w is not None) == dyn_weights
     assert (aff is not None) == use_aff
-    grid = (n_q // QUERY_TILE,)
+    n_stripes = T_pad // stripe
+    grid = (n_q // QUERY_TILE, n_stripes)
 
     def _row_spec(per_query: bool) -> pl.BlockSpec:
         return (
-            pl.BlockSpec((QUERY_TILE, T_pad), lambda i: (i, 0))
+            pl.BlockSpec((QUERY_TILE, stripe), lambda i, j: (i, j))
             if per_query
-            else pl.BlockSpec((1, T_pad), lambda i: (0, 0))
+            else pl.BlockSpec((1, stripe), lambda i, j: (0, j))
         )
 
     in_specs = [
-        pl.BlockSpec((QUERY_TILE, T_pad), lambda i: (i, 0)),
-        pl.BlockSpec((QUERY_TILE, T_pad), lambda i: (i, 0)),
+        pl.BlockSpec((QUERY_TILE, stripe), lambda i, j: (i, j)),
+        pl.BlockSpec((QUERY_TILE, stripe), lambda i, j: (i, j)),
         _row_spec(per_query_qos),
         _row_spec(per_query_load),
         _row_spec(per_query_rtt),
@@ -236,14 +178,15 @@ def fused_select_pallas(
         in_specs.append(_row_spec(per_query_aff))
         operands.append(aff)
     if dyn_weights:
-        in_specs.append(pl.BlockSpec((1, 128), lambda i: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, 128), lambda i, j: (0, 0)))
         operands.append(w)
 
-    out_spec = pl.BlockSpec((QUERY_TILE, 1), lambda i: (i, 0))
+    out_spec = pl.BlockSpec((QUERY_TILE, 1), lambda i, j: (i, 0))
     out_shape = jax.ShapeDtypeStruct((n_q, 1), jnp.float32)
     idx, c, n, s = pl.pallas_call(
         functools.partial(
-            _select_kernel, k=k, alpha=alpha, beta=beta, gamma=gamma,
+            _select_kernel, k=k, n_stripes=n_stripes, t_total=T_pad,
+            stripe=stripe, alpha=alpha, beta=beta, gamma=gamma,
             delta=delta, temp=temp, eps=eps, use_aff=use_aff,
             dyn_weights=dyn_weights,
         ),
@@ -254,6 +197,7 @@ def fused_select_pallas(
             jax.ShapeDtypeStruct((n_q, 1), jnp.int32),
             out_shape, out_shape, out_shape,
         ],
+        scratch_shapes=topk_scratch(use_aff),
         interpret=interpret,
     )(*operands)
     return idx[:, 0], c[:, 0], n[:, 0], s[:, 0]

@@ -46,6 +46,7 @@ import numpy as np
 from repro import configs
 from repro.core import latency as latlib
 from repro.models.api import get_model
+from repro.launch.cache import enable_compile_cache
 from repro.obs import LiveDashboard, Observability
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.frontend import AsyncServingGateway
@@ -117,13 +118,13 @@ QUERIES = [
 ]
 
 
-def serve_online(args) -> dict:
+def serve_online(args) -> tuple:
     """Run the asyncio micro-batch front-end over a live arrival stream.
 
     Requests from ``--arrivals`` at ``--rate`` rps are submitted to an
     `AsyncServingGateway` at their scheduled times (scaled by
     ``--time-scale``; >1 slows the replay down).  Returns the summary
-    dict that is also printed.
+    dict that is also printed, and the gateway that served the run.
     """
     obs = _build_obs(args)
     replicas = replica_pool([("yi-6b", "dense")] * args.n_replicas)
@@ -197,10 +198,10 @@ def serve_online(args) -> dict:
     summary["gateway_p99_ms"] = round(reg.get("gateway_latency_ms").p99, 2)
     _emit_artifacts(args, obs, summary)
     print("online serving summary:", summary)
-    return summary
+    return summary, gw
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default="internlm2-1.8b")
     ap.add_argument("--n-replicas", type=int, default=4)
@@ -233,8 +234,13 @@ def main():
                     help="write a Chrome trace (Perfetto-loadable) to PATH")
     ap.add_argument("--dashboard", action="store_true",
                     help="live text dashboard during --mode online")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     _setup_logging(args.quiet)
+    enable_compile_cache()
 
     if args.mode == "online":
         serve_online(args)

@@ -26,6 +26,8 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import adaptive as _adaptive
 from repro.core import latency as latlib
@@ -290,8 +292,16 @@ class SonarGateway:
         if device_telemetry is None:
             device_telemetry = bool(shards)
         self.telemetry_dtype = telemetry_dtype
+        ring_sharding = None
+        mesh = self.engine().mesh if shards and use_kernels else None
+        if mesh is not None:
+            # the ring lives beside the shards that read it: split over the
+            # fleet axis when the replicas divide evenly, else replicated
+            spec = P("fleet") if n % mesh.devices.size == 0 else P()
+            ring_sharding = NamedSharding(mesh, spec)
         self._telemetry = (
-            DeviceTelemetry(init, dtype=telemetry_dtype)
+            DeviceTelemetry(init, sharding=ring_sharding,
+                            dtype=telemetry_dtype)
             if device_telemetry
             else _HostTelemetry(init, dtype=telemetry_dtype)
         )
