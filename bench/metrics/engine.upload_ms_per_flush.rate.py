@@ -1,0 +1,7 @@
+from harness import flush_records
+
+
+def read(ctx):
+    """Engine upload ms per flush, summed over the flush's engine calls,
+    from the flush records of the window's answers."""
+    return flush_records.phase_ms(ctx, "engine.upload")

@@ -72,6 +72,7 @@ from repro.core import adaptive as _adaptive
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from repro.obs import trace as obs_trace
+from repro.obs.metrics import MetricsRegistry
 
 NEG = kref.NEG
 
@@ -246,159 +247,168 @@ def _route_pipeline(
     n_servers = w_server.shape[0]
     n_tools = w_tool.shape[0]
 
+    # each stage runs under a named scope, so a profile's op metadata says
+    # which stage a device op belongs to (instruction names do not change)
     # -- stage 1: server scores + top-s candidate mask (Eq. 1-2) --
-    if use_kernels:
-        s_scores = ops.bm25_scores(q_server, w_server, interpret=interpret)
-    else:
-        s_scores = bm25.bm25_scores(w_server, q_server)
-    # SONAR-FT: demote known-failed servers below every live one before
-    # the top-s, so failover escapes an all-dead candidate set (mirrors
-    # the scalar `_candidates` masking; NEG ties re-fill in index order)
-    if use_failover and dead_mask is not None:
-        dm_server = dead_mask.astype(jnp.float32)
-        if dm_server.ndim == 1:
-            dm_server = dm_server[None, :]
-        s_scores = jnp.where(dm_server > 0.0, NEG, s_scores)
-    _, cand_servers = jax.lax.top_k(s_scores, min(top_s, n_servers))
-    member = jnp.any(
-        cand_servers[:, :, None] == jnp.arange(n_servers)[None, None, :], axis=1
-    )                                                       # [n_q, n_servers]
-    in_cand = jnp.take(member, tool_server, axis=1)         # [n_q, n_tools]
+    with jax.named_scope("stage1_bm25"):
+        if use_kernels:
+            s_scores = ops.bm25_scores(q_server, w_server, interpret=interpret)
+        else:
+            s_scores = bm25.bm25_scores(w_server, q_server)
+        # SONAR-FT: demote known-failed servers below every live one before
+        # the top-s, so failover escapes an all-dead candidate set (mirrors
+        # the scalar `_candidates` masking; NEG ties re-fill in index order)
+        if use_failover and dead_mask is not None:
+            dm_server = dead_mask.astype(jnp.float32)
+            if dm_server.ndim == 1:
+                dm_server = dm_server[None, :]
+            s_scores = jnp.where(dm_server > 0.0, NEG, s_scores)
+        _, cand_servers = jax.lax.top_k(s_scores, min(top_s, n_servers))
+    with jax.named_scope("candidates"):
+        member = jnp.any(
+            cand_servers[:, :, None] == jnp.arange(n_servers)[None, None, :], axis=1
+        )                                                       # [n_q, n_servers]
+        in_cand = jnp.take(member, tool_server, axis=1)         # [n_q, n_tools]
 
     # -- stage 2: tool scores, masked outside candidate servers (Eq. 3-4),
     # plus the rerank re-valuation (RerankRAG).  Only the unfused path
     # materializes the [n_q, n_tools] matrices — the kernel path streams
     # them stripe-by-stripe inside `ops.fused_score_select` below --
-    if not use_kernels:
-        t_scores = bm25.bm25_scores(w_tool, q_tool)
-        sel = jnp.where(in_cand, t_scores, NEG)
-        val = bm25.bm25_scores(w_tool, q_rerank) if rerank else sel
+    with jax.named_scope("score_fuse"):
+        if not use_kernels:
+            t_scores = bm25.bm25_scores(w_tool, q_tool)
+            sel = jnp.where(in_cand, t_scores, NEG)
+            val = bm25.bm25_scores(w_tool, q_rerank) if rerank else sel
 
     # -- QoS N per tool (Eq. 6-7): Pallas kernel over the telemetry matrix --
-    if use_network and latency_hist is not None:
-        if latency_hist.ndim == 3:                          # per-query windows
-            n_q = latency_hist.shape[0]
-            flat = latency_hist.reshape(n_q * n_servers, latency_hist.shape[-1])
-            if use_kernels:
-                n_server = ops.qos_scores(flat, qos_params, interpret=interpret)
+    with jax.named_scope("qos"):
+        if use_network and latency_hist is not None:
+            if latency_hist.ndim == 3:                          # per-query windows
+                n_q = latency_hist.shape[0]
+                flat = latency_hist.reshape(n_q * n_servers, latency_hist.shape[-1])
+                if use_kernels:
+                    n_server = ops.qos_scores(flat, qos_params, interpret=interpret)
+                else:
+                    n_server = network_score(flat, qos_params)
+                n_server = n_server.reshape(n_q, n_servers)
             else:
-                n_server = network_score(flat, qos_params)
-            n_server = n_server.reshape(n_q, n_servers)
-        else:
-            if use_kernels:
-                n_server = ops.qos_scores(latency_hist, qos_params,
-                                          interpret=interpret)
+                if use_kernels:
+                    n_server = ops.qos_scores(latency_hist, qos_params,
+                                              interpret=interpret)
+                else:
+                    n_server = network_score(latency_hist, qos_params)
+            # SONAR-FT staleness discount: elementwise per-server multiply
+            # commutes with the per-tool gather below, so this matches the
+            # scalar router's per-candidate discount bit-for-bit.
+            if use_staleness and telemetry_age is not None:
+                n_server = n_server * staleness_discount(
+                    telemetry_age, stale_half_life
+                )
+            if n_server.ndim == 2:
+                tool_qos = jnp.take(n_server, tool_server, axis=1)  # [n_q, n_tools]
             else:
-                n_server = network_score(latency_hist, qos_params)
-        # SONAR-FT staleness discount: elementwise per-server multiply
-        # commutes with the per-tool gather below, so this matches the
-        # scalar router's per-candidate discount bit-for-bit.
-        if use_staleness and telemetry_age is not None:
-            n_server = n_server * staleness_discount(
-                telemetry_age, stale_half_life
-            )
-        if n_server.ndim == 2:
-            tool_qos = jnp.take(n_server, tool_server, axis=1)  # [n_q, n_tools]
+                tool_qos = n_server[tool_server]                # [n_tools]
+            # SONAR-ADAPT: the live weight vector replaces the static floats
+            # only on its *active* terms — inactive terms keep their structural
+            # literals, preserving the reduction identities below
+            if adapt_w is not None:
+                eff_alpha, eff_beta = adapt_w[0], adapt_w[1]
+            else:
+                eff_alpha, eff_beta = alpha, beta
         else:
-            tool_qos = n_server[tool_server]                # [n_tools]
-        # SONAR-ADAPT: the live weight vector replaces the static floats
-        # only on its *active* terms — inactive terms keep their structural
-        # literals, preserving the reduction identities below
-        if adapt_w is not None:
-            eff_alpha, eff_beta = adapt_w[0], adapt_w[1]
-        else:
-            eff_alpha, eff_beta = alpha, beta
-    else:
-        tool_qos = jnp.zeros((n_tools,), jnp.float32)
-        eff_alpha, eff_beta = 1.0, 0.0                      # S = C (scalar path)
+            tool_qos = jnp.zeros((n_tools,), jnp.float32)
+            eff_alpha, eff_beta = 1.0, 0.0                      # S = C (scalar path)
 
     # -- SONAR-LB load term: per-server utilization penalty, broadcast to
     # tools of the host server (shared [n_servers] or per-query) --
-    if use_load and server_load is not None:
-        pen = load_penalty(server_load, load_knee, load_sharp)
-        if server_load.ndim == 2:                           # [n_q, n_servers]
-            tool_load = jnp.take(pen, tool_server, axis=1)  # [n_q, n_tools]
+    with jax.named_scope("candidates"):
+        if use_load and server_load is not None:
+            pen = load_penalty(server_load, load_knee, load_sharp)
+            if server_load.ndim == 2:                           # [n_q, n_servers]
+                tool_load = jnp.take(pen, tool_server, axis=1)  # [n_q, n_tools]
+            else:
+                tool_load = pen[tool_server]                    # [n_tools]
+            eff_gamma = adapt_w[2] if adapt_w is not None else gamma
         else:
-            tool_load = pen[tool_server]                    # [n_tools]
-        eff_gamma = adapt_w[2] if adapt_w is not None else gamma
-    else:
-        tool_load = jnp.zeros((n_tools,), jnp.float32)
-        eff_gamma = 0.0
+            tool_load = jnp.zeros((n_tools,), jnp.float32)
+            eff_gamma = 0.0
 
-    # -- SONAR-GEO locality term: per-(client-region, server) RTT penalty,
-    # broadcast to tools of the host server.  The RTT arrives either as an
-    # explicit vector (shared [n_servers] or per-query [n_q, n_servers]) or
-    # as a per-request region index gathered from the [n_regions,
-    # n_servers] RTT matrix — the gather runs inside the jit pipeline. --
-    if use_rtt and (
-        client_rtt is not None
-        or (region_idx is not None and region_rtt is not None)
-    ):
-        if client_rtt is None:
-            # untagged requests carry region -1 (the simulator's sentinel):
-            # clamp the gather and zero their row — R(0) = 0, so they pay
-            # no locality penalty, matching the scalar path's convention
-            client_rtt = jnp.take(
-                region_rtt, jnp.maximum(region_idx, 0), axis=0
-            )
-            client_rtt = jnp.where(
-                (region_idx >= 0)[:, None], client_rtt, 0.0
-            )
-        pen_r = rtt_penalty(client_rtt, rtt_scale)
-        if client_rtt.ndim == 2:                            # [n_q, n_servers]
-            tool_rtt = jnp.take(pen_r, tool_server, axis=1)  # [n_q, n_tools]
+        # -- SONAR-GEO locality term: per-(client-region, server) RTT penalty,
+        # broadcast to tools of the host server.  The RTT arrives either as an
+        # explicit vector (shared [n_servers] or per-query [n_q, n_servers]) or
+        # as a per-request region index gathered from the [n_regions,
+        # n_servers] RTT matrix — the gather runs inside the jit pipeline. --
+        if use_rtt and (
+            client_rtt is not None
+            or (region_idx is not None and region_rtt is not None)
+        ):
+            if client_rtt is None:
+                # untagged requests carry region -1 (the simulator's sentinel):
+                # clamp the gather and zero their row — R(0) = 0, so they pay
+                # no locality penalty, matching the scalar path's convention
+                client_rtt = jnp.take(
+                    region_rtt, jnp.maximum(region_idx, 0), axis=0
+                )
+                client_rtt = jnp.where(
+                    (region_idx >= 0)[:, None], client_rtt, 0.0
+                )
+            pen_r = rtt_penalty(client_rtt, rtt_scale)
+            if client_rtt.ndim == 2:                            # [n_q, n_servers]
+                tool_rtt = jnp.take(pen_r, tool_server, axis=1)  # [n_q, n_tools]
+            else:
+                tool_rtt = pen_r[tool_server]                   # [n_tools]
+            eff_delta = adapt_w[3] if adapt_w is not None else delta
         else:
-            tool_rtt = pen_r[tool_server]                   # [n_tools]
-        eff_delta = adapt_w[3] if adapt_w is not None else delta
-    else:
-        tool_rtt = jnp.zeros((n_tools,), jnp.float32)
-        eff_delta = 0.0
+            tool_rtt = jnp.zeros((n_tools,), jnp.float32)
+            eff_delta = 0.0
 
-    # -- SONAR-SESSION sticky-affinity bonus: per-(session, server) warmth
-    # W in [0,1], broadcast to the host server's tools.  The warmth array
-    # is *data* (eps alone is static), so per-request affinity changes
-    # never recompile; when absent the term vanishes from the traced graph
-    # and the compiled program is byte-identical to SONAR-GEO's. --
-    if use_aff and affinity is not None:
-        if affinity.ndim == 2:                              # [n_q, n_servers]
-            tool_aff = jnp.take(affinity, tool_server, axis=1)
+        # -- SONAR-SESSION sticky-affinity bonus: per-(session, server) warmth
+        # W in [0,1], broadcast to the host server's tools.  The warmth array
+        # is *data* (eps alone is static), so per-request affinity changes
+        # never recompile; when absent the term vanishes from the traced graph
+        # and the compiled program is byte-identical to SONAR-GEO's. --
+        if use_aff and affinity is not None:
+            if affinity.ndim == 2:                              # [n_q, n_servers]
+                tool_aff = jnp.take(affinity, tool_server, axis=1)
+            else:
+                tool_aff = affinity[tool_server]                # [n_tools]
         else:
-            tool_aff = affinity[tool_server]                # [n_tools]
-    else:
-        tool_aff = None
+            tool_aff = None
 
-    # -- SONAR-FT failed-server mask, broadcast to the host server's tools --
-    if use_failover and dead_mask is not None:
-        dm = dead_mask.astype(jnp.float32)
-        if dm.ndim == 2:                                    # [n_q, n_servers]
-            tool_dead = jnp.take(dm, tool_server, axis=1)   # [n_q, n_tools]
+        # -- SONAR-FT failed-server mask, broadcast to the host server's tools --
+        if use_failover and dead_mask is not None:
+            dm = dead_mask.astype(jnp.float32)
+            if dm.ndim == 2:                                    # [n_q, n_servers]
+                tool_dead = jnp.take(dm, tool_server, axis=1)   # [n_q, n_tools]
+            else:
+                tool_dead = dm[tool_server]                     # [n_tools]
         else:
-            tool_dead = dm[tool_server]                     # [n_tools]
-    else:
-        tool_dead = None
+            tool_dead = None
 
     # -- fused stage-2 scoring + candidate top-k + Eq. 5 softmax + Eq. 8
     # fusion + argmax: one Pallas pass (kernels/score_fuse) on the kernel
     # path; the unfused jnp oracle otherwise --
-    if use_kernels:
-        tool_idx, c, n, s = ops.fused_score_select(
-            q_tool, w_tool, tool_server, cand_servers,
-            tool_qos, tool_load, tool_dead,
-            q_rerank if rerank else None,
-            k=top_k, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
-            tool_rtt=tool_rtt, delta=eff_delta,
-            tool_aff=tool_aff, eps=eps,
-            temp=temp, interpret=interpret,
-        )
-    else:
-        tool_idx, c, n, s = kref.fused_select_ref(
-            sel, val, tool_qos, tool_load, tool_dead,
-            k=top_k, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
-            tool_rtt=tool_rtt, delta=eff_delta,
-            tool_aff=tool_aff, eps=eps,
-            temp=temp,
-        )
-    server_idx = tool_server[tool_idx]
+    with jax.named_scope("score_fuse"):
+        if use_kernels:
+            tool_idx, c, n, s = ops.fused_score_select(
+                q_tool, w_tool, tool_server, cand_servers,
+                tool_qos, tool_load, tool_dead,
+                q_rerank if rerank else None,
+                k=top_k, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
+                tool_rtt=tool_rtt, delta=eff_delta,
+                tool_aff=tool_aff, eps=eps,
+                temp=temp, interpret=interpret,
+            )
+        else:
+            tool_idx, c, n, s = kref.fused_select_ref(
+                sel, val, tool_qos, tool_load, tool_dead,
+                k=top_k, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
+                tool_rtt=tool_rtt, delta=eff_delta,
+                tool_aff=tool_aff, eps=eps,
+                temp=temp,
+            )
+    with jax.named_scope("select"):
+        server_idx = tool_server[tool_idx]
     return server_idx, tool_idx, c, n, s
 
 
@@ -485,12 +495,24 @@ def _route_adaptive(
     return server_idx, tool_idx, c, n, s, new_state
 
 
+def engine_phase_histograms(registry: Optional[MetricsRegistry]) -> dict:
+    """The ``engine_phase_{upload,enqueue,readback}_ms`` histograms a
+    routing engine times each `route` call into: host arrays to device
+    operands, the jit call up to its (asynchronous) return, and the host
+    conversions of the outputs (waiting on the device, then D2H).  In
+    ``registry`` (a gateway's), or a private one."""
+    reg = registry if registry is not None else MetricsRegistry()
+    return {ph: reg.histogram(f"engine_phase_{ph}_ms", "ms")
+            for ph in ("upload", "enqueue", "readback")}
+
+
 class BatchRoutingEngine:
     """Vectorized drop-in for a fleet of `Router.select` calls.
 
     One engine per (server pool, algorithm, config); `encode` turns query
     strings into term-count matrices on the host, `route` runs the full
-    jit-compiled decision for the batch.
+    jit-compiled decision for the batch and times its phases into
+    ``registry`` (see `engine_phase_histograms`).
     """
 
     def __init__(
@@ -502,6 +524,7 @@ class BatchRoutingEngine:
         interpret: Optional[bool] = None,
         index: Optional[ToolIndex] = None,
         adapt: Optional[_adaptive.AdaptConfig] = None,
+        registry: Optional[MetricsRegistry] = None,
     ):
         if use_kernels is None:
             # The Pallas kernels are the fast path on TPU; on CPU they run
@@ -533,6 +556,7 @@ class BatchRoutingEngine:
         if self.algo == "sonar_adapt" or adapt is not None:
             self.adapt_cfg = adapt if adapt is not None else _adaptive.AdaptConfig()
             self.adapt_state = _adaptive.init_state(cfg, self.adapt_cfg)
+        self._m_phase = engine_phase_histograms(registry)
 
     # -- host side ----------------------------------------------------------
     def encode(self, queries: Sequence[str]) -> EncodedBatch:
@@ -671,38 +695,43 @@ class BatchRoutingEngine:
                 expertise=z, network=z, fused=z,
                 select_latency_ms=self.select_latency_ms(),
             )
-        operands, statics = self._pipeline_args(
-            batch, latency_hist, server_load, telemetry_age_s, failed_mask,
-            client_rtt_ms, client_region, region_rtt_ms, affinity,
-        )
-        if self.adapt_state is not None and self.adapt_cfg.lr != 0.0:
-            # fused update + route: one program, no extra dispatch.  At
-            # lr == 0 we fall through to the static path below, whose
-            # compiled program is byte-identical to the hand-tuned
-            # variant's (the weights can never leave their init).
-            fb_r, fb_f, fb_v = self._drain_feedback()
-            with obs_trace.annotate("netmcp.route_adaptive"):
+        adapting = self.adapt_state is not None and self.adapt_cfg.lr != 0.0
+        with obs_trace.annotate("engine.upload", self._m_phase["upload"]):
+            operands, statics = self._pipeline_args(
+                batch, latency_hist, server_load, telemetry_age_s,
+                failed_mask, client_rtt_ms, client_region, region_rtt_ms,
+                affinity,
+            )
+            if adapting:
+                fb_r, fb_f, fb_v = self._drain_feedback()
+        with obs_trace.annotate("engine.enqueue", self._m_phase["enqueue"]):
+            if adapting:
+                # fused update + route: one program, no extra dispatch.
+                # At lr == 0 we fall through to the static path below,
+                # whose compiled program is byte-identical to the
+                # hand-tuned variant's (the weights can never leave their
+                # init).
                 server_idx, tool_idx, c, n, s, self.adapt_state = (
                     _route_adaptive(
                         self.adapt_state, fb_r, fb_f, fb_v, *operands,
                         acfg=self.adapt_cfg, **statics,
                     )
                 )
-        else:
-            with obs_trace.annotate("netmcp.route_pipeline"):
+            else:
                 server_idx, tool_idx, c, n, s = _route_pipeline(
                     *operands, **statics,
                 )
-        if route_stats is not None:
-            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
-        return BatchDecisions(
-            server_idx=np.asarray(server_idx),
-            tool_idx=np.asarray(tool_idx),
-            expertise=np.asarray(c),
-            network=np.asarray(n),
-            fused=np.asarray(s),
-            select_latency_ms=self.select_latency_ms(),
-        )
+            if route_stats is not None:
+                route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
+        with obs_trace.annotate("engine.readback", self._m_phase["readback"]):
+            return BatchDecisions(
+                server_idx=np.asarray(server_idx),
+                tool_idx=np.asarray(tool_idx),
+                expertise=np.asarray(c),
+                network=np.asarray(n),
+                fused=np.asarray(s),
+                select_latency_ms=self.select_latency_ms(),
+            )
 
     def lower(self, batch: EncodedBatch, *args, **kw) -> jax.stages.Lowered:
         """The program `route` would run on these inputs (same arguments,

@@ -70,7 +70,12 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import adaptive as _adaptive
 from repro.core import bm25, quantize
-from repro.core.batch_routing import BatchDecisions, EncodedBatch, encode_for_index
+from repro.core.batch_routing import (
+    BatchDecisions,
+    EncodedBatch,
+    encode_for_index,
+    engine_phase_histograms,
+)
 from repro.obs import trace as obs_trace
 from repro.core.dataset import Server
 from repro.core.qos import (
@@ -920,6 +925,9 @@ class ShardedRoutingEngine:
     index : ToolIndex | TiledFleetIndex, optional
         Pre-built index; a `TiledFleetIndex` enables template-gathered
         scoring (no fleet-sized weight matrices anywhere).
+    registry : MetricsRegistry, optional
+        Where `route` times its upload, enqueue and readback phases (see
+        `batch_routing.engine_phase_histograms`).
     """
 
     def __init__(
@@ -934,9 +942,11 @@ class ShardedRoutingEngine:
         index=None,
         compact_stage2: Optional[bool] = None,
         adapt: Optional[_adaptive.AdaptConfig] = None,
+        registry=None,
     ):
         if use_kernels is None:
             use_kernels = jax.default_backend() == "tpu"
+        self._m_phase = engine_phase_histograms(registry)
         self.cfg = cfg
         self.algo = algo.lower().replace("-", "_")
         router_cls = ALGORITHMS[self.algo]
@@ -1188,26 +1198,28 @@ class ShardedRoutingEngine:
                 expertise=z, network=z, fused=z,
                 select_latency_ms=self.select_latency_ms(),
             )
-        dyn = self._dyn(
-            batch, latency_hist, server_load, telemetry_age_s, failed_mask,
-            client_rtt_ms, client_region, region_rtt_ms, affinity,
-            telemetry_templates=telemetry_templates,
-        )
-        with obs_trace.annotate("netmcp.route_sharded"):
+        with obs_trace.annotate("engine.upload", self._m_phase["upload"]):
+            dyn = self._dyn(
+                batch, latency_hist, server_load, telemetry_age_s,
+                failed_mask, client_rtt_ms, client_region, region_rtt_ms,
+                affinity, telemetry_templates=telemetry_templates,
+            )
+        with obs_trace.annotate("engine.enqueue", self._m_phase["enqueue"]):
             server_idx, tool_idx, c, n, s = _route_sharded(
                 dyn, mesh=self.mesh, sc=self._sc
             )
-        if route_stats is not None:
-            # fold this call's device outputs into the jit-safe stats
-            # buffer (donated .at[].add) before any host conversion
-            route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
-        return BatchDecisions(
-            server_idx=np.asarray(server_idx, np.int32),
-            tool_idx=np.asarray(tool_idx, np.int32),
-            expertise=np.asarray(c), network=np.asarray(n),
-            fused=np.asarray(s),
-            select_latency_ms=self.select_latency_ms(),
-        )
+            if route_stats is not None:
+                # fold this call's device outputs into the jit-safe stats
+                # buffer (donated .at[].add) before any host conversion
+                route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
+        with obs_trace.annotate("engine.readback", self._m_phase["readback"]):
+            return BatchDecisions(
+                server_idx=np.asarray(server_idx, np.int32),
+                tool_idx=np.asarray(tool_idx, np.int32),
+                expertise=np.asarray(c), network=np.asarray(n),
+                fused=np.asarray(s),
+                select_latency_ms=self.select_latency_ms(),
+            )
 
     def lower(self, batch: EncodedBatch, *args, **kw) -> jax.stages.Lowered:
         """The program `route` would run on these inputs (same arguments,

@@ -4,9 +4,8 @@ One `SpanTracer` records the full lifecycle of every request through the
 serving stack — admission, queue wait, encode, device dispatch, merge,
 failover hops, completion — as *complete* ("X") trace events on a single
 timeline, plus instant ("i") events for discrete occurrences (sheds,
-expiries, chaos fault injections) and counter ("C") events for live
-series.  `to_chrome_trace()` emits the Trace Event Format JSON that
-Perfetto / chrome://tracing load directly.
+expiries, chaos fault injections).  `to_chrome_trace()` emits the Trace
+Event Format JSON that Perfetto / chrome://tracing load directly.
 
 Design rules (the observability layer must cost ~nothing when off):
 
@@ -21,21 +20,25 @@ Design rules (the observability layer must cost ~nothing when off):
     its wall clock, the discrete-event simulator its sim clock.  Export
     converts to the microseconds Chrome expects.
 
-`annotate(name)` is the `jax.profiler` hook: when profiler annotations
-are enabled (see `enable_jax_annotations`), the jit/Pallas hot paths run
-inside a `jax.profiler.TraceAnnotation`, so an `xprof`/TensorBoard
-profile shows routing phases by name.  Disabled, it is one module-level
-boolean check.
+`annotate(name, histogram)` is the one phase primitive of the served
+path.  It times its block on `time.perf_counter`, adds the milliseconds to
+a registry histogram (exact count and sum) and to the `FlushRecord` of the
+flush being routed, and, while `enable_jax_annotations` is on, opens a
+`jax.profiler.TraceAnnotation` of the same name, so a profile shows each
+program phase on the device's clock.  Off, it costs two clock reads and
+one ``observe``.
 """
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import json
 import time
 from typing import Callable, Optional, Sequence
 
 __all__ = [
     "NULL_TRACER",
+    "FlushRecord",
     "SpanTracer",
     "annotate",
     "emit_chaos_events",
@@ -43,6 +46,7 @@ __all__ = [
     "emit_request_spans",
     "enable_jax_annotations",
     "jax_annotations_enabled",
+    "recording",
 ]
 
 
@@ -167,17 +171,6 @@ class SpanTracer:
             ev["args"] = args
         self._push(ev)
 
-    def counter(self, name: str, values: dict,
-                t_ms: Optional[float] = None, *, tid=0) -> None:
-        """Record a counter sample (rendered as a stacked series)."""
-        if not self.enabled:
-            return
-        self._push({
-            "name": name, "ph": "C",
-            "ts": 1000.0 * (self.clock_ms() if t_ms is None else t_ms),
-            "pid": self.pid, "tid": tid, "args": dict(values),
-        })
-
     # -- export --------------------------------------------------------------
     def to_chrome_trace(self) -> dict:
         """Trace Event Format payload (Perfetto / chrome://tracing)."""
@@ -208,18 +201,21 @@ NULL_TRACER = SpanTracer(enabled=False)
 
 
 # ---------------------------------------------------------------------------
-# jax.profiler annotation hook (the jit/Pallas hot-path marker)
+# Phases: histogram + flush record + jax.profiler annotation
 # ---------------------------------------------------------------------------
 
 _JAX_ANNOTATIONS = False
 
 
 def enable_jax_annotations(on: bool = True) -> None:
-    """Toggle `jax.profiler.TraceAnnotation` wrapping of the routing hot
-    paths (`BatchRoutingEngine.route`, `ShardedRoutingEngine.route`, the
-    telemetry-ring push).  Off (the default), `annotate` is a single
-    boolean check; on, an `xprof` profile captured around serving shows
-    the device work attributed to named routing phases."""
+    """Toggle the `jax.profiler.TraceAnnotation` that every `annotate`
+    phase opens: the front end's ``frontend.flush``, ``frontend.submit``
+    and ``frontend.resolve``; the gateway's ``gateway.encode``,
+    ``gateway.dispatch``, ``gateway.merge`` and ``gateway.ring_push``; the
+    engines' ``engine.upload``, ``engine.enqueue`` and ``engine.readback``;
+    `ServeEngine`'s ``netmcp.prefill`` and ``netmcp.decode_step``.  On, an
+    `xprof` profile captured around serving names each host gap after the
+    program phase that was open."""
     global _JAX_ANNOTATIONS
     _JAX_ANNOTATIONS = bool(on)
 
@@ -228,16 +224,98 @@ def jax_annotations_enabled() -> bool:
     return _JAX_ANNOTATIONS
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Wrap a jit dispatch in a profiler annotation when enabled."""
-    if _JAX_ANNOTATIONS:
-        import jax
+class FlushRecord:
+    """Server timing of one flush, shared by the answers it routed.
 
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    else:
-        yield
+    ``index`` is the flush's number (the parent id of its requests'
+    spans); ``t_start_ms`` / ``t_end_ms`` its interval on the serving
+    clock (the pump's virtual one, the asyncio front end's wall one);
+    ``gap_ms`` the time from the end of the previous flush to its start
+    where the batcher held requests when that flush ended (else None).
+    ``spans`` holds each phase the flush ran as (name, start ms from the
+    flush's start, ms); ``phases`` sums them by name (engine phases over
+    the flush's chunks, the ring push over its completions).  Make the
+    record when the flush starts: offsets count from then.
+    """
+
+    __slots__ = ("index", "t_start_ms", "t_end_ms", "gap_ms", "spans", "_t0")
+
+    def __init__(self, index: int, t_start_ms: float,
+                 gap_ms: Optional[float] = None):
+        self.index = index
+        self.t_start_ms = t_start_ms
+        self.t_end_ms = t_start_ms
+        self.gap_ms = gap_ms
+        self.spans: list = []
+        self._t0 = time.perf_counter()
+
+    def add(self, name: str, t0_s: float, t1_s: float) -> None:
+        self.spans.append((name, 1000.0 * (t0_s - self._t0),
+                           1000.0 * (t1_s - t0_s)))
+
+    @property
+    def phases(self) -> dict:
+        """Phase name -> ms, summed over the flush."""
+        out: dict = {}
+        for name, _, ms in self.spans:
+            out[name] = out.get(name, 0.0) + ms
+        return out
+
+
+_FLUSH: contextvars.ContextVar = contextvars.ContextVar("netmcp_flush",
+                                                        default=None)
+
+
+@contextlib.contextmanager
+def recording(rec: FlushRecord):
+    """Make ``rec`` the flush that phases in this context add to, inside a
+    ``frontend.flush`` profiler span."""
+    with annotate("frontend.flush"):
+        token = _FLUSH.set(rec)
+        try:
+            yield rec
+        finally:
+            _FLUSH.reset(token)
+
+
+class _Phase:
+    __slots__ = ("name", "hist", "ms", "_t0", "_span")
+
+    def __init__(self, name: str, histogram=None):
+        self.name = name
+        self.hist = histogram
+        self.ms = 0.0
+        self._span = None
+
+    def __enter__(self):
+        if _JAX_ANNOTATIONS:
+            import jax
+
+            self._span = jax.profiler.TraceAnnotation(self.name)
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ms = 1000.0 * (t1 - self._t0)
+        if self.hist is not None:
+            self.hist.observe(self.ms)
+        rec = _FLUSH.get()
+        if rec is not None:
+            rec.add(self.name, self._t0, t1)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return False
+
+
+def annotate(name: str, histogram=None) -> _Phase:
+    """Time a block as the phase ``name``: into ``histogram`` (ms) when
+    given, into the current flush's record when one is open (see
+    `recording`), and into the profiler when annotations are on.  The
+    returned object holds the block's milliseconds in ``ms`` after it
+    exits."""
+    return _Phase(name, histogram)
 
 
 # ---------------------------------------------------------------------------
@@ -246,41 +324,28 @@ def annotate(name: str):
 
 def emit_flush_spans(
     tracer: SpanTracer,
-    t0_ms: float,
-    t1_ms: float,
-    phases: Sequence[tuple],
+    rec: FlushRecord,
     rids: Sequence[int],
     *,
     tid=0,
-    flush_idx: Optional[int] = None,
 ) -> None:
-    """Emit one flush's span tree: a parent ``flush`` span over
-    [t0, t1] and child phase spans (encode / dispatch / merge) that
-    **tile the interval exactly** — phase durations (measured wall ms
-    inside `SonarGateway.route_batch`) are rescaled so their sum equals
-    the caller-observed flush duration, and the last phase absorbs the
-    rounding remainder.  Tiling is what lets tests assert that
-    per-request span sums reproduce the measured end-to-end latency.
-    """
+    """Emit one flush's span tree: a parent ``flush`` span over the
+    record's [t_start_ms, t_end_ms] and every measured phase at its
+    measured offset from the flush's start, with its measured duration
+    (engine phases nest inside ``gateway.dispatch``, ring pushes inside
+    ``gateway.merge``).  The flush's self time is what its phases leave
+    uncovered.  Where the pump's virtual service time (``service_ms``) is
+    shorter than the measured work, phases are cut at the flush's end."""
     if not tracer.enabled:
         return
-    args = {"rids": list(rids), "batch": len(rids)}
-    if flush_idx is not None:
-        args["flush"] = flush_idx
-    tracer.add_span("flush", t0_ms, t1_ms, cat="serving", tid=tid, args=args)
-    total = sum(max(d, 0.0) for _, d in phases)
-    span_ms = max(t1_ms - t0_ms, 0.0)
-    if total <= 0.0 or span_ms <= 0.0:
-        return
-    scale = span_ms / total
-    cur = t0_ms
-    for j, (name, dur) in enumerate(phases):
-        end = t1_ms if j == len(phases) - 1 else cur + max(dur, 0.0) * scale
-        tracer.add_span(
-            name, cur, end, cat="serving", tid=tid,
-            args=None if flush_idx is None else {"flush": flush_idx},
-        )
-        cur = end
+    t0, t1 = rec.t_start_ms, rec.t_end_ms
+    tracer.add_span("flush", t0, t1, cat="serving", tid=tid,
+                    args={"rids": list(rids), "batch": len(rids),
+                          "flush": rec.index})
+    for name, offset_ms, ms in rec.spans:
+        a = min(t0 + offset_ms, t1)
+        tracer.add_span(name, a, min(a + ms, t1), cat="serving", tid=tid,
+                        args={"flush": rec.index})
 
 
 def emit_request_spans(

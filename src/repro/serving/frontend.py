@@ -23,11 +23,13 @@ import asyncio
 import time
 from typing import Optional
 
+from repro.obs import trace as obs_trace
 from repro.serving.microbatch import (
     BatchingPolicy,
     MicroBatcher,
     ServeResult,
     _emit_flush_trace,
+    flush_gap_ms,
 )
 from repro.traffic.source import LiveRequest
 
@@ -65,6 +67,7 @@ class AsyncServingGateway:
             "serving_flushes_total", "flushes"
         )
         self._m_serve = self.obs.registry.histogram("serving_latency_ms", "ms")
+        self._m_gap = self.obs.registry.histogram("serving_flush_gap_ms", "ms")
         if self.obs.tracer.enabled:
             # wall-clock timeline: ms since this front-end started
             self.obs.tracer.clock_ms = self.now_ms
@@ -76,6 +79,7 @@ class AsyncServingGateway:
         self._drain = True
         self._t0 = time.monotonic()
         self.n_flushes = 0
+        self._last_end_ms: Optional[float] = None
 
     def now_ms(self) -> float:
         """Wall-clock ms since the gateway was constructed."""
@@ -99,24 +103,25 @@ class AsyncServingGateway:
             await self.start()
         if self._closing:
             raise RuntimeError("gateway is closing")
-        now = self.now_ms()
-        rid = self._next_rid
-        self._next_rid += 1
-        req = LiveRequest(
-            rid=rid, text=text, t_ms=now,
-            deadline_ms=None if deadline_ms is None else now + deadline_ms,
-            region=region, session_id=session_id,
-        )
-        fut = asyncio.get_running_loop().create_future()
-        if self.batcher.offer(req, now):
-            self._futures[rid] = fut
-            self._wake.set()
-        else:
-            self.obs.tracer.instant("shed", now, args={"rid": rid})
-            fut.set_result(ServeResult(
-                rid=rid, shed=True, t_arrival_ms=now,
-                t_routed_ms=now, t_done_ms=now,
-            ))
+        with obs_trace.annotate("frontend.submit"):
+            now = self.now_ms()
+            rid = self._next_rid
+            self._next_rid += 1
+            req = LiveRequest(
+                rid=rid, text=text, t_ms=now,
+                deadline_ms=None if deadline_ms is None else now + deadline_ms,
+                region=region, session_id=session_id,
+            )
+            fut = asyncio.get_running_loop().create_future()
+            if self.batcher.offer(req, now):
+                self._futures[rid] = fut
+                self._wake.set()
+            else:
+                self.obs.tracer.instant("shed", now, args={"rid": rid})
+                fut.set_result(ServeResult(
+                    rid=rid, shed=True, t_arrival_ms=now,
+                    t_routed_ms=now, t_done_ms=now,
+                ))
         return await fut
 
     async def close(self, drain: bool = True) -> None:
@@ -160,6 +165,7 @@ class AsyncServingGateway:
 
     async def _flush(self, loop) -> None:
         now = self.now_ms()
+        oldest = self.batcher.oldest_ms()
         batch = self.batcher.take(now)
         for req in self.batcher.take_expired():
             self._resolve_dropped(req, shed=False, now=now)
@@ -175,33 +181,40 @@ class AsyncServingGateway:
             if any(r.session_id is not None for r in batch) else None
         )
         pad = self.policy.max_batch if self.policy.pad_batches else None
-        routed = await loop.run_in_executor(
-            None, lambda: self.gw.route_batch(
-                texts, client_regions=regions, pad_to=pad, session_ids=sids
-            )
-        )
+        gap = flush_gap_ms(self._last_end_ms, oldest, now)
+        if gap is not None:
+            self._m_gap.observe(gap)
+        rec = obs_trace.FlushRecord(self.n_flushes, now, gap)
+
+        def route():
+            with obs_trace.recording(rec):
+                return self.gw.route_batch(
+                    texts, client_regions=regions, pad_to=pad,
+                    session_ids=sids,
+                )
+
+        routed = await loop.run_in_executor(None, route)
         done = self.now_ms()
-        # flush boundary: dispatch deferred device-stat updates outside
-        # the per-request latency window
-        self.obs.drain_route_stats()
-        fidx = self.n_flushes
-        self.n_flushes += 1
-        self._m_flushes.inc()
-        tracer = self.obs.tracer
-        if tracer.enabled:
-            _emit_flush_trace(
-                tracer, fidx, batch, routed, now, done - now,
-                list(self.gw.last_flush_phases),
-            )
-        for req, res in zip(batch, routed):
-            self._m_serve.observe(done - req.t_ms)
-            fut = self._futures.pop(req.rid, None)
-            if fut is not None and not fut.done():
-                fut.set_result(ServeResult(
-                    rid=req.rid, replica_idx=res.replica_idx, ok=res.ok,
-                    latency_ms=res.latency_ms, t_arrival_ms=req.t_ms,
-                    t_routed_ms=now, t_done_ms=done, batch_size=len(batch),
-                ))
+        rec.t_end_ms = self._last_end_ms = done
+        with obs_trace.annotate("frontend.resolve"):
+            # flush boundary: dispatch deferred device-stat updates
+            # outside the per-request latency window
+            self.obs.drain_route_stats()
+            self.n_flushes += 1
+            self._m_flushes.inc()
+            tracer = self.obs.tracer
+            if tracer.enabled:
+                _emit_flush_trace(tracer, rec, batch, routed)
+            for req, res in zip(batch, routed):
+                self._m_serve.observe(done - req.t_ms)
+                fut = self._futures.pop(req.rid, None)
+                if fut is not None and not fut.done():
+                    fut.set_result(ServeResult(
+                        rid=req.rid, replica_idx=res.replica_idx, ok=res.ok,
+                        latency_ms=res.latency_ms, t_arrival_ms=req.t_ms,
+                        t_routed_ms=now, t_done_ms=done,
+                        batch_size=len(batch), flush=rec,
+                    ))
 
     def _resolve_dropped(self, req, *, shed: bool,
                          now: Optional[float] = None) -> None:

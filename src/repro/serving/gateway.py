@@ -20,7 +20,6 @@ telemetry window to a device-resident ring buffer advanced in place
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -37,6 +36,7 @@ from repro.core.mesh_routing import ShardedRoutingEngine
 from repro.core.qos import load_penalty, rtt_penalty
 from repro.core.routing import ALGORITHMS, RoutingConfig, SonarRouter  # noqa: F401
 from repro.obs import Observability
+from repro.obs import trace as obs_trace
 from repro.sessions.warmth import WarmthTracker
 
 ARCH_CAPABILITIES = {
@@ -267,6 +267,12 @@ class SonarGateway:
             else np.asarray(region_rtt_ms, np.float32)
         )
         self._engine = None
+        # observability: all gateway accounting lives in the registry
+        # (report() reads it back — one source of truth shared with the
+        # micro-batcher / front-end / engine layers bound to the same
+        # bundle); the device-side route stats are threaded through the
+        # batched engines when obs.jit_stats is on.
+        self.obs = obs if obs is not None else Observability()
         n = len(self.replicas)
         # in-flight accounting: callers running concurrent traffic use
         # begin()/finish() so the utilization the load term sees tracks
@@ -307,12 +313,6 @@ class SonarGateway:
         )
         self.t = history
         self.stats: list = []
-        # observability: all gateway accounting lives in the registry
-        # (report() reads it back — one source of truth shared with the
-        # micro-batcher / front-end / engine layers bound to the same
-        # bundle); the device-side route stats are threaded through the
-        # batched engines when obs.jit_stats is on.
-        self.obs = obs if obs is not None else Observability()
         reg = self.obs.registry
         self._m_requests = reg.counter("gateway_requests_total", "req")
         self._m_failures = reg.counter("gateway_failures_total", "req")
@@ -326,14 +326,13 @@ class SonarGateway:
             "gateway_unmatched_finish_total", "req"
         )
         self._m_ejected = reg.gauge("gateway_ejected", "replicas")
+        # per-flush walls of route_batch's phases, and the ring push per
+        # completion on every path
         self._m_phase = {
             ph: reg.histogram(f"gateway_phase_{ph}_ms", "ms")
-            for ph in ("encode", "dispatch", "merge")
+            for ph in ("encode", "dispatch", "merge", "ring_push")
         }
         self._route_stats = self.obs.ensure_route_stats(n)
-        # per-flush phase durations (wall ms), for span emission by the
-        # serving drivers: [("encode", ms), ("dispatch", ms), ("merge", ms)]
-        self.last_flush_phases: list = []
         # SONAR-ADAPT: live weight-trajectory surface.  The scalar router
         # (route/begin+finish) and the batched engine (route_batch) each
         # hold learner state; the gauges publish whichever one last moved.
@@ -370,11 +369,14 @@ class SonarGateway:
         return self._telemetry.host()
 
     def _observe(self, idx: int, latency_ms: float):
-        col = np.array(
-            self.traces[:, min(self.t, self.traces.shape[1] - 1)], np.float32
-        )
-        col[idx] = latency_ms
-        self._telemetry.push(col)
+        with obs_trace.annotate("gateway.ring_push",
+                                self._m_phase["ring_push"]):
+            col = np.array(
+                self.traces[:, min(self.t, self.traces.shape[1] - 1)],
+                np.float32,
+            )
+            col[idx] = latency_ms
+            self._telemetry.push(col)
         self.t += 1
 
     def _utilization(self) -> np.ndarray:
@@ -638,12 +640,12 @@ class SonarGateway:
                 self._engine = ShardedRoutingEngine(
                     self.replicas, self.router.cfg, algo=self.algo,
                     n_shards=self.shards, mesh=self._mesh_opt,
-                    index=self.router.index,
+                    index=self.router.index, registry=self.obs.registry,
                 )
             else:
                 self._engine = BatchRoutingEngine(
                     self.replicas, self.router.cfg, algo=self.algo,
-                    index=self.router.index,
+                    index=self.router.index, registry=self.obs.registry,
                 )
         return self._engine
 
@@ -709,9 +711,8 @@ class SonarGateway:
             session_ids is not None
             and getattr(self.router, "uses_affinity", False)
         )
-        t_phase = time.perf_counter()
-        enc = eng.encode(request_texts)
-        encode_ms = 1000.0 * (time.perf_counter() - t_phase)
+        with obs_trace.annotate("gateway.encode", self._m_phase["encode"]):
+            enc = eng.encode(request_texts)
         dispatch_ms = 0.0
         picks: list = []
         chunked = self.router.uses_load and len(self.replicas) > 1
@@ -753,16 +754,16 @@ class SonarGateway:
                         warm_any = True
                 if not warm_any:
                     aff = None
-            t_phase = time.perf_counter()
-            dec = eng.route(
-                sub, self._telemetry.raw(), self._utilization(),
-                failed_mask=mask,
-                affinity=aff,
-                route_stats=self._route_stats,
-                n_real=n_chunk if sub.n != n_chunk else None,
-                **geo_kw,
-            )
-            dispatch_ms += 1000.0 * (time.perf_counter() - t_phase)
+            with obs_trace.annotate("gateway.dispatch") as ph:
+                dec = eng.route(
+                    sub, self._telemetry.raw(), self._utilization(),
+                    failed_mask=mask,
+                    affinity=aff,
+                    route_stats=self._route_stats,
+                    n_real=n_chunk if sub.n != n_chunk else None,
+                    **geo_kw,
+                )
+            dispatch_ms += ph.ms
             adapting = getattr(eng, "adapt_state", None) is not None
             for qi in range(n_chunk):
                 idx = int(dec.server_idx[qi])
@@ -778,32 +779,28 @@ class SonarGateway:
                 self._m_in_flight.inc()
                 sid = None if session_ids is None else session_ids[lo + qi]
                 picks.append((idx, expertise, network, feats, sid))
-        t_phase = time.perf_counter()
-        out = []
-        for idx, expertise, network, feats, sid in picks:
-            latency = float(self.traces[idx, min(self.t, self.traces.shape[1] - 1)])
-            ok = latency < latlib.OFFLINE_MS
-            self._record_outcome(idx, ok)
-            self._observe(idx, latency)
-            self._session_touch(sid, idx, ok)
-            if feats is not None:
-                eng.observe_feedback(latency, ok=ok, feats=feats)
-            self.in_flight[idx] = max(self.in_flight[idx] - 1.0, 0.0)
-            self._m_in_flight.dec()
-            out.append(self._account(RouteResult(
-                replica_idx=idx, latency_ms=latency, ok=ok,
-                expertise=expertise, network=network,
-            )))
-        if getattr(eng, "adapt_state", None) is not None:
-            self._publish_adapt(eng.adapt_state)
-        merge_ms = 1000.0 * (time.perf_counter() - t_phase)
-        self.last_flush_phases = [
-            ("encode", encode_ms), ("dispatch", dispatch_ms),
-            ("merge", merge_ms),
-        ]
-        self._m_phase["encode"].observe(encode_ms)
+        # one observe per flush: the chunks' dispatch walls summed
         self._m_phase["dispatch"].observe(dispatch_ms)
-        self._m_phase["merge"].observe(merge_ms)
+        out = []
+        with obs_trace.annotate("gateway.merge", self._m_phase["merge"]):
+            for idx, expertise, network, feats, sid in picks:
+                latency = float(
+                    self.traces[idx, min(self.t, self.traces.shape[1] - 1)]
+                )
+                ok = latency < latlib.OFFLINE_MS
+                self._record_outcome(idx, ok)
+                self._observe(idx, latency)
+                self._session_touch(sid, idx, ok)
+                if feats is not None:
+                    eng.observe_feedback(latency, ok=ok, feats=feats)
+                self.in_flight[idx] = max(self.in_flight[idx] - 1.0, 0.0)
+                self._m_in_flight.dec()
+                out.append(self._account(RouteResult(
+                    replica_idx=idx, latency_ms=latency, ok=ok,
+                    expertise=expertise, network=network,
+                )))
+            if getattr(eng, "adapt_state", None) is not None:
+                self._publish_adapt(eng.adapt_state)
         return out
 
     def report(self) -> dict:

@@ -49,7 +49,13 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.obs.trace import SpanTracer, emit_flush_spans, emit_request_spans
+from repro.obs.trace import (
+    FlushRecord,
+    SpanTracer,
+    emit_flush_spans,
+    emit_request_spans,
+    recording,
+)
 from repro.traffic.source import LiveRequest
 
 __all__ = [
@@ -119,7 +125,9 @@ class ServeResult:
     are **ms** on the caller's clock (virtual for the pump, wall for the
     asyncio front-end); ``wait_ms = t_routed_ms - t_arrival_ms`` is the
     queueing delay and ``latency_ms`` the replica's observed network
-    latency from the gateway's feed-forward record.
+    latency from the gateway's feed-forward record.  ``flush`` is the
+    server timing of the flush that routed it, one `FlushRecord` shared
+    by the flush's answers (None when shed or expired).
     """
 
     rid: int
@@ -132,6 +140,9 @@ class ServeResult:
     batch_size: int = 0
     shed: bool = False
     expired: bool = False
+    flush: Optional[FlushRecord] = dataclasses.field(
+        default=None, compare=False, repr=False
+    )
 
     @property
     def wait_ms(self) -> float:
@@ -193,6 +204,10 @@ class MicroBatcher:
     @property
     def n_pending(self) -> int:
         return len(self._pending)
+
+    def oldest_ms(self) -> Optional[float]:
+        """Arrival time of the oldest pending request (None when empty)."""
+        return self._pending[0].t_ms if self._pending else None
 
     def offer(self, req: LiveRequest, now_ms: float) -> bool:
         """Admit one arriving request; returns False (and accounts a
@@ -281,20 +296,27 @@ class MicroBatcher:
             )
 
 
-def _emit_flush_trace(tracer, fidx, batch, routed, t_flush_ms, busy_ms,
-                      phases) -> None:
+def flush_gap_ms(last_end_ms: Optional[float], oldest_ms: Optional[float],
+                 now_ms: float) -> Optional[float]:
+    """ms from the end of the previous flush to the start of this one, or
+    None where the batcher held no request when that flush ended (the
+    oldest request pending at this flush's start, ``oldest_ms``, had not
+    arrived by then) or where there was no previous flush."""
+    if last_end_ms is None or oldest_ms is None or oldest_ms > last_end_ms:
+        return None
+    return now_ms - last_end_ms
+
+
+def _emit_flush_trace(tracer, rec, batch, routed) -> None:
     """One flush's spans: the flush+phase tree on the serving track and
-    serve/queue_wait per request.  Pure function of flush-log data, so
-    the live trace and `MicroBatchPump.replay_spans` emit identical
-    events."""
-    emit_flush_spans(
-        tracer, t_flush_ms, t_flush_ms + busy_ms, phases,
-        [r.rid for r in batch], flush_idx=fidx,
-    )
+    serve/queue_wait per request.  Pure function of the flush record and
+    its batch, so the live trace and `MicroBatchPump.replay_spans` emit
+    identical events."""
+    emit_flush_spans(tracer, rec, [r.rid for r in batch])
     for req, res in zip(batch, routed):
         emit_request_spans(
-            tracer, req.rid, req.t_ms, t_flush_ms, t_flush_ms + busy_ms,
-            replica_idx=res.replica_idx, flush_idx=fidx,
+            tracer, req.rid, req.t_ms, rec.t_start_ms, rec.t_end_ms,
+            replica_idx=res.replica_idx, flush_idx=rec.index,
         )
 
 
@@ -349,8 +371,7 @@ class MicroBatchPump:
         self.batcher = MicroBatcher(policy, registry=self.obs.registry)
         self._service_ms = service_ms
         self.flush_log: list = []     # list[list[LiveRequest]] actually routed
-        self.flush_times: list = []   # [(t_flush_ms, busy_ms)] per flush
-        self.flush_phases: list = []  # per-flush gateway phase durations
+        self.flush_records: list = []  # list[FlushRecord], one per flush
         self.weight_log: list = []    # [(flush_idx, [a, b, g, d])] when the
                                       # gateway routes with SONAR-ADAPT
         self.results: dict = {}       # rid -> ServeResult
@@ -359,6 +380,7 @@ class MicroBatchPump:
             "serving_flushes_total", "flushes"
         )
         self._m_serve = self.obs.registry.histogram("serving_latency_ms", "ms")
+        self._m_gap = self.obs.registry.histogram("serving_flush_gap_ms", "ms")
         if self.obs.tracer.enabled:
             # spans land on the pump's virtual timeline, aligned with the
             # gateway's health instants (ejection/readmission)
@@ -369,6 +391,7 @@ class MicroBatchPump:
         """Form and route one micro-batch at virtual time ``now_ms``;
         returns the engine-busy duration in virtual ms (0.0 when the take
         yielded nothing to route)."""
+        oldest = self.batcher.oldest_ms()
         batch = self.batcher.take(now_ms)
         tracer = self.obs.tracer
         for req in self.batcher.take_expired():
@@ -389,10 +412,16 @@ class MicroBatchPump:
             if any(r.session_id is not None for r in batch) else None
         )
         pad = self.policy.max_batch if self.policy.pad_batches else None
+        prev = self.flush_records[-1].t_end_ms if self.flush_records else None
+        gap = flush_gap_ms(prev, oldest, now_ms)
+        if gap is not None:
+            self._m_gap.observe(gap)
+        rec = FlushRecord(len(self.flush_log), now_ms, gap)
         t0 = time.perf_counter()
-        routed = self.gw.route_batch(
-            texts, client_regions=regions, pad_to=pad, session_ids=sids
-        )
+        with recording(rec):
+            routed = self.gw.route_batch(
+                texts, client_regions=regions, pad_to=pad, session_ids=sids
+            )
         wall_ms = 1000.0 * (time.perf_counter() - t0)
         # device-stat fold boundary — after the timed window, so the
         # deferred jit dispatches never land in a measured flush
@@ -401,10 +430,10 @@ class MicroBatchPump:
             wall_ms if self._service_ms is None else
             float(self._service_ms(texts))
         )
-        fidx = len(self.flush_log)
+        fidx = rec.index
+        rec.t_end_ms = now_ms + busy_ms
         self.flush_log.append(batch)
-        self.flush_times.append((now_ms, busy_ms))
-        self.flush_phases.append(list(self.gw.last_flush_phases))
+        self.flush_records.append(rec)
         self._m_flushes.inc()
         eng = getattr(self.gw, "_engine", None)
         state = getattr(eng, "adapt_state", None) if eng is not None else None
@@ -427,14 +456,11 @@ class MicroBatchPump:
                 rid=req.rid, replica_idx=res.replica_idx, ok=res.ok,
                 latency_ms=res.latency_ms, t_arrival_ms=req.t_ms,
                 t_routed_ms=now_ms, t_done_ms=now_ms + busy_ms,
-                batch_size=len(batch),
+                batch_size=len(batch), flush=rec,
             )
             self._m_serve.observe(now_ms + busy_ms - req.t_ms)
         if tracer.enabled:
-            _emit_flush_trace(
-                tracer, fidx, batch, routed, now_ms, busy_ms,
-                self.flush_phases[-1],
-            )
+            _emit_flush_trace(tracer, rec, batch, routed)
         return busy_ms
 
     # -- driver --------------------------------------------------------------
@@ -487,19 +513,15 @@ class MicroBatchPump:
 
     def replay_spans(self) -> SpanTracer:
         """Deterministically rebuild the flush/request span timeline from
-        `flush_log` (+ recorded flush times/phases and results) into a
-        fresh tracer.  Emits exactly the events the live trace recorded
+        `flush_log` (+ the flush records and results) into a fresh
+        tracer.  Emits exactly the events the live trace recorded
         (the live path and this replay share `_emit_flush_trace`), so a
         replay of a replay is byte-identical — tested in
         tests/test_obs.py."""
         tracer = SpanTracer(enabled=True, clock_ms=lambda: 0.0)
-        for fidx, batch in enumerate(self.flush_log):
-            t_flush, busy = self.flush_times[fidx]
+        for rec, batch in zip(self.flush_records, self.flush_log):
             routed = [self.results[r.rid] for r in batch]
-            _emit_flush_trace(
-                tracer, fidx, batch, routed, t_flush, busy,
-                self.flush_phases[fidx],
-            )
+            _emit_flush_trace(tracer, rec, batch, routed)
         return tracer
 
     def report(self) -> PumpReport:

@@ -1,8 +1,8 @@
 """Observability invariants (docs/observability.md).
 
-Covers: Chrome-trace export validity, span nesting/monotonicity, the
-phase-tiling identity (queue_wait + encode + dispatch + merge == serve,
-per request), deterministic span replay from `MicroBatchPump.flush_log`,
+Covers: Chrome-trace export validity, span nesting/monotonicity, phase
+spans at their measured offsets (phases + flush self time == flush, and
+queue_wait + flush == serve, per request), deterministic span replay from `MicroBatchPump.flush_log`,
 metrics<->accounting conservation (property-tested against
 `MicroBatcher.check_accounting`), jit-safe `DeviceRouteStats` (padding
 exclusion + deferred drain), the unified `SonarGateway.report()` source
@@ -120,7 +120,6 @@ def test_tracer_disabled_and_bounded_buffer():
     off = SpanTracer(enabled=False)
     off.add_span("x", 0.0, 1.0)
     off.instant("y")
-    off.counter("z", {"v": 1})
     with off.span("w"):
         pass
     assert off.events == []
@@ -177,30 +176,59 @@ def _spans(events, name, **match):
     return out
 
 
+TOP_PHASES = ("gateway.encode", "gateway.dispatch", "gateway.merge")
+NESTED = {"engine.upload": "gateway.dispatch",
+          "engine.enqueue": "gateway.dispatch",
+          "engine.readback": "gateway.dispatch",
+          "gateway.ring_push": "gateway.merge"}
+
+
+def _inside(ev, parents):
+    return any(p["ts"] - 1e-6 <= ev["ts"]
+               and ev["ts"] + ev["dur"] <= p["ts"] + p["dur"] + 1e-6
+               for p in parents)
+
+
 def test_span_nesting_and_phase_tiling(pump_run):
+    """Phases sit at their measured offsets in the flush: the flush's own
+    phases are in order, inside it and non-overlapping, and their sum plus
+    the flush's self time (what they leave uncovered) is its duration;
+    engine phases nest in a dispatch, ring pushes in the merge."""
     obs, _, pump, rep = pump_run
     events = obs.tracer.events
     for fidx in range(rep.n_flushes):
         (flush,) = _spans(events, "flush", flush=fidx)
+        rec = pump.flush_records[fidx]
         t0, t1 = flush["ts"], flush["ts"] + flush["dur"]
-        phases = [
-            _spans(events, ph, flush=fidx)[0]
-            for ph in ("encode", "dispatch", "merge")
-        ]
-        # contiguous, monotone, nested, and tiling the flush exactly
-        cur = t0
-        for ev in phases:
-            assert np.isclose(ev["ts"], cur, rtol=1e-9, atol=1e-3)
-            assert ev["dur"] >= 0.0
+        assert np.isclose(flush["dur"] / 1000.0, rec.t_end_ms - rec.t_start_ms)
+        top = sorted((e for ph in TOP_PHASES for e in _spans(events, ph, flush=fidx)),
+                     key=lambda e: e["ts"])
+        names = [e["name"] for e in top]
+        assert names[0] == "gateway.encode" and names[-1] == "gateway.merge"
+        assert set(names[1:-1]) == {"gateway.dispatch"}
+        self_us, cur = 0.0, t0
+        for ev in top:
+            assert ev["ts"] >= cur - 1e-6 and ev["dur"] >= 0.0
+            self_us += ev["ts"] - cur
             cur = ev["ts"] + ev["dur"]
-        assert np.isclose(cur, t1, rtol=1e-9, atol=1e-3)
-        total = sum(ev["dur"] for ev in phases)
-        assert np.isclose(total, flush["dur"], rtol=1e-9, atol=1e-3)
+        assert cur <= t1 + 1e-6
+        self_us += t1 - cur
+        assert np.isclose(sum(e["dur"] for e in top) + self_us, flush["dur"],
+                          rtol=1e-9, atol=1e-3)
+        # measured, not rescaled: each span is its phase's measured time
+        for ph in TOP_PHASES:
+            assert np.isclose(sum(e["dur"] for e in _spans(events, ph, flush=fidx)) / 1000.0,
+                              rec.phases[ph], rtol=1e-9, atol=1e-6)
+        for child, parent in NESTED.items():
+            kids = _spans(events, child, flush=fidx)
+            assert kids
+            parents = _spans(events, parent, flush=fidx)
+            assert all(_inside(k, parents) for k in kids)
 
 
 def test_request_spans_sum_to_e2e_latency(pump_run):
-    """Acceptance identity: per-request queue_wait + encode + dispatch +
-    merge spans reproduce the measured end-to-end serve latency."""
+    """Acceptance identity: per request, queue_wait + the flush it rode
+    == serve, and both reproduce the measured end-to-end serve latency."""
     obs, _, pump, rep = pump_run
     events = obs.tracer.events
     routed = [r for r in rep.results if not (r.shed or r.expired)]
@@ -214,16 +242,16 @@ def test_request_spans_sum_to_e2e_latency(pump_run):
             e for e in _spans(events, "queue_wait") if e["tid"] == res.rid
         ]
         fidx = serve["args"]["flush"]
-        phase_ms = sum(
-            _spans(events, ph, flush=fidx)[0]["dur"]
-            for ph in ("encode", "dispatch", "merge")
-        ) / 1000.0
-        total_ms = wait["dur"] / 1000.0 + phase_ms
+        assert res.flush is pump.flush_records[fidx]
+        (flush,) = _spans(events, "flush", flush=fidx)
+        total_ms = (wait["dur"] + flush["dur"]) / 1000.0
         assert np.isclose(total_ms, res.serve_ms, rtol=1e-9, atol=1e-6)
         assert np.isclose(serve["dur"] / 1000.0, res.serve_ms,
                           rtol=1e-9, atol=1e-6)
         # nesting: queue_wait starts with serve, ends at the flush start
         assert wait["ts"] == serve["ts"]
+        assert np.isclose(wait["ts"] + wait["dur"], flush["ts"],
+                          rtol=1e-9, atol=1e-3)
         assert wait["ts"] + wait["dur"] <= serve["ts"] + serve["dur"] + 1e-3
     # shed / expired requests are instants, not spans
     names = [e["name"] for e in events if e["ph"] == "i"]
@@ -233,8 +261,7 @@ def test_request_spans_sum_to_e2e_latency(pump_run):
 
 def test_replay_spans_reproduces_live_trace(pump_run):
     obs, _, pump, _ = pump_run
-    span_names = {"flush", "encode", "dispatch", "merge",
-                  "serve", "queue_wait"}
+    span_names = {"flush", "serve", "queue_wait", *TOP_PHASES, *NESTED}
     live = [e for e in obs.tracer.events if e["name"] in span_names]
     replayed = pump.replay_spans().events
     assert live == replayed
@@ -268,6 +295,132 @@ def test_async_frontend_emits_the_same_span_taxonomy():
                           rtol=1e-9, atol=1e-3)
         assert wait["dur"] <= sp["dur"] + 1e-3
     assert obs.registry.value("serving_offered_total") == len(TEXTS)
+
+
+# ---------------------------------------------------------------------------
+# Flush records: the server timing of each flush, on every answer
+# ---------------------------------------------------------------------------
+
+ENGINE_PHASES = ("engine.upload", "engine.enqueue", "engine.readback")
+
+
+def _chunked_gateway(obs):
+    """sonar_lb over three replicas with two-row chunks: every flush of
+    four makes two engine calls."""
+    replicas = replica_pool([("yi-6b", "dense")] * 3)
+    return SonarGateway(
+        replicas, profiles=[latlib.ideal_profile()] * 3, algo="sonar_lb",
+        use_kernels=True, device_telemetry=True, lb_chunk=2, obs=obs,
+    )
+
+
+def _serve_burst(gw, frontend, n, straggler=False):
+    """``n`` requests offered at once through ``frontend``, and with
+    ``straggler`` one more after the gateway went idle; the results."""
+    policy = BatchingPolicy(max_batch=4, max_wait_ms=1.0, queue_limit=64,
+                            pad_batches=True)
+    texts = [TEXTS[i % len(TEXTS)] for i in range(n)]
+    if frontend == "pump":
+        pump = MicroBatchPump(gw, policy)
+        sched = [LiveRequest(rid=i, text=t, t_ms=0.0)
+                 for i, t in enumerate(texts)]
+        if straggler:
+            sched.append(LiveRequest(rid=n, text=TEXTS[0], t_ms=60_000.0))
+        return pump.replay(sched).results
+
+    async def drive():
+        srv = AsyncServingGateway(gw, policy)
+        await srv.start()
+        res = await asyncio.gather(*[srv.submit(t) for t in texts])
+        if straggler:
+            await asyncio.sleep(0.02)
+            res.append(await srv.submit(TEXTS[0]))
+        await srv.close()
+        return res
+
+    return asyncio.run(drive())
+
+
+@pytest.mark.parametrize("frontend", ["pump", "async"])
+def test_flush_records_account_every_phase(frontend):
+    obs = Observability()
+    gw = _chunked_gateway(obs)
+    eng = gw.engine()
+    calls = [0]
+    route = eng.route
+
+    def counted(*a, **kw):
+        calls[0] += 1
+        return route(*a, **kw)
+
+    eng.route = counted
+    results = _serve_burst(gw, frontend, 14, straggler=True)
+    routed = [r for r in results if not (r.shed or r.expired)]
+    assert len(routed) == 15 and all(r.flush is not None for r in routed)
+    reg = obs.registry
+    for ph in ("upload", "enqueue", "readback"):
+        assert reg.get(f"engine_phase_{ph}_ms").count == calls[0]
+    assert reg.get("gateway_phase_ring_push_ms").count == len(routed)
+    recs = sorted({id(r.flush): r.flush for r in routed}.values(),
+                  key=lambda r: r.index)
+    assert [r.index for r in recs] == list(range(len(recs))) and len(recs) >= 4
+    assert reg.get("gateway_phase_dispatch_ms").count == len(recs)
+    for res in routed:
+        assert (res.t_routed_ms, res.t_done_ms) == (res.flush.t_start_ms,
+                                                    res.flush.t_end_ms)
+    for rec in recs:
+        p = rec.phases
+        assert all(p[ph] > 0.0 for ph in ENGINE_PHASES)
+        assert sum(p[ph] for ph in ENGINE_PHASES) <= p["gateway.dispatch"]
+        assert 0.0 < p["gateway.ring_push"] <= p["gateway.merge"]
+    # requests waited at the end of every burst flush but the last; the
+    # straggler came to an idle gateway
+    assert recs[0].gap_ms is None and recs[-1].gap_ms is None
+    assert all(r.gap_ms is not None and r.gap_ms >= 0.0 for r in recs[1:-1])
+    gaps = reg.get("serving_flush_gap_ms")
+    assert gaps.count == len(recs) - 2
+    assert np.isclose(gaps.total, sum(r.gap_ms for r in recs[1:-1]))
+
+
+@pytest.fixture
+def jax_annotations():
+    from repro.obs import trace as obs_trace
+
+    obs_trace.enable_jax_annotations(True)
+    try:
+        yield
+    finally:
+        obs_trace.enable_jax_annotations(False)
+
+
+def test_phase_spans_reach_the_profiler_timeline(jax_annotations, tmp_path):
+    """With annotations on, the phases are host spans of the profiler's
+    own trace (the clock of the device ops), each inside its flush's
+    ``frontend.flush`` span on the thread that routed the flush."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench"))
+    from harness import tracefile
+
+    gw = _chunked_gateway(Observability())
+    _serve_burst(gw, "async", 4)            # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        results = _serve_burst(gw, "async", 12)
+    n_flushes = len({id(r.flush) for r in results})
+    host = tracefile.load(str(tmp_path))["host"]
+    flushes = [(t, s, s + d) for t, name, s, d in host
+               if name == "frontend.flush"]
+    assert len(flushes) == n_flushes
+    for name in ("gateway.encode", "engine.readback", "gateway.ring_push"):
+        spans = [(t, s, s + d) for t, n, s, d in host if n == name]
+        assert spans, name
+        for t, a, b in spans:
+            assert any(t == ft and fa <= a and b <= fb
+                       for ft, fa, fb in flushes), name
+    encodes = [s for t, n, s, d in host if n == "gateway.encode"]
+    assert len(encodes) == n_flushes
 
 
 # ---------------------------------------------------------------------------
