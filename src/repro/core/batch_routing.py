@@ -198,15 +198,17 @@ def encode_for_index(
         "top_s", "top_k", "alpha", "beta", "gamma", "load_knee", "load_sharp",
         "delta", "rtt_scale", "temp", "stale_half_life", "use_network",
         "use_load", "use_staleness", "use_failover", "use_rtt", "use_aff",
-        "eps", "rerank", "use_kernels", "qos_params", "interpret",
+        "eps", "rerank", "use_kernels", "qos_params", "interpret", "n_servers",
     ),
 )
 def _route_pipeline(
     q_server: jax.Array,          # [n_q, V_s]
     q_tool: jax.Array,            # [n_q, V_t]
     q_rerank: Optional[jax.Array],
-    w_server: jax.Array,          # [n_servers, V_s]
-    w_tool: jax.Array,            # [n_tools, V_t]
+    w_server: jax.Array,          # [n_servers, V_s] (kernel path: zero-
+                                  # padded to ops.bm25_corpus_shape)
+    w_tool: jax.Array,            # [n_tools, V_t] (kernel path: zero-padded
+                                  # to ops.score_fuse_corpus_shape)
     tool_server: jax.Array,       # [n_tools] i32
     latency_hist: Optional[jax.Array],  # [n_servers, T] or [n_q, n_servers, T]
     server_load: Optional[jax.Array],   # [n_servers] or [n_q, n_servers] rho
@@ -243,16 +245,18 @@ def _route_pipeline(
     use_kernels: bool,
     qos_params: QosParams,
     interpret: Optional[bool],
+    n_servers: int,
 ):
-    n_servers = w_server.shape[0]
-    n_tools = w_tool.shape[0]
+    # the real counts: the kernel path's corpora carry zero rows past them
+    n_tools = tool_server.shape[0]
 
     # each stage runs under a named scope, so a profile's op metadata says
     # which stage a device op belongs to (instruction names do not change)
     # -- stage 1: server scores + top-s candidate mask (Eq. 1-2) --
     with jax.named_scope("stage1_bm25"):
         if use_kernels:
-            s_scores = ops.bm25_scores(q_server, w_server, interpret=interpret)
+            s_scores = ops.bm25_scores(q_server, w_server, n_docs=n_servers,
+                                       interpret=interpret)
         else:
             s_scores = bm25.bm25_scores(w_server, q_server)
         # SONAR-FT: demote known-failed servers below every live one before
@@ -419,6 +423,7 @@ def _route_pipeline(
         "delta", "rtt_scale", "temp", "stale_half_life", "use_network",
         "use_load", "use_staleness", "use_failover", "use_rtt", "use_aff",
         "eps", "rerank", "use_kernels", "qos_params", "interpret", "acfg",
+        "n_servers",
     ),
     donate_argnums=(0,),
 )
@@ -466,6 +471,7 @@ def _route_adaptive(
     use_kernels: bool,
     qos_params: QosParams,
     interpret: Optional[bool],
+    n_servers: int,
 ):
     """SONAR-ADAPT hot path: ONE jit program that applies the pending EG
     update and routes the batch with the freshly-updated weights.  The
@@ -490,7 +496,7 @@ def _route_adaptive(
         use_staleness=use_staleness, use_failover=use_failover,
         use_rtt=use_rtt, use_aff=use_aff, eps=eps,
         rerank=rerank, use_kernels=use_kernels,
-        qos_params=qos_params, interpret=interpret,
+        qos_params=qos_params, interpret=interpret, n_servers=n_servers,
     )
     return server_idx, tool_idx, c, n, s, new_state
 
@@ -512,7 +518,10 @@ class BatchRoutingEngine:
     One engine per (server pool, algorithm, config); `encode` turns query
     strings into term-count matrices on the host, `route` runs the full
     jit-compiled decision for the batch and times its phases into
-    ``registry`` (see `engine_phase_histograms`).
+    ``registry`` (see `engine_phase_histograms`).  The gauge
+    ``engine_corpus_pad_bytes_per_call`` holds the corpus bytes the route
+    program pads per call (`ops.corpus_pad_bytes`): 0 on both paths, the
+    kernel path storing its corpora aligned.
     """
 
     def __init__(
@@ -546,8 +555,22 @@ class BatchRoutingEngine:
         self.interpret = interpret
         self.index = index if index is not None else ToolIndex(servers)
         self._tool_server = jnp.asarray(self.index.tool_server)
-        self._w_server = jnp.asarray(self.index.server_corpus.weights)
-        self._w_tool = jnp.asarray(self.index.tool_corpus.weights)
+        w_server = self.index.server_corpus.weights
+        w_tool = self.index.tool_corpus.weights
+        self.n_servers = int(w_server.shape[0])
+        if use_kernels:
+            # stored once at the shapes the kernels read, so no route call
+            # pads or relays the corpora (zero rows and terms are exact)
+            self._w_server = ops.bm25_corpus(w_server)
+            self._w_tool = ops.score_fuse_corpus(w_tool)
+            pad_bytes = ops.corpus_pad_bytes(self._w_server.shape,
+                                             self._w_tool.shape)
+        else:
+            self._w_server = jnp.asarray(w_server)
+            self._w_tool = jnp.asarray(w_tool)
+            pad_bytes = 0
+        reg = registry if registry is not None else MetricsRegistry()
+        reg.gauge("engine_corpus_pad_bytes_per_call", "B").set(pad_bytes)
         # SONAR-ADAPT learner state (None for the hand-tuned algorithms)
         self.adapt_cfg: Optional[_adaptive.AdaptConfig] = None
         self.adapt_state: Optional[_adaptive.AdaptState] = None
@@ -556,7 +579,7 @@ class BatchRoutingEngine:
         if self.algo == "sonar_adapt" or adapt is not None:
             self.adapt_cfg = adapt if adapt is not None else _adaptive.AdaptConfig()
             self.adapt_state = _adaptive.init_state(cfg, self.adapt_cfg)
-        self._m_phase = engine_phase_histograms(registry)
+        self._m_phase = engine_phase_histograms(reg)
 
     # -- host side ----------------------------------------------------------
     def encode(self, queries: Sequence[str]) -> EncodedBatch:
@@ -805,6 +828,7 @@ class BatchRoutingEngine:
             use_kernels=self.use_kernels,
             qos_params=self.cfg.qos,
             interpret=self.interpret,
+            n_servers=self.n_servers,
         )
         operands = (
             jnp.asarray(batch.q_server),
@@ -863,8 +887,7 @@ class BatchRoutingEngine:
         decisions and the per-query failover counts."""
         budget = self.cfg.failover_budget if budget is None else int(budget)
         n = batch.n
-        n_servers = int(self._w_server.shape[0])
-        mask = np.zeros((n, n_servers), bool)
+        mask = np.zeros((n, self.n_servers), bool)
         if failed_mask is not None:
             mask |= np.asarray(failed_mask, bool)
         up = None if alive is None else np.asarray(alive, bool)

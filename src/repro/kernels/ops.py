@@ -1,8 +1,10 @@
 """Public jit'd entry points for the Pallas kernels.
 
 These wrappers own all padding/alignment bookkeeping so callers (the SONAR
-router, the serving attention layers) use natural shapes.  On CPU (this
-container) the kernels execute in interpret mode; on TPU they compile to
+router, the serving attention layers) use natural shapes.  A caller that
+keeps a constant corpus may store it once at the shape the kernel reads
+(`bm25_corpus`, `score_fuse_corpus`), and the per-call pad then vanishes.
+On CPU the kernels execute in interpret mode; on TPU they compile to
 Mosaic.  `interpret=None` auto-selects by backend.
 """
 from __future__ import annotations
@@ -56,6 +58,69 @@ def _pad_rows(x, per_query: bool, lanes: int, q_tile: int, value=0.0):
     return _pad_to(x, 0, q_tile, value=value) if per_query else x
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
+# ---------------------------------------------------------------------------
+# Corpus matrices stored at the shapes the kernels read
+# ---------------------------------------------------------------------------
+
+def bm25_corpus_shape(n_docs: int, V: int) -> tuple:
+    """The shape `bm25_scores` pads a [n_docs, V] corpus to."""
+    return _round_up(n_docs, _bm25.BD), _round_up(V, _bm25.BV)
+
+
+def score_fuse_corpus_shape(n_tools: int, V: int) -> tuple:
+    """The shape `fused_score_select` pads a [n_tools, V] corpus to."""
+    return _round_up(n_tools, _scf.STRIPE), _round_up(V, 128)
+
+
+def corpus_pad_bytes(server_shape, tool_shape) -> int:
+    """Bytes the kernel wrappers pad per call for f32 server and tool
+    corpora stored at these shapes: each padded corpus's aligned size, 0
+    for one stored aligned."""
+    out = 0
+    for shape, aligned in ((server_shape, bm25_corpus_shape(*server_shape)),
+                           (tool_shape, score_fuse_corpus_shape(*tool_shape))):
+        if tuple(shape) != aligned:
+            out += 4 * aligned[0] * aligned[1]
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(dst: jax.Array, rows: jax.Array, lo: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_slice(dst, rows, (lo, 0))
+
+
+def _place_aligned(w: np.ndarray, shape: tuple, chunk_rows: int) -> jax.Array:
+    """``w`` zero-padded to ``shape`` on the default device, written in
+    blocks of ``chunk_rows`` rows (a divisor of ``shape[0]``): no padded
+    host copy and no unpadded device copy of the whole matrix exists."""
+    w = np.asarray(w)
+    n, V = w.shape
+    dst = jnp.zeros(shape, w.dtype)
+    for lo in range(0, n, chunk_rows):
+        hi = min(lo + chunk_rows, n)
+        block = np.zeros((chunk_rows, shape[1]), w.dtype)
+        block[: hi - lo, :V] = w[lo:hi]
+        dst = _write_rows(dst, block, np.int32(lo))
+    return dst
+
+
+def bm25_corpus(w: np.ndarray) -> jax.Array:
+    """A [n_docs, V] host corpus on the device at `bm25_corpus_shape`,
+    which `bm25_scores` reads with no pad."""
+    return _place_aligned(w, bm25_corpus_shape(*w.shape), _bm25.BD)
+
+
+def score_fuse_corpus(w: np.ndarray) -> jax.Array:
+    """A [n_tools, V] host corpus on the device at
+    `score_fuse_corpus_shape`, which `fused_score_select` reads with no
+    pad."""
+    return _place_aligned(w, score_fuse_corpus_shape(*w.shape), _scf.STRIPE)
+
+
 # ---------------------------------------------------------------------------
 # QoS
 # ---------------------------------------------------------------------------
@@ -89,14 +154,17 @@ def qos_scores(
 
 def bm25_scores(
     qcounts: jax.Array,  # [n_q, V]
-    weights: jax.Array,  # [n_docs, V]
+    weights: jax.Array,  # [n_docs, V], or stored at `bm25_corpus_shape`
     *,
+    n_docs: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """scores [n_q, n_docs]; exact match of core.bm25.bm25_scores.
-    Zero padding is exact for BM25 (absent terms contribute zero)."""
+    Zero padding is exact for BM25 (absent terms contribute zero), so the
+    corpus may arrive with zero rows and columns past the real ``n_docs``
+    (default ``weights.shape[0]``) documents and V terms."""
     n_q, V = qcounts.shape
-    n_d = weights.shape[0]
+    n_d = weights.shape[0] if n_docs is None else n_docs
     q = _pad_to(_pad_to(jnp.asarray(qcounts, jnp.float32), 1, _bm25.BV), 0, _bm25.BQ)
     w = _pad_to(_pad_to(jnp.asarray(weights, jnp.float32), 1, _bm25.BV), 0, _bm25.BD)
     out = _bm25.bm25_scores_pallas(q, w, interpret=_auto_interpret(interpret))
@@ -208,7 +276,8 @@ def fused_select(
 
 def fused_score_select(
     q_tool: jax.Array,        # [n_q, V] stage-2 query term counts (f32/bf16)
-    w_tool: jax.Array,        # [n_tools, V] tool corpus weights (f32/bf16)
+    w_tool: jax.Array,        # [n_tools, V] tool corpus weights (f32/bf16),
+                              # or stored at `score_fuse_corpus_shape`
     tool_server: jax.Array,   # [n_tools] i32 host server per tool
     cand_servers: jax.Array,  # [n_q, top_s] i32 stage-1 candidates
     tool_qos: jax.Array,      # [n_q, n_tools] or [n_tools] per-tool N
@@ -234,9 +303,10 @@ def fused_score_select(
     stripes hosting no candidate tools).  Decision parity with
     `bm25_scores` + `fused_select` / `kernels.ref.fused_select_ref`; bf16
     operands are upcast to f32 exactly at block load (the quantized
-    carve-out in docs/benchmarks.md)."""
+    carve-out in docs/benchmarks.md).  The real tool count is
+    ``tool_server``'s length; rows of ``w_tool`` past it are zero padding."""
     n_q, V = q_tool.shape
-    n_t, top_s = w_tool.shape[0], cand_servers.shape[1]
+    n_t, top_s = tool_server.shape[0], cand_servers.shape[1]
     k = min(k, n_t)
     assert k <= _scf.K_MAX and top_s <= 128
 

@@ -84,6 +84,108 @@ def test_batched_kernel_path_matches_scalar():
         )
 
 
+# ---------------------------------------------------------------------------
+# Kernel path: corpora stored at the kernels' shapes
+# ---------------------------------------------------------------------------
+
+def _natural_corpora(engine):
+    """Give the engine's route program the corpora at their natural
+    shapes, so the kernel wrappers pad them inside every call."""
+    import jax.numpy as jnp
+
+    engine._w_server = jnp.asarray(engine.index.server_corpus.weights)
+    engine._w_tool = jnp.asarray(engine.index.tool_corpus.weights)
+
+
+def _corpus_pads(text: str, engine) -> list:
+    """(operand, result) shapes of the lowered program's pads whose operand
+    has a corpus's rows and terms, each real or kernel-aligned."""
+    import re
+
+    ws = engine.index.server_corpus.weights.shape
+    wt = engine.index.tool_corpus.weights.shape
+    sizes = [
+        tuple(zip(ws, ops.bm25_corpus_shape(*ws))),
+        tuple(zip(wt, ops.score_fuse_corpus_shape(*wt))),
+    ]
+    pads = re.findall(
+        r"stablehlo\.pad .*: \(tensor<(\d+)x(\d+)x\w+>, [^)]*\) -> "
+        r"tensor<(\d+)x(\d+)x(\w+)>", text)
+    return [((int(a), int(b)), (int(c), int(d), dt)) for a, b, c, d, dt in pads
+            if any(int(a) in rows and int(b) in cols for rows, cols in sizes)]
+
+
+@pytest.mark.parametrize("algo", ["sonar", "sonar_lb", "sonar_ft", "rerank_rag"])
+def test_aligned_corpora_match_natural_shapes(algo):
+    """The kernel path stores its corpora zero-padded to the kernels'
+    shapes; its decisions equal the same engine's with the corpora at
+    their natural shapes (padded inside each call instead).  No axis of
+    this pool's corpora is tile-aligned."""
+    plat = platform.NetMCPPlatform(SERVERS, scenario="hybrid", seed=1)
+    hist = plat.latency_window(3000)
+    engine = make_engine(algo, SERVERS, use_kernels=True, interpret=True)
+    ws = engine.index.server_corpus.weights.shape
+    wt = engine.index.tool_corpus.weights.shape
+    assert engine._w_server.shape == ops.bm25_corpus_shape(*ws) != ws
+    assert engine._w_tool.shape == ops.score_fuse_corpus_shape(*wt) != wt
+    batch = engine.encode(QUERY_TEXTS[:16])
+    n = len(SERVERS)
+    load = np.linspace(0.0, 1.5, n).astype(np.float32)
+
+    if algo == "sonar_ft":
+        first = engine.route(batch, hist, load)
+        alive = np.ones(n, bool)
+        alive[first.server_idx[:4]] = False
+        dead = np.zeros(n, bool)
+        dead[int(first.server_idx[5])] = True
+
+        def decide():
+            dec, fo = engine.route_failover(batch, hist, load, alive=alive,
+                                            failed_mask=dead)
+            assert fo.sum() > 0
+            return dec
+    else:
+        def decide():
+            return engine.route(batch, hist, load if algo == "sonar_lb" else None)
+
+    aligned = decide()
+    _natural_corpora(engine)
+    natural = decide()
+    for f in ("server_idx", "tool_idx", "expertise", "network", "fused"):
+        np.testing.assert_array_equal(getattr(aligned, f), getattr(natural, f),
+                                      err_msg=f"{algo}: {f}")
+
+
+def test_route_program_pads_no_corpus():
+    """No pad of the lowered kernel-path route program takes a corpus, and
+    the engine's gauge says so; the same program given the natural shapes
+    pads each corpus to the aligned shape, the bytes the gauge's formula
+    gives for those shapes."""
+    from repro.obs.metrics import MetricsRegistry
+
+    plat = platform.NetMCPPlatform(SERVERS, scenario="hybrid", seed=1)
+    hist = plat.latency_window(3000)
+    reg = MetricsRegistry()
+    engine = make_engine("sonar_lb", SERVERS, use_kernels=True, interpret=True,
+                         registry=reg)
+    args = (engine.encode(QUERY_TEXTS[:8]), hist, np.zeros(len(SERVERS)))
+    assert _corpus_pads(engine.lower(*args).as_text(), engine) == []
+    gauge = reg.get("engine_corpus_pad_bytes_per_call")
+    assert gauge is not None and gauge.value == 0.0
+
+    ws = engine.index.server_corpus.weights.shape
+    wt = engine.index.tool_corpus.weights.shape
+    _natural_corpora(engine)
+    pads = _corpus_pads(engine.lower(*args).as_text(), engine)
+    assert len(pads) == 4                    # rows and terms of each corpus
+    aligned = {ops.bm25_corpus_shape(*ws), ops.score_fuse_corpus_shape(*wt)}
+    final = [(r, c, dt) for _, (r, c, dt) in pads if (r, c) in aligned]
+    assert len(final) == 2 and {dt for *_, dt in final} == {"f32"}
+    assert ops.corpus_pad_bytes(ws, wt) == sum(4 * r * c for r, c, _ in final)
+    assert ops.corpus_pad_bytes(ops.bm25_corpus_shape(*ws),
+                                ops.score_fuse_corpus_shape(*wt)) == 0
+
+
 def test_batched_respects_config_and_exposes_scores():
     cfg = RoutingConfig(top_s=3, top_k=6, alpha=0.7, beta=0.3)
     plat = platform.NetMCPPlatform(SERVERS, scenario="fluctuating", seed=2)

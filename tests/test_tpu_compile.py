@@ -11,12 +11,17 @@ compile needs only shapes.  Widths:
 * mega — the 10^6-server tiled fleet: 2M tools, a 32-sample window;
 * shard — one of 4 mesh shards of that fleet: 500k tools, 250k servers.
 
+Each width also comes as the engine stores it (``-aligned``): the corpus
+at the kernel's aligned shape, its real count below its rows; the compiled
+program must not pad or copy it.
+
 16 queries, V=123 terms, top_s=8, k=16.  The topology is described in a
 fixture, so only the worker that runs these tests loads the TPU library;
 the persistent compile cache is off around the compiles (a compile for
 a described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +39,11 @@ WIDTHS = {
     "shard": (500_000, 250_000, 32),
 }
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+# each width with its corpus at the natural shape, and as the engine stores
+# it: already at the kernel's aligned shape, the real count below its rows
+CASES = ([pytest.param(w, False, id=w) for w in WIDTHS]
+         + [pytest.param(w, True, id=f"{w}-aligned") for w in WIDTHS])
+SHORT = 37   # real rows short of the width in an aligned case
 
 
 @pytest.fixture(scope="module")
@@ -63,16 +73,39 @@ def shape(topo):
                                                         sharding=one_chip)
 
 
-def _assert_kernel(fn, *args) -> None:
+def _assert_kernel(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
 
 
-@pytest.mark.parametrize("width", list(WIDTHS))
-def test_bm25_compiles(shape, width):
+def _assert_no_pad(text: str, corpus: jax.ShapeDtypeStruct) -> None:
+    """No pad or copy instruction of the compiled program reads the corpus
+    parameter."""
+    dt = {"float32": "f32", "bfloat16": "bf16"}[corpus.dtype.name]
+    dims = ",".join(map(str, corpus.shape))
+    names = re.findall(rf"%(\S+) = {dt}\[{dims}\]\S* parameter\(", text)
+    assert len(names) == 1, names
+    reads = re.compile(rf"%{re.escape(names[0])}[,)]")
+    pads = re.compile(r"^%\S*(pad|copy)\S* = | (pad|copy)\(")   # op or fusion
+    hits = [ln.strip()[:160] for ln in text.splitlines()
+            if reads.search(ln) and pads.search(ln.strip())]
+    assert not hits, hits
+
+
+@pytest.mark.parametrize("width,aligned", CASES)
+def test_bm25_compiles(shape, width, aligned):
     n_t = WIDTHS[width][0]
-    _assert_kernel(lambda q, w: ops.bm25_scores(q, w, interpret=False),
-                   shape((NQ, V)), shape((n_t, V)))
+    if not aligned:
+        _assert_kernel(lambda q, w: ops.bm25_scores(q, w, interpret=False),
+                       shape((NQ, V)), shape((n_t, V)))
+        return
+    n_d = n_t - SHORT
+    w = shape(ops.bm25_corpus_shape(n_d, V))
+    text = _assert_kernel(
+        lambda q, w: ops.bm25_scores(q, w, n_docs=n_d, interpret=False),
+        shape((NQ, V)), w)
+    _assert_no_pad(text, w)
 
 
 @pytest.mark.parametrize("width", list(WIDTHS))
@@ -105,17 +138,24 @@ def test_fused_select_live_weights_compiles(shape, width):
         shape((NQ, n_t)), shape((NQ, n_t)), shape((n_t,)), shape((4,)))
 
 
-@pytest.mark.parametrize("width", list(WIDTHS))
-def test_fused_score_select_compiles(shape, width):
+@pytest.mark.parametrize("width,aligned", CASES)
+def test_fused_score_select_compiles(shape, width, aligned):
     """bf16 tool weights, load and a per-query dead mask (SONAR-FT)."""
     n_t = WIDTHS[width][0]
-    _assert_kernel(
+    w_shape = (n_t, V)
+    if aligned:
+        n_t -= SHORT
+        w_shape = ops.score_fuse_corpus_shape(n_t, V)
+    w = shape(w_shape, BF16)
+    text = _assert_kernel(
         lambda q, w, ts, c, qs, u, d: ops.fused_score_select(
             q, w, ts, c, qs, u, d, k=K, alpha=0.5, beta=0.5, gamma=0.35,
             interpret=False),
-        shape((NQ, V)), shape((n_t, V), BF16), shape((n_t,), I32),
+        shape((NQ, V)), w, shape((n_t,), I32),
         shape((NQ, TOP_S), I32), shape((n_t,)), shape((n_t,)),
         shape((NQ, n_t)))
+    if aligned:
+        _assert_no_pad(text, w)
 
 
 @pytest.mark.parametrize("width", ["served", "mega"])
