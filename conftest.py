@@ -1,6 +1,6 @@
 """Repo-level pytest bootstrap.
 
-Two jobs:
+Three jobs:
 
 1. Put ``src/`` on ``sys.path`` so ``PYTHONPATH=src`` is not strictly
    required (CI installs the package with ``pip install -e .`` anyway).
@@ -15,6 +15,11 @@ Two jobs:
    shrinking, no edge-case database) but it keeps every property exercised.
    When the real ``hypothesis`` is importable (as in CI, via the dev
    extras) it is used untouched.
+
+3. Give the benchmark's CPU fixture (``tests/bench/conftest.py``, whose
+   ``tiny`` root cuts every configuration of ``BENCHMARK.json`` by its
+   ``TINY`` table) the cuts of configurations and traffic mixes added
+   after it, kept in ``tests/bench/tiny_cuts.py``.
 """
 from __future__ import annotations
 
@@ -169,3 +174,21 @@ else:
     _profile = os.environ.get("HYPOTHESIS_PROFILE")
     if _profile:
         hypothesis.settings.load_profile(_profile)
+
+
+def pytest_plugin_registered(plugin, manager):
+    """Merge ``tests/bench/tiny_cuts.py`` into the tables of the benchmark
+    fixture's conftest as it registers."""
+    path = getattr(plugin, "__file__", None) or ""
+    if not path.endswith(os.path.join("tests", "bench", "conftest.py")):
+        return
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_tiny_cuts", os.path.join(os.path.dirname(path), "tiny_cuts.py")
+    )
+    cuts = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cuts)
+    for name in ("TINY", "TINY_TRAFFIC"):
+        for key, value in getattr(cuts, name).items():
+            getattr(plugin, name).setdefault(key, value)

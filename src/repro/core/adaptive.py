@@ -311,8 +311,9 @@ class SonarAdaptRouter(SonarGeoRouter):
         servers: Sequence,
         cfg: RoutingConfig = RoutingConfig(),
         adapt: AdaptConfig = AdaptConfig(),
+        index=None,
     ):
-        super().__init__(servers, cfg)
+        super().__init__(servers, cfg, index=index)
         self.base_cfg = cfg
         self.adapt_cfg = adapt
         self.state = init_state(cfg, adapt)

@@ -199,6 +199,7 @@ def encode_for_index(
         "delta", "rtt_scale", "temp", "stale_half_life", "use_network",
         "use_load", "use_staleness", "use_failover", "use_rtt", "use_aff",
         "eps", "rerank", "use_kernels", "qos_params", "interpret", "n_servers",
+        "row_blocks",
     ),
 )
 def _route_pipeline(
@@ -246,6 +247,7 @@ def _route_pipeline(
     qos_params: QosParams,
     interpret: Optional[bool],
     n_servers: int,
+    row_blocks: bool = False,
 ):
     # the real counts: the kernel path's corpora carry zero rows past them
     n_tools = tool_server.shape[0]
@@ -258,7 +260,7 @@ def _route_pipeline(
             s_scores = ops.bm25_scores(q_server, w_server, n_docs=n_servers,
                                        interpret=interpret)
         else:
-            s_scores = bm25.bm25_scores(w_server, q_server)
+            s_scores = bm25.bm25_scores(w_server, q_server, row_blocks)
         # SONAR-FT: demote known-failed servers below every live one before
         # the top-s, so failover escapes an all-dead candidate set (mirrors
         # the scalar `_candidates` masking; NEG ties re-fill in index order)
@@ -280,9 +282,10 @@ def _route_pipeline(
     # them stripe-by-stripe inside `ops.fused_score_select` below --
     with jax.named_scope("score_fuse"):
         if not use_kernels:
-            t_scores = bm25.bm25_scores(w_tool, q_tool)
+            t_scores = bm25.bm25_scores(w_tool, q_tool, row_blocks)
             sel = jnp.where(in_cand, t_scores, NEG)
-            val = bm25.bm25_scores(w_tool, q_rerank) if rerank else sel
+            val = (bm25.bm25_scores(w_tool, q_rerank, row_blocks) if rerank
+                   else sel)
 
     # -- QoS N per tool (Eq. 6-7): Pallas kernel over the telemetry matrix --
     with jax.named_scope("qos"):
@@ -423,7 +426,7 @@ def _route_pipeline(
         "delta", "rtt_scale", "temp", "stale_half_life", "use_network",
         "use_load", "use_staleness", "use_failover", "use_rtt", "use_aff",
         "eps", "rerank", "use_kernels", "qos_params", "interpret", "acfg",
-        "n_servers",
+        "n_servers", "row_blocks",
     ),
     donate_argnums=(0,),
 )
@@ -472,6 +475,7 @@ def _route_adaptive(
     qos_params: QosParams,
     interpret: Optional[bool],
     n_servers: int,
+    row_blocks: bool = False,
 ):
     """SONAR-ADAPT hot path: ONE jit program that applies the pending EG
     update and routes the batch with the freshly-updated weights.  The
@@ -497,6 +501,7 @@ def _route_adaptive(
         use_rtt=use_rtt, use_aff=use_aff, eps=eps,
         rerank=rerank, use_kernels=use_kernels,
         qos_params=qos_params, interpret=interpret, n_servers=n_servers,
+        row_blocks=row_blocks,
     )
     return server_idx, tool_idx, c, n, s, new_state
 
@@ -829,6 +834,7 @@ class BatchRoutingEngine:
             qos_params=self.cfg.qos,
             interpret=self.interpret,
             n_servers=self.n_servers,
+            row_blocks=bool(getattr(self.index, "row_blocks", False)),
         )
         operands = (
             jnp.asarray(batch.q_server),

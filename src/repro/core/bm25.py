@@ -131,18 +131,48 @@ def build_corpus_tiled(
 # against the f32 scalar reference; HIGHEST keeps them exact (CPU ignores it).
 HIGHEST = jax.lax.Precision.HIGHEST
 
+# Corpus rows scored by one matmul of `bm25_scores(..., row_blocks=True)`.
+ROW_BLOCK = 128
 
-def bm25_scores(weights: jnp.ndarray, qcounts: jnp.ndarray) -> jnp.ndarray:
+
+def bm25_scores(weights: jnp.ndarray, qcounts: jnp.ndarray,
+                row_blocks: bool = False) -> jnp.ndarray:
     """Score queries against the corpus: [n_docs, V] x [n_q, V] -> [n_q, n_docs].
 
     Pure-jnp oracle for kernels/bm25_score.  Query term *counts* saturate via
     the standard query-side BM25 (count clipped at 1 works for short queries;
     we keep raw counts to match rank-bm25 behaviour for repeated terms).
+
+    With ``row_blocks`` the corpus is scored ``ROW_BLOCK`` rows at a time,
+    every block by the same [n_q, V] x [V, ROW_BLOCK] matmul (the last block
+    ends at the last row; a corpus of fewer rows is zero-padded to one
+    block), so a row's score does not depend on how many rows are scored
+    beside it: a template-tiled index and its densified expansion score
+    bit-identically.  One matmul over all rows does not promise that, as
+    XLA picks its accumulation order by the operand shapes (on the CPU, 30
+    and 120 rows of the same weights scored 1 ulp apart); it is the
+    default, being several times faster on a multi-core CPU.
     """
-    return jnp.matmul(
-        qcounts.astype(jnp.float32), weights.astype(jnp.float32).T,
-        precision=HIGHEST,
-    )
+    q = qcounts.astype(jnp.float32)
+    w = weights.astype(jnp.float32)
+    if not row_blocks:
+        return jnp.matmul(q, w.T, precision=HIGHEST)
+    n = w.shape[0]
+    if n < ROW_BLOCK:
+        w = jnp.pad(w, ((0, ROW_BLOCK - n), (0, 0)))
+    rows = max(n, ROW_BLOCK)
+    n_blocks = -(-rows // ROW_BLOCK)
+    starts = np.minimum(np.arange(n_blocks) * ROW_BLOCK, rows - ROW_BLOCK)
+    s = jax.lax.map(
+        lambda lo: jnp.matmul(
+            q, jax.lax.dynamic_slice_in_dim(w, lo, ROW_BLOCK, 0).T,
+            precision=HIGHEST,
+        ),
+        jnp.asarray(starts, jnp.int32),
+    )                                                  # [n_blocks, n_q, B]
+    r = np.arange(n)
+    block = np.minimum(r // ROW_BLOCK, n_blocks - 1)
+    return s[block, :, r - starts[block]].T
 
 
 def topk(scores: jnp.ndarray, k: int):

@@ -107,6 +107,10 @@ class _DenseIndexView:
     tool_server: np.ndarray
     n_tools: int
 
+    # score its rows as the tiled index scores its template rows (see
+    # `bm25.bm25_scores`)
+    row_blocks = True
+
 
 class TiledFleetIndex:
     """Template-tiled two-level BM25 index for 10^5-10^6-server fleets.
@@ -140,6 +144,9 @@ class TiledFleetIndex:
     """
 
     is_tiled = True
+    # jnp BM25 scores each template row as its densified expansion's rows
+    # score (`bm25.bm25_scores`)
+    row_blocks = True
 
     def __init__(
         self,
@@ -201,6 +208,21 @@ class TiledFleetIndex:
                 ),
                 n_docs=self.tool_corpus.n_docs,
             )
+
+    def server_scores(self, qtext: str) -> np.ndarray:
+        """[n_servers] stage-1 BM25 scores of one query, for the scalar
+        `Router` (per template row, then gathered: every row is reduced
+        on its own, so each equals its expanded row's `ToolIndex` score)."""
+        q = self.server_corpus.encode_query(qtext)
+        return ToolIndex._row_scores(self.server_corpus.weights, q)[
+            self.server_doc_map]
+
+    def tool_scores(self, qtext: str) -> np.ndarray:
+        """[n_tools] stage-2 BM25 scores of one query (see
+        `server_scores`)."""
+        q = self.tool_corpus.encode_query(qtext)
+        return ToolIndex._row_scores(self.tool_corpus.weights, q)[
+            self.tool_doc_map]
 
     def densify(self) -> _DenseIndexView:
         """Expanded-weights view (for the single-device parity engine)."""
@@ -316,6 +338,9 @@ class _StaticCfg(NamedTuple):
     # every pre-existing static config hashes identically
     use_aff: bool = False
     eps: float = 0.0
+    # jnp BM25 in fixed row blocks (`bm25.bm25_scores`): the index's rows
+    # score as its densified expansion's do
+    row_blocks: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +352,7 @@ class _StaticCfg(NamedTuple):
 def _bm25_2d(q: jax.Array, w: jax.Array, sc: _StaticCfg) -> jax.Array:
     if sc.use_kernels:
         return ops.bm25_scores(q, w, interpret=sc.interpret)
-    return bm25.bm25_scores(w, q)
+    return bm25.bm25_scores(w, q, row_blocks=sc.row_blocks)
 
 
 def _qos_2d(lat: jax.Array, sc: _StaticCfg) -> jax.Array:
@@ -345,7 +370,7 @@ def _stage1_stacked(d: dict, sc: _StaticCfg) -> tuple:
         s = d["s_pre"]                                   # [J, n_q, s_pad]
     else:
         w = d["w_server"]                                # [J, s_pad, V]
-        if sc.use_kernels:
+        if sc.use_kernels or sc.row_blocks:
             J, S, V = w.shape
             s = _bm25_2d(d["q_server"], w.reshape(J * S, V), sc)
             s = s.reshape(-1, J, S).transpose(1, 0, 2)
@@ -374,7 +399,7 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
         t = d["t_pre"]                                   # [J, n_q, t_pad]
     else:
         w = d["w_tool"]                                  # [J, t_pad, V]
-        if sc.use_kernels:
+        if sc.use_kernels or sc.row_blocks:
             J, T, V = w.shape
             t = _bm25_2d(d["q_tool"], w.reshape(J * T, V), sc)
             t = t.reshape(-1, J, T).transpose(1, 0, 2)
@@ -394,7 +419,7 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
     if sc.rerank:
         if "val_pre" in d:
             val_full = d["val_pre"]
-        elif sc.use_kernels:
+        elif sc.use_kernels or sc.row_blocks:
             w = d["w_tool"]
             val_full = _bm25_2d(
                 d["q_rerank"], w.reshape(J * t_pad, -1), sc
@@ -1061,6 +1086,7 @@ class ShardedRoutingEngine:
             interpret=interpret, qos_params=cfg.qos,
             compact2=self.compact_stage2, k_slot=k_slot,
             use_aff=self.uses_affinity, eps=cfg.eps,
+            row_blocks=bool(getattr(index, "row_blocks", False)),
         )
 
         # SONAR-ADAPT learner state.  Replicated-update semantics: the EG

@@ -224,9 +224,14 @@ class Router:
     uses_affinity = False
     rerank = False
 
-    def __init__(self, servers: Sequence[Server], cfg: RoutingConfig = RoutingConfig()):
+    def __init__(self, servers: Sequence[Server], cfg: RoutingConfig = RoutingConfig(),
+                 index=None):
+        """``index`` is a prebuilt index in place of ``servers``: a
+        `ToolIndex`, or a `core.mesh_routing.TiledFleetIndex`, whose
+        ``server_scores`` / ``tool_scores`` score a template-tiled fleet
+        row for row as the expanded `ToolIndex` would."""
         self.cfg = cfg
-        self.index = ToolIndex(servers)
+        self.index = ToolIndex(servers) if index is None else index
 
     # -- semantic stages ----------------------------------------------------
     def _preprocess(self, query: str) -> tuple[str, float]:
@@ -426,7 +431,7 @@ class Router:
         records every hop, so a failover chain reads as consecutive
         audit records."""
         budget = self.cfg.failover_budget if budget is None else int(budget)
-        n_servers = len(self.index.servers)
+        n_servers = self.index.server_corpus.n_docs
         mask = (
             np.zeros(n_servers, bool)
             if failed_mask is None

@@ -234,11 +234,14 @@ class FlushRecord:
     where the batcher held requests when that flush ended (else None).
     ``spans`` holds each phase the flush ran as (name, start ms from the
     flush's start, ms); ``phases`` sums them by name (engine phases over
-    the flush's chunks, the ring push over its completions).  Make the
-    record when the flush starts: offsets count from then.
+    the flush's chunks, the ring push over its completions).  ``gauges``
+    holds the values the flush noted (`note`), such as the ejected
+    replicas its decisions saw.  Make the record when the flush starts:
+    offsets count from then.
     """
 
-    __slots__ = ("index", "t_start_ms", "t_end_ms", "gap_ms", "spans", "_t0")
+    __slots__ = ("index", "t_start_ms", "t_end_ms", "gap_ms", "spans",
+                 "gauges", "_t0")
 
     def __init__(self, index: int, t_start_ms: float,
                  gap_ms: Optional[float] = None):
@@ -247,6 +250,7 @@ class FlushRecord:
         self.t_end_ms = t_start_ms
         self.gap_ms = gap_ms
         self.spans: list = []
+        self.gauges: dict = {}
         self._t0 = time.perf_counter()
 
     def add(self, name: str, t0_s: float, t1_s: float) -> None:
@@ -276,6 +280,14 @@ def recording(rec: FlushRecord):
             yield rec
         finally:
             _FLUSH.reset(token)
+
+
+def note(**values) -> None:
+    """Record named values (gauge readings) in the open flush's record;
+    nothing where no flush is being recorded."""
+    rec = _FLUSH.get()
+    if rec is not None:
+        rec.gauges.update(values)
 
 
 class _Phase:

@@ -129,9 +129,22 @@ class DeviceTelemetry:
             donate_argnums=0,
         )
     )
+    # the column read through the template map on the device: only the
+    # template column, the replica and its sample cross from the host
+    _shift_mapped = staticmethod(
+        jax.jit(
+            lambda buf, tcol, tmap, idx, value: jnp.concatenate(
+                [buf[:, 1:],
+                 jnp.take(tcol, tmap).at[idx].set(value)[:, None]
+                 .astype(buf.dtype)], axis=1,
+            ),
+            donate_argnums=0,
+        )
+    )
 
     def __init__(self, init: np.ndarray, sharding=None,
-                 dtype: str = "float32"):
+                 dtype: str = "float32",
+                 template_map: Optional[np.ndarray] = None):
         # bf16 ring: halves the resident window and the per-route HBM
         # read; samples are rounded once on entry (the buffer never
         # leaves bf16, so there is no re-rounding drift) and upcast
@@ -139,13 +152,33 @@ class DeviceTelemetry:
         self._dtype = (
             jnp.bfloat16 if dtype in ("bfloat16", "bf16") else jnp.float32
         )
-        buf = jnp.asarray(init, self._dtype)
+        self._map = None
+        if template_map is not None:
+            # init holds one row per telemetry template: the window is
+            # gathered on the device, never [n_replicas, history] on the host
+            self._map = jnp.asarray(np.asarray(template_map, np.int32))
+            if sharding:
+                self._map = jax.device_put(self._map, sharding)
+            buf = jnp.take(jnp.asarray(init, jnp.float32), self._map,
+                           axis=0).astype(self._dtype)
+        else:
+            buf = jnp.asarray(init, self._dtype)
         self._buf = jax.device_put(buf, sharding) if sharding else buf
         self._host: Optional[np.ndarray] = None
 
     def push(self, col: np.ndarray) -> None:
         self._buf = DeviceTelemetry._shift(
             self._buf, jnp.asarray(col, jnp.float32)
+        )
+        self._host = None
+
+    def push_mapped(self, tcol: np.ndarray, idx: int, value: float) -> None:
+        """Advance by one column read through the template map: replica
+        i takes ``tcol[template_map[i]]``, replica ``idx`` takes
+        ``value``."""
+        self._buf = DeviceTelemetry._shift_mapped(
+            self._buf, jnp.asarray(tcol, jnp.float32), self._map,
+            int(idx), np.float32(value),
         )
         self._host = None
 
@@ -163,10 +196,15 @@ class SonarGateway:
 
     Parameters
     ----------
-    replicas : Sequence[Server]
-        Replica pool (capability descriptions are the routing corpus).
+    replicas : Sequence[Server] | TiledFleetIndex
+        Replica pool (capability descriptions are the routing corpus), or
+        the prebuilt template-tiled index of a mega fleet
+        (`core.mesh_routing.TiledFleetIndex`): the gateway then holds no
+        `Server` objects, the scalar router scores through the index and
+        batches route through the sharded engine (``shards``) over it.
     profiles : list[LatencyProfile], optional
-        Per-replica network profiles (default: all ideal).
+        Per-replica network profiles (default: all ideal), or with
+        ``template_map`` one per telemetry template.
     cfg : RoutingConfig
     seed : int
         Seeds both trace synthesis and the probe-readmission PRNG; the
@@ -174,8 +212,9 @@ class SonarGateway:
     history : int
         Telemetry window length in samples.
     executor : Callable, optional
-        ``(replica_idx, request_text) -> latency_ms`` — real dispatch hook;
-        default replays the synthesized traces.
+        ``(replica_idx, request_text) -> latency_ms`` — real dispatch hook,
+        on every path (`route`, and `route_batch` as each answer
+        completes); default replays the synthesized traces (`trace_at`).
     use_kernels : bool
         Route batches through the jit engine (`route_batch` fast path).
     algo : str
@@ -203,6 +242,12 @@ class SonarGateway:
         .region_server_rtt()`).  With a locality-aware algorithm
         (``algo="sonar_geo"``) requests routed with a ``client_region``
         pay attention to distance; other algorithms ignore it.
+    template_map : np.ndarray, optional
+        int [n_replicas] telemetry template of each replica (an index
+        into ``profiles``).  Traces are synthesized once per template,
+        [n_templates, T], and read through the map on ring init and on
+        every ring push, in the device ring, so host memory stays
+        O(n_templates x T + n_replicas) at any fleet size.
     obs : repro.obs.Observability, optional
         The observability bundle (docs/observability.md).  The gateway
         binds its counters/gauges/histograms in ``obs.registry`` — the
@@ -232,7 +277,7 @@ class SonarGateway:
 
     def __init__(
         self,
-        replicas: Sequence[Server],
+        replicas,
         profiles: Optional[list] = None,
         cfg: RoutingConfig = RoutingConfig(top_s=8, top_k=8),
         seed: int = 0,
@@ -251,10 +296,23 @@ class SonarGateway:
         telemetry_dtype: str = "float32",
         obs: Optional[Observability] = None,
         session_half_life: float = 256.0,
+        template_map: Optional[np.ndarray] = None,
     ):
-        self.replicas = list(replicas)
         self.algo = algo.lower().replace("-", "_")
-        self.router = ALGORITHMS[self.algo](self.replicas, cfg)
+        if getattr(replicas, "is_tiled", False):
+            if use_kernels and not shards:
+                raise ValueError("a tiled fleet routes batches through the "
+                                 "sharded engine: pass shards")
+            if template_map is None:
+                raise ValueError("a tiled fleet takes template telemetry: "
+                                 "pass profiles and template_map")
+            self.replicas: list = []
+            self.n_replicas = int(replicas.n_servers)
+            self.router = ALGORITHMS[self.algo]([], cfg, index=replicas)
+        else:
+            self.replicas = list(replicas)
+            self.n_replicas = len(self.replicas)
+            self.router = ALGORITHMS[self.algo](self.replicas, cfg)
         assert self.router.uses_network, "the gateway routes on telemetry"
         self.history = history
         self.executor = executor
@@ -273,7 +331,7 @@ class SonarGateway:
         # bundle); the device-side route stats are threaded through the
         # batched engines when obs.jit_stats is on.
         self.obs = obs if obs is not None else Observability()
-        n = len(self.replicas)
+        n = self.n_replicas
         # in-flight accounting: callers running concurrent traffic use
         # begin()/finish() so the utilization the load term sees tracks
         # outstanding work; route()/route_batch() keep their own counts.
@@ -293,10 +351,18 @@ class SonarGateway:
             profiles = [latlib.ideal_profile() for _ in range(n)]
         packed = latlib.pack_profiles(profiles)
         steps = latlib.trace_horizon_steps()
-        self.traces = latlib.generate_traces_cached(seed, packed, steps)
-        init = self.traces[:, :history]
+        # one trace row per profile; replica i reads row trace_map[i]
+        # (the identity when every replica has its own profile)
+        self.trace_rows = latlib.generate_traces_cached(seed, packed, steps)
+        self.trace_map = (
+            None if template_map is None
+            else np.asarray(template_map, np.int32)
+        )
+        init = self.trace_rows[:, :history]
         if device_telemetry is None:
-            device_telemetry = bool(shards)
+            device_telemetry = bool(shards) or template_map is not None
+        elif template_map is not None and not device_telemetry:
+            raise ValueError("template telemetry lives in the device ring")
         self.telemetry_dtype = telemetry_dtype
         ring_sharding = None
         mesh = self.engine().mesh if shards and use_kernels else None
@@ -307,7 +373,8 @@ class SonarGateway:
             ring_sharding = NamedSharding(mesh, spec)
         self._telemetry = (
             DeviceTelemetry(init, sharding=ring_sharding,
-                            dtype=telemetry_dtype)
+                            dtype=telemetry_dtype,
+                            template_map=self.trace_map)
             if device_telemetry
             else _HostTelemetry(init, dtype=telemetry_dtype)
         )
@@ -326,11 +393,14 @@ class SonarGateway:
             "gateway_unmatched_finish_total", "req"
         )
         self._m_ejected = reg.gauge("gateway_ejected", "replicas")
-        # per-flush walls of route_batch's phases, and the ring push per
-        # completion on every path
+        self._m_health_bytes = reg.gauge(
+            "gateway_health_row_bytes_per_flush", "bytes"
+        )
+        # per-flush walls of route_batch's phases, the ring push per
+        # completion on every path, and the health rows per routing call
         self._m_phase = {
             ph: reg.histogram(f"gateway_phase_{ph}_ms", "ms")
-            for ph in ("encode", "dispatch", "merge", "ring_push")
+            for ph in ("encode", "dispatch", "merge", "ring_push", "health")
         }
         self._route_stats = self.obs.ensure_route_stats(n)
         # SONAR-ADAPT: live weight-trajectory surface.  The scalar router
@@ -362,21 +432,45 @@ class SonarGateway:
         )
 
     @property
+    def traces(self) -> np.ndarray:
+        """Each replica's latency trace [n_replicas, T] ms (materialized
+        through the template map where there is one: small fleets only)."""
+        if self.trace_map is None:
+            return self.trace_rows
+        return self.trace_rows[self.trace_map]
+
+    def trace_at(self, idx: int) -> float:
+        """Replica ``idx``'s trace sample (ms) at the gateway's clock: the
+        latency a call to it takes when no ``executor`` is given."""
+        row = idx if self.trace_map is None else self.trace_map[idx]
+        return float(
+            self.trace_rows[row, min(self.t, self.trace_rows.shape[1] - 1)]
+        )
+
+    @property
     def telemetry(self) -> np.ndarray:
         """Host view of the telemetry window [n_replicas, history] ms (the
         scalar routing paths consume this; the device buffer backing a
         sharded gateway is materialized lazily and cached per tick)."""
         return self._telemetry.host()
 
+    def _call(self, idx: int, request_text: str) -> float:
+        """The latency (ms) of the call to replica ``idx``: the executor's,
+        else the trace's."""
+        if self.executor is not None:
+            return float(self.executor(idx, request_text))
+        return self.trace_at(idx)
+
     def _observe(self, idx: int, latency_ms: float):
         with obs_trace.annotate("gateway.ring_push",
                                 self._m_phase["ring_push"]):
-            col = np.array(
-                self.traces[:, min(self.t, self.traces.shape[1] - 1)],
-                np.float32,
-            )
-            col[idx] = latency_ms
-            self._telemetry.push(col)
+            col = self.trace_rows[:, min(self.t, self.trace_rows.shape[1] - 1)]
+            if self.trace_map is not None:
+                self._telemetry.push_mapped(col, idx, latency_ms)
+            else:
+                col = np.array(col, np.float32)
+                col[idx] = latency_ms
+                self._telemetry.push(col)
         self.t += 1
 
     def _utilization(self) -> np.ndarray:
@@ -467,17 +561,22 @@ class SonarGateway:
         request regardless of chunking.  Never masks the whole fleet for
         any request (a single-replica pool with its replica ejected must
         still route — the request *is* the probe)."""
-        if not self.router.uses_failover or not self.ejected.any():
+        if not self.router.uses_failover:
             return None
-        rows = 1 if n_requests is None else n_requests
-        probe = (
-            self._probe_rng.random((rows, len(self.ejected))) < self.probe_prob
-        )
-        mask = self.ejected[None, :] & ~probe
-        mask[mask.all(axis=1)] = False
-        if not mask.any():
-            return None
-        return mask[0] if n_requests is None else mask
+        with obs_trace.annotate("gateway.health_mask",
+                                self._m_phase["health"]):
+            if not self.ejected.any():
+                return None
+            rows = 1 if n_requests is None else n_requests
+            probe = (
+                self._probe_rng.random((rows, len(self.ejected)))
+                < self.probe_prob
+            )
+            mask = self.ejected[None, :] & ~probe
+            mask[mask.all(axis=1)] = False
+            if not mask.any():
+                return None
+            return mask[0] if n_requests is None else mask
 
     def _record_outcome(self, idx: int, ok: bool) -> None:
         was_ejected = bool(self.ejected[idx])
@@ -612,10 +711,7 @@ class SonarGateway:
                 **({} if aff is None else {"affinity": aff}),
             )
         idx = decision.server_idx
-        if self.executor is not None:
-            latency = float(self.executor(idx, request_text))
-        else:
-            latency = float(self.traces[idx, min(self.t, self.traces.shape[1] - 1)])
+        latency = self._call(idx, request_text)
         ok = latency < latlib.OFFLINE_MS
         self._record_outcome(idx, ok)
         self._observe(idx, latency)
@@ -648,6 +744,24 @@ class SonarGateway:
                     index=self.router.index, registry=self.obs.registry,
                 )
         return self._engine
+
+    def warm(self, rows: int) -> None:
+        """Compile what `route_batch` runs for ``rows``-row engine calls:
+        one engine call on empty queries per program it can pick, without
+        health rows and, under a failover-aware algorithm, with them.
+        The gateway's state is left as it was, so a later first ejection
+        compiles nothing."""
+        if not self.use_kernels:
+            return
+        eng = self.engine()
+        sub = eng.encode([""] * rows)
+        masks = [None]
+        if self.router.uses_failover:
+            masks.append(np.zeros((rows, self.n_replicas), bool))
+            masks[-1][:, 0] = True
+        for mask in masks:
+            eng.route(sub, self._telemetry.raw(), self._utilization(),
+                      failed_mask=mask)
 
     def route_batch(
         self,
@@ -714,14 +828,18 @@ class SonarGateway:
         with obs_trace.annotate("gateway.encode", self._m_phase["encode"]):
             enc = eng.encode(request_texts)
         dispatch_ms = 0.0
+        health_bytes = 0
+        # the ejected set this flush's decisions see (health moves at merge)
+        ejected_seen = self._m_ejected.value
         picks: list = []
-        chunked = self.router.uses_load and len(self.replicas) > 1
+        chunked = self.router.uses_load and self.n_replicas > 1
         step = self.lb_chunk if chunked else (pad_to or len(request_texts))
         step = max(step, 1)
         for lo in range(0, len(request_texts), step):
             n_chunk = min(step, len(request_texts) - lo)
             sub = enc.slice(lo, lo + n_chunk)
             mask = self._health_mask(n_chunk)
+            health_bytes += 0 if mask is None else mask.nbytes
             reg = regions_arr[lo : lo + n_chunk] if use_geo else None
             if pad_to is not None and sub.n < step:
                 sub = sub.pad_to(step)
@@ -745,7 +863,7 @@ class SonarGateway:
                 # session-less / padded rows stay zero; an all-zero
                 # matrix is dropped so affinity-free chunks keep the
                 # exact historical scoring graph (byte-identity gate)
-                aff = np.zeros((sub.n, len(self.replicas)), np.float32)
+                aff = np.zeros((sub.n, self.n_replicas), np.float32)
                 warm_any = False
                 for qi in range(n_chunk):
                     row = self._session_affinity(session_ids[lo + qi])
@@ -778,15 +896,17 @@ class SonarGateway:
                 self.in_flight[idx] += 1.0
                 self._m_in_flight.inc()
                 sid = None if session_ids is None else session_ids[lo + qi]
-                picks.append((idx, expertise, network, feats, sid))
+                picks.append((idx, expertise, network, feats, sid,
+                              request_texts[lo + qi]))
         # one observe per flush: the chunks' dispatch walls summed
         self._m_phase["dispatch"].observe(dispatch_ms)
+        self._m_health_bytes.set(float(health_bytes))
+        obs_trace.note(gateway_ejected=ejected_seen,
+                       gateway_health_row_bytes_per_flush=float(health_bytes))
         out = []
         with obs_trace.annotate("gateway.merge", self._m_phase["merge"]):
-            for idx, expertise, network, feats, sid in picks:
-                latency = float(
-                    self.traces[idx, min(self.t, self.traces.shape[1] - 1)]
-                )
+            for idx, expertise, network, feats, sid, text in picks:
+                latency = self._call(idx, text)
                 ok = latency < latlib.OFFLINE_MS
                 self._record_outcome(idx, ok)
                 self._observe(idx, latency)

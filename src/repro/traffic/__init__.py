@@ -18,6 +18,7 @@ from repro.traffic.fleet import (  # noqa: F401
     mega_platform,
     replica_fleet,
     telemetry_palette,
+    telemetry_template_map,
 )
 from repro.traffic.queueing import QueueConfig, ServerQueue  # noqa: F401
 from repro.traffic.simulator import (  # noqa: F401

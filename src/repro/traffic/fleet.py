@@ -122,6 +122,14 @@ def telemetry_palette(n_templates: int = 16, seed: int = 0) -> list:
     return palette
 
 
+def telemetry_template_map(n_servers: int, n_templates: int) -> np.ndarray:
+    """int64 [n_servers] telemetry template of each server: a stride
+    co-prime to the description round-robin of `mega_fleet_index`, so
+    semantic ties and network ties decorrelate (int64: the Knuth
+    multiplier overflows default-int32 platforms)."""
+    return (np.arange(n_servers, dtype=np.int64) * 2654435761) % n_templates
+
+
 def mega_platform(
     n_servers: int,
     n_tel_templates: int = 16,
@@ -134,14 +142,10 @@ def mega_platform(
     servers map onto them with a stride co-prime to the description
     round-robin, so semantic ties and network ties decorrelate.  Storage
     is O(templates x T) + O(servers) regardless of fleet size."""
-    palette = telemetry_palette(n_tel_templates, seed)
-    # decorrelate from the `mega_fleet_index` description round-robin
-    # (int64: the Knuth multiplier overflows default-int32 platforms)
-    tel_map = (np.arange(n_servers, dtype=np.int64) * 2654435761) % n_tel_templates
     return NetMCPPlatform(
         servers=None,
-        profiles=palette,
-        template_map=tel_map,
+        profiles=telemetry_palette(n_tel_templates, seed),
+        template_map=telemetry_template_map(n_servers, n_tel_templates),
         seed=seed,
         horizon_s=horizon_s,
         dt_s=dt_s,
