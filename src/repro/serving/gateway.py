@@ -347,6 +347,7 @@ class SonarGateway:
         self.fail_streak = np.zeros(n, np.int64)
         self.ejected = np.zeros(n, bool)
         self._probe_rng = np.random.default_rng(seed ^ 0x5EED)
+        self._health_draws = 0        # probe uniforms drawn, ever
         if profiles is None:
             profiles = [latlib.ideal_profile() for _ in range(n)]
         packed = latlib.pack_profiles(profiles)
@@ -395,6 +396,9 @@ class SonarGateway:
         self._m_ejected = reg.gauge("gateway_ejected", "replicas")
         self._m_health_bytes = reg.gauge(
             "gateway_health_row_bytes_per_flush", "bytes"
+        )
+        self._m_health_draws = reg.gauge(
+            "gateway_health_draws_per_flush", "draws"
         )
         # per-flush walls of route_batch's phases, the ring push per
         # completion on every path, and the health rows per routing call
@@ -565,17 +569,22 @@ class SonarGateway:
             return None
         with obs_trace.annotate("gateway.health_mask",
                                 self._m_phase["health"]):
-            if not self.ejected.any():
+            ids = np.flatnonzero(self.ejected)
+            if not len(ids):
                 return None
             rows = 1 if n_requests is None else n_requests
-            probe = (
-                self._probe_rng.random((rows, len(self.ejected)))
-                < self.probe_prob
+            # a probe bit only matters on an ejected replica, so only the
+            # ejected columns draw one: rows x ejected uniforms per call
+            masked = ~(
+                self._probe_rng.random((rows, len(ids))) < self.probe_prob
             )
-            mask = self.ejected[None, :] & ~probe
-            mask[mask.all(axis=1)] = False
-            if not mask.any():
+            self._health_draws += masked.size
+            if len(ids) == self.n_replicas:
+                masked[masked.all(axis=1)] = False
+            if not masked.any():
                 return None
+            mask = np.zeros((rows, self.n_replicas), bool)
+            mask[:, ids] = masked
             return mask[0] if n_requests is None else mask
 
     def _record_outcome(self, idx: int, ok: bool) -> None:
@@ -829,6 +838,7 @@ class SonarGateway:
             enc = eng.encode(request_texts)
         dispatch_ms = 0.0
         health_bytes = 0
+        draws0 = self._health_draws
         # the ejected set this flush's decisions see (health moves at merge)
         ejected_seen = self._m_ejected.value
         picks: list = []
@@ -900,9 +910,12 @@ class SonarGateway:
                               request_texts[lo + qi]))
         # one observe per flush: the chunks' dispatch walls summed
         self._m_phase["dispatch"].observe(dispatch_ms)
+        health_draws = float(self._health_draws - draws0)
         self._m_health_bytes.set(float(health_bytes))
+        self._m_health_draws.set(health_draws)
         obs_trace.note(gateway_ejected=ejected_seen,
-                       gateway_health_row_bytes_per_flush=float(health_bytes))
+                       gateway_health_row_bytes_per_flush=float(health_bytes),
+                       gateway_health_draws_per_flush=health_draws)
         out = []
         with obs_trace.annotate("gateway.merge", self._m_phase["merge"]):
             for idx, expertise, network, feats, sid, text in picks:

@@ -17,7 +17,7 @@ from repro.core import latency as latlib
 from repro.core.mesh_routing import ShardedRoutingEngine
 from repro.core.routing import RoutingConfig
 from repro.obs import trace as obs_trace
-from repro.serving.gateway import SonarGateway
+from repro.serving.gateway import SonarGateway, replica_pool
 from repro.traffic import fleet
 
 POOL = dataset.build_server_pool(seed=0)
@@ -172,6 +172,18 @@ def test_template_traces_equal_per_replica_traces(dtype):
                                   ring(per_replica[:, 5:history]))
 
 
+class _CountingRng:
+    """Counts the uniforms a gateway's probe generator hands out."""
+
+    def __init__(self, rng):
+        self.rng, self.drawn = rng, 0
+
+    def random(self, shape):
+        out = self.rng.random(shape)
+        self.drawn += out.size
+        return out
+
+
 @pytest.mark.parametrize("algo,health", [("sonar_ft", True),
                                          ("sonar_lb", False)])
 def test_health_rows_are_timed_and_counted_per_flush(algo, health):
@@ -182,17 +194,100 @@ def test_health_rows_are_timed_and_counted_per_flush(algo, health):
     assert gw.report()["ejected"] == ejected and ejected > 0
     hist = gw.obs.registry.get("gateway_phase_health_ms")
     before = hist.count
+    rng = gw._probe_rng = _CountingRng(gw._probe_rng)
     rec = obs_trace.FlushRecord(0, 0.0)
     with obs_trace.recording(rec):
         gw.route_batch(QUERIES)
+    draws = rec.gauges["gateway_health_draws_per_flush"]
+    assert draws == rng.drawn
+    assert gw.obs.registry.get("gateway_health_draws_per_flush").value == draws
     if health:
         assert "gateway.health_mask" in rec.phases
         assert hist.count - before == len(QUERIES) // gw.lb_chunk
         assert rec.gauges["gateway_ejected"] == ejected
         assert rec.gauges["gateway_health_row_bytes_per_flush"] == len(QUERIES) * N
+        assert draws == len(QUERIES) * ejected       # rows x ejected, not N
     else:
         assert "gateway.health_mask" not in rec.phases and hist.count == 0
         assert rec.gauges["gateway_health_row_bytes_per_flush"] == 0.0
+        assert draws == 0.0
+
+
+def _health_gateway(n, ejected, probe_prob=0.1):
+    gw = SonarGateway(replica_pool([("yi-6b", "dense")] * n),
+                      algo="sonar_ft", seed=11, probe_prob=probe_prob)
+    gw.ejected[ejected] = True
+    gw._probe_rng = _CountingRng(gw._probe_rng)
+    return gw
+
+
+def _health_rows(gw, rows, batched):
+    """``rows`` requests' health rows, as one batched call or as that many
+    scalar calls (a ``None`` row masks nothing)."""
+    if batched:
+        mask = gw._health_mask(rows)
+        assert mask is None or mask.shape == (rows, gw.n_replicas)
+        return np.zeros((rows, gw.n_replicas), bool) if mask is None else mask
+    out = np.zeros((rows, gw.n_replicas), bool)
+    for r in range(rows):
+        row = gw._health_mask()
+        assert row is None or row.shape == (gw.n_replicas,)
+        if row is not None:
+            out[r] = row
+    return out
+
+
+HEALTH_N = 32
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["scalar", "batched"])
+@pytest.mark.parametrize("n_ejected", [1, 7, HEALTH_N])
+@pytest.mark.parametrize("rows", [1, 8, 64])
+def test_health_rows_draw_only_for_ejected_replicas(rows, n_ejected, batched):
+    """A health row draws one probe uniform per (request, ejected replica)
+    and never masks a replica that is not ejected."""
+    ejected = np.linspace(0, HEALTH_N - 1, n_ejected).astype(int)
+    gw = _health_gateway(HEALTH_N, ejected)
+    mask = _health_rows(gw, rows, batched)
+    assert gw._probe_rng.drawn == rows * n_ejected
+    assert not mask[:, ~gw.ejected].any()
+    assert not mask.all(axis=1).any()
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["scalar", "batched"])
+@pytest.mark.parametrize("n_ejected", [1, 7])
+def test_health_rows_readmit_each_ejected_replica_at_probe_prob(
+        n_ejected, batched):
+    """Over 10^4 requests each ejected replica is a candidate in a share
+    ``probe_prob`` of them, within 5 binomial standard deviations."""
+    rows, p = 10_000, 0.1
+    gw = _health_gateway(HEALTH_N, np.arange(n_ejected) * 4, probe_prob=p)
+    mask = _health_rows(gw, rows, batched)
+    admitted = rows - mask[:, gw.ejected].sum(axis=0)
+    z = np.abs(admitted - rows * p) / np.sqrt(rows * p * (1 - p))
+    assert z.max() < 5.0, z
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["scalar", "batched"])
+@pytest.mark.parametrize("probe_prob", [0.0, 0.3])
+def test_health_rows_never_mask_the_whole_fleet(probe_prob, batched):
+    """With every replica ejected no request sees the whole fleet masked;
+    with no probes at all that is no mask, for the scalar form too."""
+    n, rows = 3, 256
+    gw = _health_gateway(n, np.arange(n), probe_prob=probe_prob)
+    mask = _health_rows(gw, rows, batched)
+    assert not mask.all(axis=1).any()
+    if probe_prob == 0.0:
+        assert gw._health_mask() is None and gw._health_mask(rows) is None
+    else:
+        assert mask.any()
+
+
+@pytest.mark.parametrize("n_requests", [None, 8])
+def test_health_rows_without_ejection_are_none_and_draw_nothing(n_requests):
+    gw = _health_gateway(HEALTH_N, [])
+    assert gw._health_mask(n_requests) is None
+    assert gw._probe_rng.drawn == 0
 
 
 def test_tiled_fleet_needs_the_sharded_engine_and_template_telemetry():
