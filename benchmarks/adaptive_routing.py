@@ -22,10 +22,10 @@ good as the best hand-tuned variant on the scenario's headline metric.
 The sweeps are deterministic discrete-event replays, so the comparisons
 are exact — no statistical tolerance.
 
-A fourth section measures the cost of the fused in-jit update with the
+A fourth section measures the cost of the per-route jit update with the
 interleaved A/B methodology of ``benchmarks.obs_overhead``: back-to-back
 saturated micro-batch flushes (the serving-knee condition), one arm with
-the adaptation step fused into the routed program (default lr) and one
+the adaptation step run before each routed program (default lr) and one
 arm with ``lr=0`` (which takes the identical static program the
 hand-tuned variants compile).  Gate: MEAN knee flush-service time within
 3% (full) / 10% (--smoke).  At the knee every flush sits on the critical
@@ -225,8 +225,8 @@ def _flush_times(adapting: bool, *, n_replicas: int, n_flushes: int,
     calls — the serving-knee condition, where the engine never idles and
     the serve tail is service-dominated.  ``adapting=False`` zeroes the
     learning rate, which routes through the identical static program the
-    hand-tuned variants compile, so the two arms differ only by the fused
-    update (+ its feedback drain)."""
+    hand-tuned variants compile, so the two arms differ only by the jit
+    update (+ its feedback fold) and the ``adapt_w`` operand."""
     gw = _make_gateway(n_replicas, seed)
     if not adapting:
         eng = gw.engine()
@@ -354,8 +354,8 @@ def _by_key(points: list, *keys: str) -> dict:
 
 def check(results: dict) -> None:
     """Acceptance gates: SONAR-ADAPT >= the best hand-tuned variant at
-    EVERY sweep point on each scenario's headline metric, and the fused
-    in-jit update costs <= gate_pct on the mean knee flush service.  The sweeps
+    EVERY sweep point on each scenario's headline metric, and the jit
+    update costs <= gate_pct on the mean knee flush service.  The sweeps
     are deterministic replays, so the comparisons are exact."""
     for key, algos in sorted(_by_key(
             results["offered_load"]["points"], "rate_rps").items()):

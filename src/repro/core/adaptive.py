@@ -12,9 +12,10 @@ learning as future work.  Two implementations live here:
    w = [alpha, beta, gamma, delta] held in a pure-functional `AdaptState`
    pytree and updated by exponentiated-gradient (EG) REINFORCE steps on the
    shaped reward the serving/traffic layers already emit.  The update is a
-   handful of FLOPs over a fixed-size feedback bucket, so the batched
-   engine fuses it into the routed jit program (state donated like the
-   telemetry ring) and adaptation costs nothing extra on the hot path.
+   handful of FLOPs over a fixed-size feedback bucket, run by one donated
+   jit program (`adapt_update`) for the scalar router and both routing
+   engines; the engines pass the live weights to their routed programs as
+   data, so a weight update never recompiles them.
 
 Update rule (doctested in docs/algorithms.md):
 
@@ -48,8 +49,8 @@ from repro.core.routing import (
 )
 
 # Fixed feedback-batch width: outcomes are padded (valid-masked) to this
-# bucket so the fused update compiles ONCE per engine instead of once per
-# feedback count (the same bucketing trick as the serving pad_to path).
+# bucket so an engine's update compiles ONCE instead of once per feedback
+# count (the same bucketing trick as the serving pad_to path).
 FEEDBACK_BUCKET = 64
 
 
@@ -158,7 +159,7 @@ class AdaptConfig(NamedTuple):
 
 class AdaptState(NamedTuple):
     """The learner state — a pytree threaded through (and donated by)
-    the jit routing programs."""
+    the jit update."""
 
     weights: jax.Array               # f32 [4] = [alpha, beta, gamma, delta]
     baseline: jax.Array              # f32 []  reward EMA (advantage baseline)
@@ -276,10 +277,9 @@ def adapt_update(
     valid: np.ndarray,
     acfg: AdaptConfig,
 ) -> AdaptState:
-    """Jit'd standalone update (state donated).  The batched engine fuses
-    the same `_adapt_step` into its routed program instead; this entry is
-    for the scalar router, the sharded engine's replicated state, and
-    overflow buckets."""
+    """Jit'd update (state donated): the scalar router's per-outcome
+    step, and the routing engines' fold of their queued feedback, one
+    ``FEEDBACK_BUCKET`` a step, before each route."""
     return _adapt_update_jit(state, rewards, feats, valid, acfg=acfg)
 
 

@@ -272,8 +272,8 @@ class BatchAgent:
         latencies: list = [[] for _ in range(n)]
         # SONAR-FT: per-query failed-server masks grown across turns, and
         # per-query telemetry ages — mirroring the scalar Agent exactly.
-        uses_staleness = getattr(self.engine, "uses_staleness", False)
-        uses_failover = getattr(self.engine, "uses_failover", False)
+        uses_staleness = self.engine.terms.staleness
+        uses_failover = self.engine.terms.failover
         failed = (
             np.zeros((n, len(plat.servers)), bool) if uses_failover else None
         )

@@ -55,6 +55,14 @@ With a multi-device mesh the per-shard stages run under ``shard_map``;
 without one (the CPU-test default) the same stage functions run on the
 shard-stacked arrays directly, so the emulated and distributed paths share
 every line of math.
+
+`ShardedRoutingEngine` shares its host layer with `BatchRoutingEngine`
+(`batch_routing.RoutingEngineBase`): encoding, the timed `route`, and the
+call's live terms (`routing.Terms.live`), which decide the key set of the
+program's ``dyn`` operands and enter the static `_StaticCfg` beside the
+`RoutingConfig`.  SONAR-ADAPT's weights are updated once per route by the
+standalone `adaptive.adapt_update` and enter as the replicated
+``adapt_w`` operand, as they enter `BatchRoutingEngine`'s program.
 """
 from __future__ import annotations
 
@@ -70,22 +78,15 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import adaptive as _adaptive
 from repro.core import bm25, quantize
-from repro.core.batch_routing import (
-    BatchDecisions,
-    EncodedBatch,
-    encode_for_index,
-    engine_phase_histograms,
-)
-from repro.obs import trace as obs_trace
+from repro.core.batch_routing import EncodedBatch, RoutingEngineBase
 from repro.core.dataset import Server
 from repro.core.qos import (
-    QosParams,
     load_penalty,
     network_score,
     rtt_penalty,
     staleness_discount,
 )
-from repro.core.routing import ALGORITHMS, RoutingConfig, ToolIndex
+from repro.core.routing import RoutingConfig, Terms, ToolIndex
 from repro.kernels import ops
 from repro.kernels import ref as kref
 
@@ -304,40 +305,20 @@ def make_shard_plan(
 # ---------------------------------------------------------------------------
 
 class _StaticCfg(NamedTuple):
+    cfg: RoutingConfig
+    terms: Terms                  # the call's live terms (`Terms.live`)
     n_shards: int
-    top_s: int
-    top_k: int
     n_servers: int
     n_tools: int
     s_keep: int                   # per-shard stage-1 candidates
     k_keep: int                   # per-shard stage-2 candidates
-    alpha: float
-    beta: float
-    gamma: float
-    load_knee: float
-    load_sharp: float
-    delta: float
-    rtt_scale: float
-    temp: float
-    stale_half_life: float
-    use_network: bool
-    use_load: bool
-    use_staleness: bool
-    use_failover: bool
-    use_rtt: bool
-    rerank: bool
     use_kernels: bool
     interpret: Optional[bool]
-    qos_params: QosParams
     # compacted candidate stage-2 (tiled mega fleets): score only the
     # ≤ top_s * k_slot tools hosted on candidate servers instead of
     # running shard-local top-k over the full tool axis
     compact2: bool = False
     k_slot: int = 0               # max tools hosted on any one server
-    # SONAR-SESSION sticky-affinity bonus (+eps*W); off by default so
-    # every pre-existing static config hashes identically
-    use_aff: bool = False
-    eps: float = 0.0
     # jnp BM25 in fixed row blocks (`bm25.bm25_scores`): the index's rows
     # score as its densified expansion's do
     row_blocks: bool = False
@@ -357,8 +338,8 @@ def _bm25_2d(q: jax.Array, w: jax.Array, sc: _StaticCfg) -> jax.Array:
 
 def _qos_2d(lat: jax.Array, sc: _StaticCfg) -> jax.Array:
     if sc.use_kernels:
-        return ops.qos_scores(lat, sc.qos_params, interpret=sc.interpret)
-    return network_score(lat, sc.qos_params)
+        return ops.qos_scores(lat, sc.cfg.qos, interpret=sc.interpret)
+    return network_score(lat, sc.cfg.qos)
 
 
 def _stage1_stacked(d: dict, sc: _StaticCfg) -> tuple:
@@ -377,7 +358,7 @@ def _stage1_stacked(d: dict, sc: _StaticCfg) -> tuple:
         else:
             s = jnp.einsum("qv,jsv->jqs", d["q_server"], w,
                            precision=bm25.HIGHEST)
-    if sc.use_failover and "dead" in d:
+    if sc.terms.failover:
         s = jnp.where(d["dead"] > 0.0, NEG, s)           # [J, B, s_pad] bcast
     s = jnp.where(d["server_valid"][:, None, :], s, PAD_NEG)
     v, li = jax.lax.top_k(s, sc.s_keep)                  # [J, n_q, s_keep]
@@ -416,7 +397,7 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
     sel = jnp.where(in_cand, t, NEG)
     sel = jnp.where(d["tool_valid"][:, None, :], sel, PAD_NEG)
 
-    if sc.rerank:
+    if sc.terms.rerank:
         if "val_pre" in d:
             val_full = d["val_pre"]
         elif sc.use_kernels or sc.row_blocks:
@@ -437,8 +418,7 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
         idx = jnp.broadcast_to(host_l[:, None, :], (J, B, t_pad))
         return jnp.take_along_axis(per_server, idx, axis=-1)
 
-    net_active = sc.use_network and ("lat" in d or "qos_pre" in d)
-    if net_active:
+    if sc.terms.network:
         if "qos_pre" in d:
             n_server = d["qos_pre"]                       # [J, B, s_pad]
         elif d["lat"].ndim == 4:                          # per-query windows
@@ -449,16 +429,16 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
             Jl, S, T = d["lat"].shape
             n_server = _qos_2d(d["lat"].reshape(Jl * S, T), sc)
             n_server = n_server.reshape(Jl, 1, S)
-        if sc.use_staleness and "age" in d:
+        if sc.terms.staleness:
             n_server = n_server * staleness_discount(
-                d["age"], sc.stale_half_life
+                d["age"], sc.cfg.stale_half_life_s
             )
         tool_qos = per_tool(n_server)
     else:
         tool_qos = jnp.zeros((J, 1, t_pad), jnp.float32)
 
-    if sc.use_load and "load" in d:
-        pen = load_penalty(d["load"], sc.load_knee, sc.load_sharp)
+    if sc.terms.load:
+        pen = load_penalty(d["load"], sc.cfg.load_knee, sc.cfg.load_sharp)
         tool_load = per_tool(pen)
     else:
         tool_load = jnp.zeros((J, 1, t_pad), jnp.float32)
@@ -466,7 +446,7 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
     # SONAR-GEO: client-region -> server RTT penalty over the shard's
     # server slice, as an explicit vector or gathered from the sharded
     # [J, n_regions, s_pad] RTT matrix by the replicated region indices
-    if sc.use_rtt and ("rtt" in d or "rtt_region" in d):
+    if sc.terms.rtt:
         if "rtt_region" in d:
             # clamp the gather and zero untagged (region < 0) requests'
             # rows — no locality penalty, matching the scalar convention
@@ -477,18 +457,18 @@ def _stage2_stacked(d: dict, cand_gids: jax.Array, sc: _StaticCfg) -> tuple:
             rtt_s = jnp.where((ridx >= 0)[None, :, None], rtt_s, 0.0)
         else:
             rtt_s = d["rtt"]                              # [J, 1|B, s_pad]
-        tool_rtt = per_tool(rtt_penalty(rtt_s, sc.rtt_scale))
+        tool_rtt = per_tool(rtt_penalty(rtt_s, sc.cfg.rtt_scale_ms))
     else:
         tool_rtt = jnp.zeros((J, 1, t_pad), jnp.float32)
 
-    if sc.use_failover and "dead" in d:
+    if sc.terms.failover:
         tool_dead = per_tool(d["dead"])
     else:
         tool_dead = jnp.zeros((J, 1, t_pad), jnp.float32)
 
     # SONAR-SESSION: per-(session, server) warmth over the shard's server
     # slice, broadcast to the host server's tools like load/dead
-    if sc.use_aff and "aff" in d:
+    if sc.terms.affinity:
         tool_aff = per_tool(d["aff"])
     else:
         tool_aff = jnp.zeros((J, 1, t_pad), jnp.float32)
@@ -563,7 +543,7 @@ def _stage2_compact(
     doc = doc3.reshape(n_q, W)
 
     sel = jnp.where(ok, jnp.take_along_axis(t_full, doc, axis=1), NEG)
-    if sc.rerank:
+    if sc.terms.rerank:
         val = jnp.where(ok, jnp.take_along_axis(v_full, doc, axis=1), NEG)
     else:
         val = sel
@@ -579,9 +559,8 @@ def _stage2_compact(
             x[:, :, None], (n_q, S, K)
         ).reshape(n_q, W)
 
-    net_active = sc.use_network and (nt is not None or "lat" in d)
     qos_fn = functools.partial(_qos_2d, sc=sc)
-    if net_active:
+    if sc.terms.network:
         if nt is not None:                                 # template QoS
             tmf = d["tel_map"].reshape(-1)                 # [J*s_pad]
             qos_s = jnp.take(nt, jnp.take(tmf, cand))      # [n_q, S]
@@ -598,18 +577,20 @@ def _stage2_compact(
             J, Sp, T = d["lat"].shape
             rows = d["lat"].reshape(J * Sp, T)[cand.reshape(-1)]
             qos_s = _replicated(qos_fn, kmesh)(rows).reshape(n_q, S)
-        if sc.use_staleness and "age" in d:
-            qos_s = qos_s * staleness_discount(gath(d["age"]), sc.stale_half_life)
+        if sc.terms.staleness:
+            qos_s = qos_s * staleness_discount(gath(d["age"]),
+                                               sc.cfg.stale_half_life_s)
         qos = expand(qos_s)
     else:
         qos = jnp.zeros((n_q, W), jnp.float32)
 
-    if sc.use_load and "load" in d:
-        load = expand(load_penalty(gath(d["load"]), sc.load_knee, sc.load_sharp))
+    if sc.terms.load:
+        load = expand(load_penalty(gath(d["load"]), sc.cfg.load_knee,
+                                   sc.cfg.load_sharp))
     else:
         load = jnp.zeros((n_q, W), jnp.float32)
 
-    if sc.use_rtt and ("rtt" in d or "rtt_region" in d):
+    if sc.terms.rtt:
         if "rtt_region" in d:
             ridx = d["region_idx"]
             rr = jnp.transpose(d["rtt_region"], (1, 0, 2))  # [R, J, s_pad]
@@ -619,21 +600,21 @@ def _stage2_compact(
             rtt_s = jnp.where((ridx >= 0)[:, None], rtt_s, 0.0)
         else:
             rtt_s = gath(d["rtt"])
-        rtt = expand(rtt_penalty(rtt_s, sc.rtt_scale))
+        rtt = expand(rtt_penalty(rtt_s, sc.cfg.rtt_scale_ms))
     else:
         rtt = jnp.zeros((n_q, W), jnp.float32)
 
-    if sc.use_failover and "dead" in d:
+    if sc.terms.failover:
         dead = expand(gath(d["dead"]))
     else:
         dead = jnp.zeros((n_q, W), jnp.float32)
 
-    if sc.use_aff and "aff" in d:
+    if sc.terms.affinity:
         aff = expand(gath(d["aff"]))
     else:
         aff = jnp.zeros((n_q, W), jnp.float32)
 
-    k_final = min(sc.top_k, sc.n_tools)
+    k_final = min(sc.cfg.top_k, sc.n_tools)
     if W < k_final:                                        # keep the merge
         pad = k_final - W                                  # k identical to
         sel = jnp.pad(sel, ((0, 0), (0, pad)), constant_values=NEG)
@@ -722,9 +703,10 @@ _SH4 = ("shard", None, None, None)
 
 @functools.partial(jax.jit, static_argnames=("mesh", "sc"))
 def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
-    """Hierarchical sharded routing.  `dyn` key presence selects the input
-    mode (dense vs tiled weights/telemetry, which optional vectors are
-    supplied) — a different key set is a different pytree structure, so jit
+    """Hierarchical sharded routing.  The `dyn` key set follows the input
+    mode (dense vs tiled weights/telemetry) and the call's live terms
+    (``sc.terms``: an optional vector is there exactly when its term is)
+    — a different key set is a different pytree structure, so jit
     re-traces exactly when the mode changes."""
     # -- tiled template scoring (replicated small matmuls + gathers) --
     # Quantized storage: template weights may live in bf16 on device; the
@@ -746,13 +728,13 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
     if "tool_doc_map" in dyn:
         w_tool_t = dyn["w_tool_t"].astype(jnp.float32)
         t_full = bm25_fn(dyn["q_tool"], w_tool_t)
-        if sc.rerank:
+        if sc.terms.rerank:
             v_full = bm25_fn(dyn["q_rerank"], w_tool_t)
         if not compact2:
             pre["t_pre"] = jnp.transpose(
                 jnp.take(t_full, dyn["tool_doc_map"], axis=1), (1, 0, 2)
             )
-            if sc.rerank:
+            if sc.terms.rerank:
                 pre["val_pre"] = jnp.transpose(
                     jnp.take(v_full, dyn["tool_doc_map"], axis=1), (1, 0, 2)
                 )
@@ -786,7 +768,7 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
     v_sh, gid_sh = _run_stage(f1, mesh, arrays1, specs1, 2)
 
     # -- merge 1: the small all-gather + global top-s (Eq. 2) --
-    top_s = min(sc.top_s, sc.n_servers)
+    top_s = min(sc.cfg.top_s, sc.n_servers)
     _, pos = jax.lax.top_k(_flatten_shards(v_sh), top_s)
     cand_gids = jnp.take_along_axis(_flatten_shards(gid_sh), pos, axis=-1)
 
@@ -813,7 +795,7 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
         else:
             add2("q_tool", _REP2)
             add2("w_tool", _SH3)
-        if sc.rerank and "t_pre" not in pre:
+        if sc.terms.rerank and "t_pre" not in pre:
             add2("q_rerank", _REP2)
         if "val_pre" in pre:
             add2("val_pre", _SH3)
@@ -862,37 +844,34 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
         aff = _flatten_shards(aff_c)
         gid = _flatten_shards(gid_c)
 
-    net_active = sc.use_network and (
-        "lat" in dyn or "lat_t" in dyn
-    )
     # SONAR-ADAPT: the replicated live weight vector (updated once per
     # route, identically for every shard) replaces the static floats on
     # its active terms; inactive terms keep the structural literals so the
     # reduction identities survive adaptation
+    cfg, terms = sc.cfg, sc.terms
     aw = dyn.get("adapt_w")
-    if net_active:
+    if terms.network:
         if aw is not None:
             eff_alpha, eff_beta = aw[0], aw[1]
         else:
-            eff_alpha, eff_beta = sc.alpha, sc.beta
+            eff_alpha, eff_beta = cfg.alpha, cfg.beta
     else:
         eff_alpha, eff_beta = 1.0, 0.0
-    if sc.use_load and "load" in dyn:
-        eff_gamma = aw[2] if aw is not None else sc.gamma
+    if terms.load:
+        eff_gamma = aw[2] if aw is not None else cfg.gamma
     else:
         eff_gamma = 0.0
-    if sc.use_rtt and ("rtt" in dyn or "rtt_region" in dyn):
-        eff_delta = aw[3] if aw is not None else sc.delta
+    if terms.rtt:
+        eff_delta = aw[3] if aw is not None else cfg.delta
     else:
         eff_delta = 0.0
-    dead_arg = dead if (sc.use_failover and "dead" in dyn) else None
+    dead_arg = dead if terms.failover else None
     # pass tool_aff=None when the bonus is off so no-affinity configs
     # trace the historical 4-term graph byte-identically
-    aff_active = sc.use_aff and "aff" in dyn
-    aff_arg = aff if aff_active else None
-    eff_eps = sc.eps if aff_active else 0.0
+    aff_arg = aff if terms.affinity else None
+    eff_eps = cfg.eps if terms.affinity else 0.0
 
-    k_final = min(sc.top_k, sc.n_tools)
+    k_final = min(cfg.top_k, sc.n_tools)
     if sc.use_kernels:
         # static weights stay Python floats (constant-folded kernel);
         # traced ones (SONAR-ADAPT) enter the shard_map as operands
@@ -905,7 +884,7 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
             return ops.fused_select(
                 arrs["sel"], arrs["val"], arrs["qos"], arrs["load"],
                 arrs["dead"], k=k_final, tool_rtt=arrs["rtt"],
-                tool_aff=arrs["aff"], eps=eff_eps, temp=sc.temp,
+                tool_aff=arrs["aff"], eps=eff_eps, temp=cfg.expertise_temp,
                 interpret=sc.interpret, **{**weights, **w},
             )
 
@@ -918,7 +897,7 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
             k=k_final, alpha=eff_alpha, beta=eff_beta, gamma=eff_gamma,
             tool_rtt=rtt, delta=eff_delta,
             tool_aff=aff_arg, eps=eff_eps,
-            temp=sc.temp,
+            temp=cfg.expertise_temp,
         )
     tool_idx = jnp.take_along_axis(gid, pos[:, None], axis=-1)[:, 0]
     server_idx = jnp.take(dyn["tool_server"], tool_idx)
@@ -929,8 +908,19 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
 # Engine
 # ---------------------------------------------------------------------------
 
-class ShardedRoutingEngine:
+class ShardedRoutingEngine(RoutingEngineBase):
     """Mesh-sharded drop-in for `BatchRoutingEngine` at mega-fleet scale.
+
+    Shares the host layer (`batch_routing.RoutingEngineBase`) and runs
+    `_route_sharded`; `route` additionally takes the keyword-only
+    ``telemetry_templates=(compact [M, T], template_map [n_servers])``:
+    telemetry in template-compact form — QoS is computed per template row
+    and gathered per server, identical to densified scoring but without
+    materializing [n_servers, T].  For SONAR-GEO the ``(client_region
+    [n_q], region_rtt_ms [n_regions, n_servers])`` pair keeps the RTT input
+    compact the same way: the matrix is sharded over the server axis once
+    and each shard gathers its queries' rows, so a mega fleet never
+    materializes a per-query [n_q, n_servers] RTT slab.
 
     Parameters
     ----------
@@ -938,7 +928,7 @@ class ShardedRoutingEngine:
         The fleet (ignored when `index` is given).
     cfg : RoutingConfig
     algo : str
-        One of the six registered algorithms (``rag`` .. ``sonar_ft``).
+        One of the nine registered algorithms (``rag`` .. ``sonar_adapt``).
     n_shards : int
         Server-axis partitions.  Clamped to ``n_servers``.
     mesh : Mesh | "auto" | None
@@ -951,8 +941,7 @@ class ShardedRoutingEngine:
         Pre-built index; a `TiledFleetIndex` enables template-gathered
         scoring (no fleet-sized weight matrices anywhere).
     registry : MetricsRegistry, optional
-        Where `route` times its upload, enqueue and readback phases (see
-        `batch_routing.engine_phase_histograms`).
+        Where `route` times its upload, enqueue and readback phases.
     """
 
     def __init__(
@@ -969,25 +958,9 @@ class ShardedRoutingEngine:
         adapt: Optional[_adaptive.AdaptConfig] = None,
         registry=None,
     ):
-        if use_kernels is None:
-            use_kernels = jax.default_backend() == "tpu"
-        self._m_phase = engine_phase_histograms(registry)
-        self.cfg = cfg
-        self.algo = algo.lower().replace("-", "_")
-        router_cls = ALGORITHMS[self.algo]
-        self.uses_prediction = router_cls.uses_prediction
-        self.uses_network = router_cls.uses_network
-        self.uses_load = router_cls.uses_load
-        self.uses_staleness = router_cls.uses_staleness
-        self.uses_failover = router_cls.uses_failover
-        self.uses_rtt = router_cls.uses_rtt
-        self.uses_affinity = router_cls.uses_affinity
-        self.rerank = router_cls.rerank
-        self.use_kernels = use_kernels
-        self.interpret = interpret
-        if index is None:
-            index = ToolIndex(servers)
-        self.index = index
+        super().__init__(index if index is not None else ToolIndex(servers),
+                         cfg, algo, use_kernels, interpret, adapt, registry)
+        index = self.index
         self.tiled = bool(getattr(index, "is_tiled", False))
         self.n_servers = (
             index.n_servers if self.tiled else len(index.servers)
@@ -1067,40 +1040,17 @@ class ShardedRoutingEngine:
             self._w_server_sh = jnp.asarray(ws[self.plan.server_gid])
             self._w_tool_sh = jnp.asarray(wt[self.plan.tool_gid])
 
+        # the engine's own statics; each call adds its live terms
         self._sc = _StaticCfg(
+            cfg=cfg, terms=self.terms,
             n_shards=self.plan.n_shards,
-            top_s=cfg.top_s, top_k=cfg.top_k,
             n_servers=self.n_servers, n_tools=int(index.n_tools),
             s_keep=min(cfg.top_s, self.plan.s_pad),
             k_keep=min(cfg.top_k, self.plan.t_pad),
-            alpha=cfg.alpha, beta=cfg.beta, gamma=cfg.gamma,
-            load_knee=cfg.load_knee, load_sharp=cfg.load_sharp,
-            delta=cfg.delta, rtt_scale=cfg.rtt_scale_ms,
-            temp=cfg.expertise_temp,
-            stale_half_life=cfg.stale_half_life_s,
-            use_network=self.uses_network, use_load=self.uses_load,
-            use_staleness=self.uses_staleness,
-            use_failover=self.uses_failover,
-            use_rtt=self.uses_rtt,
-            rerank=self.rerank, use_kernels=use_kernels,
-            interpret=interpret, qos_params=cfg.qos,
+            use_kernels=self.use_kernels, interpret=interpret,
             compact2=self.compact_stage2, k_slot=k_slot,
-            use_aff=self.uses_affinity, eps=cfg.eps,
-            row_blocks=bool(getattr(index, "row_blocks", False)),
+            row_blocks=self._row_blocks,
         )
-
-        # SONAR-ADAPT learner state.  Replicated-update semantics: the EG
-        # step runs ONCE per route in the standalone jit update and the
-        # resulting weight vector enters `_route_sharded` as a replicated
-        # operand, so every shard fuses with bitwise-identical weights —
-        # the distributed equivalent of "identical updates per shard".
-        self.adapt_cfg: Optional[_adaptive.AdaptConfig] = None
-        self.adapt_state: Optional[_adaptive.AdaptState] = None
-        self._fb_rewards: list = []
-        self._fb_feats: list = []
-        if self.algo == "sonar_adapt" or adapt is not None:
-            self.adapt_cfg = adapt if adapt is not None else _adaptive.AdaptConfig()
-            self.adapt_state = _adaptive.init_state(cfg, self.adapt_cfg)
 
     def _resolve_mesh(self, mesh):
         if mesh is None:
@@ -1119,55 +1069,6 @@ class ShardedRoutingEngine:
             f"{self.plan.n_shards} shards"
         )
         return mesh
-
-    # -- host side ----------------------------------------------------------
-    def encode(self, queries: Sequence[str]) -> EncodedBatch:
-        """Strings -> term-count matrices (see `BatchRoutingEngine.encode`)."""
-        return encode_for_index(
-            self.index, self.uses_prediction, self.rerank, queries
-        )
-
-    def select_latency_ms(self) -> float:
-        from repro.core.routing import BM25_STAGE_MS, LLM_CALL_MS, LLM_RERANK_MS
-
-        sl = LLM_CALL_MS + 2 * BM25_STAGE_MS
-        if self.rerank:
-            sl += LLM_RERANK_MS
-        return sl
-
-    # -- SONAR-ADAPT feedback (mirrors BatchRoutingEngine) -------------------
-    @property
-    def adapt_weights(self) -> Optional[np.ndarray]:
-        if self.adapt_state is None:
-            return None
-        return np.asarray(self.adapt_state.weights, np.float32)
-
-    def observe_feedback(
-        self,
-        latency_ms: float,
-        ok: bool = True,
-        feats: Optional[np.ndarray] = None,
-    ) -> None:
-        if self.adapt_state is None or feats is None:
-            return
-        self._fb_rewards.append(
-            _adaptive.shape_reward(latency_ms, ok, self.adapt_cfg.slo_ms)
-        )
-        self._fb_feats.append(np.asarray(feats, np.float32))
-
-    def _apply_feedback(self) -> None:
-        """Fold every pending outcome into the weight vector through the
-        shared jit update (fixed FEEDBACK_BUCKET shape per step)."""
-        B = _adaptive.FEEDBACK_BUCKET
-        while self._fb_rewards:
-            r, f, v = _adaptive.pad_feedback(
-                self._fb_rewards[:B], self._fb_feats[:B], B
-            )
-            self.adapt_state = _adaptive.adapt_update(
-                self.adapt_state, r, f, v, self.adapt_cfg
-            )
-            del self._fb_rewards[:B]
-            del self._fb_feats[:B]
 
     # -- sharding helpers ---------------------------------------------------
     def _shard_vec(self, x) -> jax.Array:
@@ -1188,74 +1089,7 @@ class ShardedRoutingEngine:
         )
 
     # -- device side --------------------------------------------------------
-    def route(
-        self,
-        batch: EncodedBatch,
-        latency_hist: Optional[np.ndarray] = None,
-        server_load: Optional[np.ndarray] = None,
-        telemetry_age_s: Optional[np.ndarray] = None,
-        failed_mask: Optional[np.ndarray] = None,
-        client_rtt_ms: Optional[np.ndarray] = None,
-        client_region: Optional[np.ndarray] = None,
-        region_rtt_ms: Optional[np.ndarray] = None,
-        affinity: Optional[np.ndarray] = None,
-        *,
-        telemetry_templates: Optional[tuple] = None,
-        route_stats=None,
-        n_real=None,
-    ) -> BatchDecisions:
-        """Route an encoded batch across the sharded fleet.
-
-        Parameters mirror `BatchRoutingEngine.route`; additionally
-        ``telemetry_templates=(compact [M, T], template_map [n_servers])``
-        supplies telemetry in template-compact form — QoS is computed per
-        template row and gathered per server, identical to densified
-        scoring but without materializing [n_servers, T].  For SONAR-GEO
-        the ``(client_region [n_q], region_rtt_ms [n_regions, n_servers])``
-        pair keeps the RTT input compact the same way: the matrix is
-        sharded over the server axis once and each shard gathers its
-        queries' rows, so a mega fleet never materializes a per-query
-        [n_q, n_servers] RTT slab.
-        """
-        if batch.n == 0:
-            z = np.zeros((0,), np.float32)
-            return BatchDecisions(
-                server_idx=z.astype(np.int32), tool_idx=z.astype(np.int32),
-                expertise=z, network=z, fused=z,
-                select_latency_ms=self.select_latency_ms(),
-            )
-        with obs_trace.annotate("engine.upload", self._m_phase["upload"]):
-            dyn = self._dyn(
-                batch, latency_hist, server_load, telemetry_age_s,
-                failed_mask, client_rtt_ms, client_region, region_rtt_ms,
-                affinity, telemetry_templates=telemetry_templates,
-            )
-        with obs_trace.annotate("engine.enqueue", self._m_phase["enqueue"]):
-            server_idx, tool_idx, c, n, s = _route_sharded(
-                dyn, mesh=self.mesh, sc=self._sc
-            )
-            if route_stats is not None:
-                # fold this call's device outputs into the jit-safe stats
-                # buffer (donated .at[].add) before any host conversion
-                route_stats.accumulate(server_idx, c, n, s, n_real=n_real)
-        with obs_trace.annotate("engine.readback", self._m_phase["readback"]):
-            return BatchDecisions(
-                server_idx=np.asarray(server_idx, np.int32),
-                tool_idx=np.asarray(tool_idx, np.int32),
-                expertise=np.asarray(c), network=np.asarray(n),
-                fused=np.asarray(s),
-                select_latency_ms=self.select_latency_ms(),
-            )
-
-    def lower(self, batch: EncodedBatch, *args, **kw) -> jax.stages.Lowered:
-        """The program `route` would run on these inputs (same arguments,
-        without ``route_stats``/``n_real``), lowered but not run; see
-        `BatchRoutingEngine.lower`."""
-        return _route_sharded.lower(
-            self._dyn(batch, *args, **kw), mesh=self.mesh, sc=self._sc
-        )
-
-    def _dyn(
+    def _program(
         self,
         batch: EncodedBatch,
         latency_hist=None,
@@ -1267,9 +1101,15 @@ class ShardedRoutingEngine:
         region_rtt_ms=None,
         affinity=None,
         *,
+        adapt_w=None,
         telemetry_templates=None,
-    ) -> dict:
-        """The dynamic operands of `_route_sharded` for one call."""
+    ) -> tuple:
+        """`_route_sharded` and its operands for one call: the `dyn` key
+        set follows the call's live terms."""
+        terms = self._live(latency_hist, server_load, telemetry_age_s,
+                           failed_mask, client_rtt_ms, client_region,
+                           region_rtt_ms, affinity,
+                           templates=telemetry_templates is not None)
         dyn: dict = {
             "tool_server": self._tool_server,
             "server_gid": self._server_gid,
@@ -1281,7 +1121,7 @@ class ShardedRoutingEngine:
             "q_server": jnp.asarray(batch.q_server),
             "q_tool": jnp.asarray(batch.q_tool),
         }
-        if self.rerank:
+        if terms.rerank:
             dyn["q_rerank"] = jnp.asarray(batch.q_rerank)
         if self.tiled:
             dyn["w_server_t"] = self._w_server_t
@@ -1295,79 +1135,35 @@ class ShardedRoutingEngine:
         else:
             dyn["w_server"] = self._w_server_sh
             dyn["w_tool"] = self._w_tool_sh
-        if self.uses_network:
-            if telemetry_templates is not None:
-                compact, tmap = telemetry_templates
-                dyn["lat_t"] = jnp.asarray(compact, jnp.float32)
-                dyn["tel_map"] = jnp.asarray(
-                    np.asarray(tmap, np.int32)[self.plan.server_gid]
-                )
-            elif latency_hist is not None:
-                dyn["lat"] = self._shard_hist(latency_hist)
-        if (
-            self.uses_load
-            and server_load is not None
-            and self.cfg.gamma != 0.0
-        ):
+        if terms.network and telemetry_templates is not None:
+            compact, tmap = telemetry_templates
+            dyn["lat_t"] = jnp.asarray(compact, jnp.float32)
+            dyn["tel_map"] = jnp.asarray(
+                np.asarray(tmap, np.int32)[self.plan.server_gid]
+            )
+        elif terms.network:
+            dyn["lat"] = self._shard_hist(latency_hist)
+        if terms.load:
             dyn["load"] = self._shard_vec(server_load)
-        if self.uses_staleness and telemetry_age_s is not None:
+        if terms.staleness:
             dyn["age"] = self._shard_vec(telemetry_age_s)
-        if self.uses_rtt and self.cfg.delta != 0.0:
-            if client_rtt_ms is not None:
-                dyn["rtt"] = self._shard_vec(client_rtt_ms)
-            elif client_region is not None and region_rtt_ms is not None:
-                rr = jnp.asarray(region_rtt_ms, jnp.float32)
-                dyn["rtt_region"] = jnp.transpose(
-                    jnp.take(rr, self._server_gid, axis=1), (1, 0, 2)
-                )                                         # [J, R, s_pad]
-                dyn["region_idx"] = jnp.asarray(client_region, jnp.int32)
-        if self.uses_failover and failed_mask is not None:
+        if terms.rtt and client_rtt_ms is not None:
+            dyn["rtt"] = self._shard_vec(client_rtt_ms)
+        elif terms.rtt:
+            rr = jnp.asarray(region_rtt_ms, jnp.float32)
+            dyn["rtt_region"] = jnp.transpose(
+                jnp.take(rr, self._server_gid, axis=1), (1, 0, 2)
+            )                                             # [J, R, s_pad]
+            dyn["region_idx"] = jnp.asarray(client_region, jnp.int32)
+        if terms.failover:
             dyn["dead"] = self._shard_vec(
                 np.asarray(failed_mask, np.float32)
             )
-        if (
-            self.uses_affinity
-            and affinity is not None
-            and self.cfg.eps != 0.0
-        ):
+        if terms.affinity:
             dyn["aff"] = self._shard_vec(affinity)
-        if self.adapt_state is not None and self.adapt_cfg.lr != 0.0:
-            # apply pending EG updates once, then replicate the weights
-            # into the sharded program (lr == 0 keeps the static program:
-            # byte-identical to the hand-tuned variant's)
-            self._apply_feedback()
-            dyn["adapt_w"] = self.adapt_state.weights
-        return dyn
-
-    def route_texts(
-        self,
-        queries: Sequence[str],
-        latency_hist: Optional[np.ndarray] = None,
-        server_load: Optional[np.ndarray] = None,
-        telemetry_age_s: Optional[np.ndarray] = None,
-        failed_mask: Optional[np.ndarray] = None,
-        client_rtt_ms: Optional[np.ndarray] = None,
-        client_region: Optional[np.ndarray] = None,
-        region_rtt_ms: Optional[np.ndarray] = None,
-        affinity: Optional[np.ndarray] = None,
-        *,
-        telemetry_templates: Optional[tuple] = None,
-    ) -> BatchDecisions:
-        return self.route(
-            self.encode(queries), latency_hist, server_load,
-            telemetry_age_s, failed_mask, client_rtt_ms,
-            client_region, region_rtt_ms, affinity,
-            telemetry_templates=telemetry_templates,
-        )
-
-
-def make_sharded_engine(
-    algo: str,
-    servers: Optional[Sequence[Server]] = None,
-    cfg: RoutingConfig = RoutingConfig(),
-    n_shards: int = 1,
-    **kw,
-) -> ShardedRoutingEngine:
-    return ShardedRoutingEngine(
-        servers, cfg, algo=algo, n_shards=n_shards, **kw
-    )
+        if adapt_w is not None:
+            # replicated into the sharded program: every shard fuses with
+            # bitwise-identical weights
+            dyn["adapt_w"] = adapt_w
+        return (_route_sharded, (dyn,),
+                dict(mesh=self.mesh, sc=self._sc._replace(terms=terms)))

@@ -578,8 +578,56 @@ ALGORITHMS = {
 }
 
 
-def make_router(name: str, servers: Sequence[Server], cfg: RoutingConfig = RoutingConfig()) -> Router:
+def _router_cls(name: str) -> type:
     key = name.lower().replace("-", "_")
     if key not in ALGORITHMS and key == "sonar_adapt":
         import repro.core.adaptive  # noqa: F401  (registers sonar_adapt)
-    return ALGORITHMS[key](servers, cfg)
+    return ALGORITHMS[key]
+
+
+def make_router(name: str, servers: Sequence[Server], cfg: RoutingConfig = RoutingConfig()) -> Router:
+    return _router_cls(name)(servers, cfg)
+
+
+@dataclasses.dataclass(frozen=True)
+class Terms:
+    """The score terms of an algorithm (`algo_terms`), or of one routing
+    call (`live`).  Frozen and hashable: the engines hand a call's terms
+    to jit as one static argument."""
+
+    prediction: bool = False
+    network: bool = False
+    load: bool = False
+    staleness: bool = False
+    failover: bool = False
+    rtt: bool = False
+    affinity: bool = False
+    rerank: bool = False
+
+    def live(self, cfg: RoutingConfig, *, network: bool = False,
+             load: bool = False, staleness: bool = False,
+             failover: bool = False, rtt: bool = False,
+             affinity: bool = False) -> "Terms":
+        """The terms a call carries, given which inputs it supplies: a term
+        is live when the algorithm uses it, its input is present, and its
+        weight (``gamma``, ``delta``, ``eps``) is not 0."""
+        return dataclasses.replace(
+            self,
+            network=self.network and network,
+            load=self.load and load and cfg.gamma != 0.0,
+            staleness=self.staleness and staleness,
+            failover=self.failover and failover,
+            rtt=self.rtt and rtt and cfg.delta != 0.0,
+            affinity=self.affinity and affinity and cfg.eps != 0.0,
+        )
+
+
+def algo_terms(name: str) -> Terms:
+    """The terms of the registered algorithm ``name``."""
+    cls = _router_cls(name)
+    return Terms(
+        prediction=cls.uses_prediction, network=cls.uses_network,
+        load=cls.uses_load, staleness=cls.uses_staleness,
+        failover=cls.uses_failover, rtt=cls.uses_rtt,
+        affinity=cls.uses_affinity, rerank=cls.rerank,
+    )

@@ -34,7 +34,12 @@ from repro.core.batch_routing import BatchRoutingEngine
 from repro.core.dataset import Server, Tool
 from repro.core.mesh_routing import ShardedRoutingEngine
 from repro.core.qos import load_penalty, rtt_penalty
-from repro.core.routing import ALGORITHMS, RoutingConfig, SonarRouter  # noqa: F401
+from repro.core.routing import (  # noqa: F401
+    ALGORITHMS,
+    RoutingConfig,
+    SonarRouter,
+    algo_terms,
+)
 from repro.obs import Observability
 from repro.obs import trace as obs_trace
 from repro.sessions.warmth import WarmthTracker
@@ -324,6 +329,13 @@ class SonarGateway:
             None if region_rtt_ms is None
             else np.asarray(region_rtt_ms, np.float32)
         )
+        # the terms a routing call here can carry, decided once: telemetry,
+        # load and health rows always, RTT with a region matrix, warmth
+        # with a session
+        self.terms = algo_terms(self.algo).live(
+            cfg, network=True, load=True, failover=True,
+            rtt=self.region_rtt_ms is not None, affinity=True,
+        )
         self._engine = None
         # observability: all gateway accounting lives in the registry
         # (report() reads it back — one source of truth shared with the
@@ -482,14 +494,8 @@ class SonarGateway:
 
     def _rtt_row(self, client_region: Optional[int]) -> Optional[np.ndarray]:
         """[n_replicas] RTT row for one client region (None when the
-        gateway has no RTT matrix, the algorithm is locality-blind, or the
-        request is untagged)."""
-        if (
-            self.region_rtt_ms is None
-            or not getattr(self.router, "uses_rtt", False)
-            or client_region is None
-            or client_region < 0
-        ):
+        RTT term is not live here, or the request is untagged)."""
+        if not self.terms.rtt or client_region is None or client_region < 0:
             return None
         return self.region_rtt_ms[int(client_region)]
 
@@ -497,12 +503,10 @@ class SonarGateway:
         self, session_id: Optional[int]
     ) -> Optional[np.ndarray]:
         """[n_replicas] warmth row for one session (None when the request
-        is session-less, the algorithm is affinity-blind, or the session
+        is session-less, the affinity term is not live here, or the session
         has fully cooled — None keeps the router on the exact
         zero-affinity scoring path)."""
-        if session_id is None or not getattr(
-            self.router, "uses_affinity", False
-        ):
+        if session_id is None or not self.terms.affinity:
             return None
         return self.session_warmth.warmth(int(session_id), float(self.t))
 
@@ -544,13 +548,13 @@ class SonarGateway:
         decision metadata plus the load/RTT terms at dispatch time."""
         cfg = self.router.cfg
         u = 0.0
-        if getattr(self.router, "uses_load", False) and cfg.gamma != 0.0:
+        if self.terms.load:
             u = float(load_penalty(
                 self._utilization()[idx], cfg.load_knee, cfg.load_sharp
             ))
         r = 0.0
         rtt_row = self._rtt_row(client_region)
-        if rtt_row is not None and cfg.delta != 0.0:
+        if rtt_row is not None:
             r = float(rtt_penalty(rtt_row[idx], cfg.rtt_scale_ms))
         return _adaptive.decision_feats(expertise, network, u, r)
 
@@ -565,7 +569,7 @@ class SonarGateway:
         request regardless of chunking.  Never masks the whole fleet for
         any request (a single-replica pool with its replica ejected must
         still route — the request *is* the probe)."""
-        if not self.router.uses_failover:
+        if not self.terms.failover:
             return None
         with obs_trace.annotate("gateway.health_mask",
                                 self._m_phase["health"]):
@@ -765,7 +769,7 @@ class SonarGateway:
         eng = self.engine()
         sub = eng.encode([""] * rows)
         masks = [None]
-        if self.router.uses_failover:
+        if self.terms.failover:
             masks.append(np.zeros((rows, self.n_replicas), bool))
             masks[-1][:, 0] = True
         for mask in masks:
@@ -822,18 +826,11 @@ class SonarGateway:
                 for i, t in enumerate(request_texts)
             ]
         eng = self.engine()
-        use_geo = (
-            client_regions is not None
-            and self.region_rtt_ms is not None
-            and getattr(self.router, "uses_rtt", False)
-        )
+        use_geo = client_regions is not None and self.terms.rtt
         regions_arr = (
             np.asarray(client_regions, np.int32) if use_geo else None
         )
-        use_aff = (
-            session_ids is not None
-            and getattr(self.router, "uses_affinity", False)
-        )
+        use_aff = session_ids is not None and self.terms.affinity
         with obs_trace.annotate("gateway.encode", self._m_phase["encode"]):
             enc = eng.encode(request_texts)
         dispatch_ms = 0.0

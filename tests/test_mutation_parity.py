@@ -190,16 +190,27 @@ def _scalar_weights_moved(n_steps: int = 24) -> bool:
     return bool(np.any(np.asarray(router.state.weights) != init))
 
 
-def _engine_weights_moved(n_rounds: int = 12) -> bool:
-    """Same trajectory probe through the batched engine's fused in-jit
-    update (feedback drains into the routed program on the next call)."""
+def _adapt_engine(sharded: bool):
+    """A SONAR-ADAPT engine on the load fixture: the batched engine, or
+    the sharded one over 3 shards."""
     from repro.core import adaptive
+    from repro.core.mesh_routing import ShardedRoutingEngine
 
-    servers, hist, load, _, _ = _fixture("load")
-    eng = BatchRoutingEngine(
-        servers, CFG, algo="sonar_adapt",
-        adapt=adaptive.AdaptConfig(slo_ms=200.0),
-    )
+    servers, _, _, _, _ = _fixture("load")
+    acfg = adaptive.AdaptConfig(slo_ms=200.0)
+    if sharded:
+        return ShardedRoutingEngine(servers, CFG, algo="sonar_adapt",
+                                    n_shards=3, use_kernels=False,
+                                    adapt=acfg)
+    return BatchRoutingEngine(servers, CFG, algo="sonar_adapt", adapt=acfg)
+
+
+def _engine_weights_moved(sharded: bool, n_rounds: int = 12) -> bool:
+    """Same trajectory probe through an engine: feedback queued by
+    `observe_feedback` is folded into the weights (the standalone
+    `adaptive.adapt_update`) on the next route call."""
+    _, hist, load, _, _ = _fixture("load")
+    eng = _adapt_engine(sharded)
     init = np.asarray(eng.adapt_state.weights).copy()
     feats = np.asarray([0.6, 0.4, -0.3, 0.0], np.float32)
     for i in range(n_rounds):
@@ -210,25 +221,32 @@ def _engine_weights_moved(n_rounds: int = 12) -> bool:
     return bool(np.any(np.asarray(eng.adapt_state.weights) != init))
 
 
-@pytest.mark.parametrize("probe", ["scalar", "engine"])
+def _weights_moved(probe: str) -> bool:
+    if probe == "scalar":
+        return _scalar_weights_moved()
+    return _engine_weights_moved(sharded=probe == "sharded")
+
+
+PROBES = ["scalar", "engine", "sharded"]
+
+
+@pytest.mark.parametrize("probe", PROBES)
 def test_adaptation_trajectory_moves_unmutated(probe):
     """Green baseline: with the real update and reward, the trajectory
-    assertion holds on both the scalar and the fused engine path."""
-    moved = _scalar_weights_moved() if probe == "scalar" else (
-        _engine_weights_moved()
-    )
-    assert moved, (
+    assertion holds on the scalar path and on both engines."""
+    assert _weights_moved(probe), (
         f"{probe}: SONAR-ADAPT weights never left their init under "
         "informative feedback — the trajectory probe is vacuous"
     )
 
 
-@pytest.mark.parametrize("probe", ["scalar", "engine"])
+@pytest.mark.parametrize("probe", PROBES)
 def test_mutation_identity_update_freezes_trajectory(probe, monkeypatch):
     """Killing the EG step (identity `_adapt_step`) must freeze the
-    weight trajectory on both update paths.  `_adapt_step` is looked up
-    on the module at trace time, so the patch + a compilation-cache drop
-    reaches the standalone jit update AND the engine's fused program."""
+    weight trajectory on every path.  `_adapt_step` is looked up on the
+    module at trace time, so the patch + a compilation-cache drop reaches
+    the standalone jit update that the scalar router and both engines
+    run."""
     import jax
 
     from repro.core import adaptive
@@ -239,10 +257,7 @@ def test_mutation_identity_update_freezes_trajectory(probe, monkeypatch):
     )
     jax.clear_caches()
     try:
-        moved = _scalar_weights_moved() if probe == "scalar" else (
-            _engine_weights_moved()
-        )
-        assert not moved, (
+        assert not _weights_moved(probe), (
             f"{probe}: weights moved with the update step mutated to the "
             "identity — the trajectory assertion does not depend on "
             "`_adapt_step`"
@@ -251,7 +266,7 @@ def test_mutation_identity_update_freezes_trajectory(probe, monkeypatch):
         jax.clear_caches()
 
 
-@pytest.mark.parametrize("probe", ["scalar", "engine"])
+@pytest.mark.parametrize("probe", PROBES)
 def test_mutation_dead_reward_freezes_trajectory(probe, monkeypatch):
     """Killing the reward signal (shape_reward == 0 for every outcome)
     must also freeze the trajectory: with a zero reward stream and a zero
@@ -263,13 +278,41 @@ def test_mutation_dead_reward_freezes_trajectory(probe, monkeypatch):
     monkeypatch.setattr(
         adaptive, "shape_reward", lambda latency_ms, ok, slo_ms=800.0: 0.0
     )
-    moved = _scalar_weights_moved() if probe == "scalar" else (
-        _engine_weights_moved()
-    )
-    assert not moved, (
+    assert not _weights_moved(probe), (
         f"{probe}: weights moved with a dead reward signal — the "
         "trajectory assertion does not depend on `shape_reward`"
     )
+
+
+def test_engines_share_one_adapt_trajectory():
+    """The batched engine and the sharded engine (3 shards) fed the same
+    `observe_feedback` stream hold bitwise-equal weights after every one
+    of 12 routes — one update scheme.  The stream's per-route counts
+    include empty rounds, a full `FEEDBACK_BUCKET` and overflows past
+    it."""
+    from repro.core import adaptive
+
+    _, hist, load, _, _ = _fixture("load")
+    engines = [_adapt_engine(sharded=False), _adapt_engine(sharded=True)]
+    rng = np.random.default_rng(7)
+    B = adaptive.FEEDBACK_BUCKET
+    counts = [1, 5, B + 6, 0, 3, B, B + 1, 2, 1, 9, 2 * B + 2, 4]
+    init = np.asarray(engines[0].adapt_state.weights).copy()
+    for r, count in enumerate(counts):
+        lat = rng.uniform(20.0, 1500.0, count)
+        ok = rng.random(count) > 0.2
+        feats = rng.uniform(-1.0, 1.0, (count, 4)).astype(np.float32)
+        for eng in engines:
+            for i in range(count):
+                eng.observe_feedback(float(lat[i]), ok=bool(ok[i]),
+                                     feats=feats[i])
+            eng.route_texts([QUERY], hist, load)
+        np.testing.assert_array_equal(
+            np.asarray(engines[0].adapt_state.weights),
+            np.asarray(engines[1].adapt_state.weights),
+            err_msg=f"route {r}: the engines' weights diverged",
+        )
+    assert np.any(np.asarray(engines[0].adapt_state.weights) != init)
 
 
 def test_parity_suite_detects_oracle_mutation(monkeypatch):
