@@ -46,7 +46,11 @@ Mega fleets (10^5-10^6 servers) use a `TiledFleetIndex`: servers are
 instances of a small set of template servers, BM25 weights are stored once
 per template (corpus statistics computed over the *expanded* fleet) and
 per-shard scores are gathered from one small template matmul instead of a
-fleet-sized one.  Telemetry can likewise stay compact: ``route`` accepts
+fleet-sized one.  On the kernel path stage 1 does not gather them: each
+shard streams the [n_q, M] template scores over its replica -> template
+map through a running top-s (`ops.stage1_select`), bit-identical to the
+gather plus ``lax.top_k`` the jnp path runs, without that row or its
+sort.  Telemetry can likewise stay compact: ``route`` accepts
 ``telemetry_templates=(compact [M, T], template_map [n_servers])`` and
 computes QoS per template row, then gathers per server — identical scores
 (identical rows), no [n_servers, T] densification anywhere.
@@ -89,9 +93,11 @@ from repro.core.qos import (
 from repro.core.routing import RoutingConfig, Terms, ToolIndex
 from repro.kernels import ops
 from repro.kernels import ref as kref
+from repro.kernels.stage1_select import MAX_TEMPLATES
+from repro.kernels.stage1_select import PAD_NEG  # below every real score
+from repro.obs import trace as obs_trace
 
 NEG = kref.NEG
-PAD_NEG = 2.0 * NEG   # pad sentinel: sorts strictly below every real score
 
 
 # ---------------------------------------------------------------------------
@@ -342,29 +348,42 @@ def _qos_2d(lat: jax.Array, sc: _StaticCfg) -> jax.Array:
     return network_score(lat, sc.cfg.qos)
 
 
+def _stage1_streams(use_kernels: bool, n_templates: int) -> bool:
+    """Whether stage 1 of a tiled index streams the template scores
+    through `ops.stage1_select`: on the kernel path, whenever they fit its
+    block."""
+    return use_kernels and n_templates <= MAX_TEMPLATES
+
+
 def _stage1_stacked(d: dict, sc: _StaticCfg) -> tuple:
     """Shard-local stage 1: server scores + local top-s.
 
     Returns (values [J, n_q, s_keep], global server ids [J, n_q, s_keep]).
     """
-    if "s_pre" in d:
-        s = d["s_pre"]                                   # [J, n_q, s_pad]
+    if "s_full" in d:
+        # tiled, kernel path: the template scores [n_q, M] streamed over
+        # the shard's replica map — no [n_q, s_pad] row and no sort
+        dead = d["dead"] if sc.terms.failover else None
+        v, li = ops.stage1_select(d["s_full"], d["server_doc_map"], dead,
+                                  k=sc.s_keep, interpret=sc.interpret)
     else:
-        w = d["w_server"]                                # [J, s_pad, V]
-        if sc.use_kernels or sc.row_blocks:
-            J, S, V = w.shape
-            s = _bm25_2d(d["q_server"], w.reshape(J * S, V), sc)
-            s = s.reshape(-1, J, S).transpose(1, 0, 2)
+        if "s_pre" in d:
+            s = d["s_pre"]                               # [J, n_q, s_pad]
         else:
-            s = jnp.einsum("qv,jsv->jqs", d["q_server"], w,
-                           precision=bm25.HIGHEST)
-    if sc.terms.failover:
-        s = jnp.where(d["dead"] > 0.0, NEG, s)           # [J, B, s_pad] bcast
-    s = jnp.where(d["server_valid"][:, None, :], s, PAD_NEG)
-    v, li = jax.lax.top_k(s, sc.s_keep)                  # [J, n_q, s_keep]
-    gid = jnp.take_along_axis(
-        jnp.broadcast_to(d["server_gid"][:, None, :], s.shape), li, axis=-1
-    )
+            w = d["w_server"]                            # [J, s_pad, V]
+            if sc.use_kernels or sc.row_blocks:
+                J, S, V = w.shape
+                s = _bm25_2d(d["q_server"], w.reshape(J * S, V), sc)
+                s = s.reshape(-1, J, S).transpose(1, 0, 2)
+            else:
+                s = jnp.einsum("qv,jsv->jqs", d["q_server"], w,
+                               precision=bm25.HIGHEST)
+        if sc.terms.failover:
+            s = jnp.where(d["dead"] > 0.0, NEG, s)       # [J, B, s_pad] bcast
+        s = jnp.where(d["server_valid"][:, None, :], s, PAD_NEG)
+        v, li = jax.lax.top_k(s, sc.s_keep)              # [J, n_q, s_keep]
+    # the ids of the kept replicas, read without broadcasting the map
+    gid = jnp.take_along_axis(d["server_gid"][:, None, :], li, axis=-1)
     return v, gid
 
 
@@ -721,10 +740,16 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
     t_full = v_full = nt = None
     if "server_doc_map" in dyn:
         w_server_t = dyn["w_server_t"].astype(jnp.float32)
-        s_full = bm25_fn(dyn["q_server"], w_server_t)
-        pre["s_pre"] = jnp.transpose(
-            jnp.take(s_full, dyn["server_doc_map"], axis=1), (1, 0, 2)
-        )
+        s_full = bm25_fn(dyn["q_server"], w_server_t)      # [n_q, M]
+        if _stage1_streams(sc.use_kernels, s_full.shape[1]):
+            pre["s_full"] = s_full
+        else:
+            # the map's pad replicas (-1) score PAD_NEG in stage 1
+            s_pad = dyn["server_gid"].shape[1]
+            pre["s_pre"] = jnp.transpose(
+                jnp.take(s_full, dyn["server_doc_map"][:, 0, :s_pad],
+                         axis=1, mode="clip"), (1, 0, 2)
+            )
     if "tool_doc_map" in dyn:
         w_tool_t = dyn["w_tool_t"].astype(jnp.float32)
         t_full = bm25_fn(dyn["q_tool"], w_tool_t)
@@ -755,7 +780,10 @@ def _route_sharded(dyn: dict, *, mesh: Optional[Mesh], sc: _StaticCfg):
             layout1.append(name)
             specs1.append(spec)
 
-    if "s_pre" in pre:
+    if "s_full" in pre:
+        add1("s_full", _REP2)
+        add1("server_doc_map", _SH3)
+    elif "s_pre" in pre:
         add1("s_pre", _SH3)
     else:
         add1("q_server", _REP2)
@@ -983,6 +1011,7 @@ class ShardedRoutingEngine(RoutingEngineBase):
         self._tool_host_g = jnp.asarray(self.plan.tool_host_global)
         self._tool_host_l = jnp.asarray(self.plan.tool_host_local)
         self.compact_stage2 = False
+        self._stage1_streams = False
         k_slot = 0
         if self.tiled:
             # quantized storage: bf16-rounded template weights live on
@@ -999,8 +1028,14 @@ class ShardedRoutingEngine(RoutingEngineBase):
                 index.server_corpus.weights, w_dtype
             )
             self._w_tool_t = jnp.asarray(index.tool_corpus.weights, w_dtype)
-            self._server_doc_sh = jnp.asarray(
-                index.server_doc_map[self.plan.server_gid]
+            # each shard's replica -> template map, pad replicas -1, held
+            # at the width the stage-1 kernel reads
+            self._server_doc_sh = ops.stage1_map(np.where(
+                self.plan.server_valid,
+                index.server_doc_map[self.plan.server_gid], -1,
+            ))
+            self._stage1_streams = _stage1_streams(
+                self.use_kernels, index.server_corpus.weights.shape[0]
             )
             self._tool_doc_sh = jnp.asarray(
                 index.tool_doc_map[self.plan.tool_gid]
@@ -1040,6 +1075,10 @@ class ShardedRoutingEngine(RoutingEngineBase):
             self._w_server_sh = jnp.asarray(ws[self.plan.server_gid])
             self._w_tool_sh = jnp.asarray(wt[self.plan.tool_gid])
 
+        # replica scores stage 1 streamed in the last call (0: lax.top_k)
+        self._m_stage1 = self.registry.gauge(
+            "engine_stage1_streamed_per_call", "scores"
+        )
         # the engine's own statics; each call adds its live terms
         self._sc = _StaticCfg(
             cfg=cfg, terms=self.terms,
@@ -1110,6 +1149,10 @@ class ShardedRoutingEngine(RoutingEngineBase):
                            failed_mask, client_rtt_ms, client_region,
                            region_rtt_ms, affinity,
                            templates=telemetry_templates is not None)
+        streamed = float(batch.n * self.n_servers
+                         if self._stage1_streams else 0)
+        self._m_stage1.set(streamed)
+        obs_trace.note(engine_stage1_streamed_per_call=streamed)
         dyn: dict = {
             "tool_server": self._tool_server,
             "server_gid": self._server_gid,

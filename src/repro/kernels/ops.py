@@ -23,6 +23,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import qos_score as _qos
 from repro.kernels import score_fuse as _scf
 from repro.kernels import select_fuse as _sel
+from repro.kernels import stage1_select as _s1
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -169,6 +170,45 @@ def bm25_scores(
     w = _pad_to(_pad_to(jnp.asarray(weights, jnp.float32), 1, _bm25.BV), 0, _bm25.BD)
     out = _bm25.bm25_scores_pallas(q, w, interpret=_auto_interpret(interpret))
     return out[:n_q, :n_d]
+
+
+# ---------------------------------------------------------------------------
+# Stage 1 over a template-tiled shard (see kernels/stage1_select)
+# ---------------------------------------------------------------------------
+
+def stage1_map(doc_map: np.ndarray) -> jax.Array:
+    """A shard-stacked replica -> template map [J, n] (-1 = pad replica)
+    on the device as `stage1_select` reads it: [J, 1, R], the replica axis
+    -1-padded to whole stripes (`stage1_select.map_width`), so no call
+    pads it."""
+    doc_map = np.asarray(doc_map, np.int32)
+    J, n = doc_map.shape
+    out = np.full((J, 1, _s1.map_width(n)), -1, np.int32)
+    out[:, 0, :n] = doc_map
+    return jnp.asarray(out)
+
+
+def stage1_select(
+    s_full: jax.Array,          # [n_q, M] f32 template scores
+    tpl_map: jax.Array,         # [J, 1, R] i32 from `stage1_map`
+    dead: Optional[jax.Array] = None,  # [J, 1 or n_q, n] f32, > 0 = dead
+    *,
+    k: int,
+    interpret: Optional[bool] = None,
+):
+    """Each shard's top-``k`` replicas per query: values [J, n_q, k] and
+    in-shard replica indices [J, n_q, k] i32, exactly ``lax.top_k`` over
+    the template scores gathered to every replica, with dead replicas at
+    NEG and pad replicas (-1) at ``2 * NEG`` — the same floats, ties to
+    the lower replica — without building or sorting that row."""
+    n_q = s_full.shape[0]
+    s = _pad_to(jnp.asarray(s_full, jnp.float32), 0, _s1.QUERY_TILE)
+    if dead is not None and dead.shape[1] > 1:
+        dead = _pad_to(dead, 1, _s1.QUERY_TILE)
+    v, idx = _s1.stage1_select_pallas(
+        s, tpl_map, dead, k=k, interpret=_auto_interpret(interpret)
+    )
+    return v[:, :n_q], idx[:, :n_q]
 
 
 # ---------------------------------------------------------------------------

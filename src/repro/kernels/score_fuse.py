@@ -31,7 +31,9 @@ the full tool axis — because each stripe merge re-peels the combined
 from earlier stripes always carry lower gids than the current stripe, so
 stability is preserved.  The running top-k (`topk_init`, `topk_merge`,
 `topk_finale`) is shared with `select_fuse`, which streams materialized
-score stripes through the same merge and finale.  Every index (lane, gid)
+score stripes through the same merge and finale, and its init and merge
+with `stage1_select`, which keeps replica ids alone and empties slots to
+a floor below its pad sentinel.  Every index (lane, gid)
 comes from an int32 iota cast to f32, exact below 2**24; the stripe flags
 ride in SMEM, one scalar per grid step.  One caveat: a query whose
 candidate servers host zero tools (every stripe skipped) returns tool 0
@@ -86,29 +88,37 @@ def topk_scratch(use_aff: bool) -> list:
     return [pltpu.VMEM((QUERY_TILE, K_MAX), jnp.float32)] * n
 
 
-def topk_init(scr, t_total: int) -> None:
-    """Empty running top-k: NEG scores, sentinel gids above every real tool
-    id so they lose every min-gid tie-break."""
+def _empty(col: int, floor: float) -> float:
+    """Value of an empty scratch slot in column ``col``: ``floor`` for the
+    selection score, NEG for the softmax value, 0 for per-tool rows."""
+    return floor if col == 0 else NEG if col == 1 else 0.0
+
+
+def topk_init(scr, t_total: int, floor: float = NEG) -> None:
+    """Empty running top-k: ``floor`` scores (below every score the caller
+    folds in), sentinel gids above every real id so they lose every
+    min-gid tie-break.  ``scr`` is [score, payload..., gid]."""
     shape = (QUERY_TILE, K_MAX)
-    scr[0][...] = jnp.full(shape, NEG, jnp.float32)
-    scr[1][...] = jnp.full(shape, NEG, jnp.float32)
-    for ref in scr[2:-1]:
-        ref[...] = jnp.zeros(shape, jnp.float32)
+    for col, ref in enumerate(scr[:-1]):
+        ref[...] = jnp.full(shape, _empty(col, floor), jnp.float32)
     scr[-1][...] = float(t_total) + lane_index(shape)
 
 
 def topk_merge(
-    scr, stripe_sel, stripe_val, rows, stripe_gid, *,
-    k: int, t_total: int, stripe: int,
+    scr, stripe_sel, payload, stripe_gid, *,
+    k: int, t_total: int, stripe: int, floor: float = NEG,
 ) -> None:
     """Fold one (QUERY_TILE, TS) stripe into the running top-k.
 
-    ``rows`` are the stripe's per-tool rows ([QT or 1, TS], scratch order)
-    and ``stripe_gid`` its global tool ids as f32.  The combined pool
-    (scratch + stripe) is peeled k times in (score desc, gid asc) order.
-    Gids are unique across the pool (stripes are disjoint ranges; scratch
-    holds earlier stripes' gids or sentinels), so the min-gid one-hot
-    selects exactly one entry per step."""
+    ``payload`` holds the stripe's rows that ride with each entry
+    ([QT or 1, TS], in the order of ``scr[1:-1]``: the softmax value, then
+    the per-tool rows) and ``stripe_gid`` its global ids as f32.  The
+    combined pool (scratch + stripe) is peeled k times in (score desc, gid
+    asc) order; a peeled entry retires to ``floor``, which must lie below
+    every score folded in (`topk_init`'s).  Gids are unique across the pool
+    (stripes are disjoint ranges; scratch holds earlier stripes' gids or
+    sentinels), so the min-gid one-hot selects exactly one entry per
+    step."""
     QT, TS = stripe_sel.shape
     lane = lane_index((QT, K_MAX))
 
@@ -116,8 +126,7 @@ def topk_merge(
         return jnp.concatenate([ref[...], jnp.broadcast_to(x, (QT, TS))], axis=1)
 
     comb_sel = comb(scr[0], stripe_sel)
-    comb_val = comb(scr[1], stripe_val)
-    comb_rows = [comb(ref, x) for ref, x in zip(scr[2:-1], rows)]
+    comb_pay = [comb(ref, x) for ref, x in zip(scr[1:-1], payload)]
     comb_gid = comb(scr[-1], stripe_gid)
     big = float(t_total + K_MAX + stripe)
 
@@ -127,14 +136,12 @@ def topk_merge(
         is_max = comb_sel >= m
         g = jnp.min(jnp.where(is_max, comb_gid, big), axis=-1, keepdims=True)
         onehot = comb_gid == g                               # [QT, C]
-        news.append(
-            [m] + [_pick(x, onehot) for x in [comb_val] + comb_rows] + [g]
-        )
+        news.append([m] + [_pick(x, onehot) for x in comb_pay] + [g])
         # retire the peeled entry from BOTH pools: score AND gid — leaving
-        # the gid live would let a later all-NEG tie re-pick it,
+        # the gid live would let a later all-floor tie re-pick it,
         # duplicating gids in scratch and double-counting the gid-keyed
         # one-hot sums on the next merge
-        comb_sel = jnp.where(onehot, NEG, comb_sel)
+        comb_sel = jnp.where(onehot, floor, comb_sel)
         comb_gid = jnp.where(onehot, big, comb_gid)
 
     # write the re-sorted top-k back into scratch lanes [0, k)
@@ -145,7 +152,7 @@ def topk_merge(
         return acc
 
     for col, ref in enumerate(scr[:-1]):
-        ref[...] = pack([e[col] for e in news], NEG if col < 2 else 0.0)
+        ref[...] = pack([e[col] for e in news], _empty(col, floor))
     scr[-1][...] = pack([e[-1] for e in news], float(t_total)) + jnp.where(
         lane >= float(k), lane, 0.0
     )
@@ -258,8 +265,8 @@ def _score_kernel(
             stripe_val = stripe_sel
         gid = STRIPE * j + jax.lax.broadcasted_iota(jnp.int32, (QT, TS), 1)
         topk_merge(
-            scr, stripe_sel, stripe_val,
-            [ref[...].astype(jnp.float32) for ref in row_refs],
+            scr, stripe_sel,
+            [stripe_val] + [ref[...].astype(jnp.float32) for ref in row_refs],
             gid.astype(jnp.float32), k=k, t_total=t_total, stripe=STRIPE,
         )
 

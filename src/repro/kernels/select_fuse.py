@@ -98,8 +98,7 @@ def _select_kernel(
     gid = stripe * j + jax.lax.broadcasted_iota(jnp.int32, (QT, TS), 1)
     topk_merge(
         scr, sel_ref[...].astype(jnp.float32),
-        val_ref[...].astype(jnp.float32),
-        [ref[...].astype(jnp.float32) for ref in row_refs],
+        [ref[...].astype(jnp.float32) for ref in [val_ref] + row_refs],
         gid.astype(jnp.float32), k=k, t_total=t_total, stripe=stripe,
     )
 

@@ -248,6 +248,48 @@ def test_kernel_path_parity():
     np.testing.assert_array_equal(d0.tool_idx, d1.tool_idx)
 
 
+@pytest.mark.parametrize("n_shards,shared_row", [(1, False), (3, False),
+                                                 (4, True)])
+def test_tiled_stage1_stream_matches_jnp_engine(n_shards, shared_row):
+    """Tiled SONAR-FT over 3,000 replicas with a down rack of 40: the
+    kernel path, whose stage 1 streams the template scores
+    (`ops.stage1_select`, interpret mode), decides exactly as the jnp path
+    (`lax.top_k`), and ``engine_stage1_streamed_per_call`` reads n_q x
+    replicas there and 0 on the jnp path and on a dense index."""
+    n = 3000
+    idx = TiledFleetIndex(POOL, np.arange(n) % len(POOL),
+                          weights_dtype="bfloat16")
+    rng = np.random.default_rng(n_shards)
+    tel_map = (np.arange(n) * 2654435761) % 16
+    compact = rng.uniform(5.0, 400.0, size=(16, 64)).astype(np.float32)
+    load = (rng.random(n) * 0.9).astype(np.float32)
+    texts = QUERY_TEXTS + ["find a flight to paris", "zzz qqq"]
+    mask = np.zeros((1 if shared_row else len(texts), n), bool)
+    mask[:, :40] = True
+    mask[-1, 3] = False                        # a probe re-admits one
+    if shared_row:
+        mask = mask[0]
+    cfg = RoutingConfig(top_s=8, top_k=8)
+    decs, streamed = [], []
+    for use_kernels in (False, True):
+        eng = ShardedRoutingEngine(cfg=cfg, algo="sonar_ft",
+                                   n_shards=n_shards, use_kernels=use_kernels,
+                                   interpret=True, index=idx)
+        decs.append(eng.route_texts(texts, server_load=load,
+                                    failed_mask=mask,
+                                    telemetry_templates=(compact, tel_map)))
+        streamed.append(
+            eng.registry.get("engine_stage1_streamed_per_call").value)
+    _assert_same(decs[0], decs[1], "tiled stage 1 kernel vs jnp")
+    np.testing.assert_array_equal(decs[0].expertise, decs[1].expertise)
+    assert streamed == [0.0, float(len(texts) * n)]
+    servers, hist, load, age, fmask = _materialize(3, 6, True, False, "some")
+    dense = ShardedRoutingEngine(servers, cfg, algo="sonar_ft", n_shards=2,
+                                 use_kernels=True, interpret=True)
+    dense.route_texts(QUERY_TEXTS, hist, load, age, fmask)
+    assert dense.registry.get("engine_stage1_streamed_per_call").value == 0.0
+
+
 def test_per_query_telemetry_parity():
     """Per-query telemetry slabs/loads/ages/masks shard along axis 1."""
     servers, _, _, _, _ = _materialize(2, 6, True, False, "none")

@@ -1,4 +1,4 @@
-"""The four routing kernels compile for a TPU v5e at real widths.
+"""The five routing kernels compile for a TPU v5e at real widths.
 
 Mosaic refuses what interpret mode accepts (float iotas, unaligned column
 slices, blocks that break the (8, 128) tiling, VMEM overflow), so every
@@ -15,7 +15,10 @@ Each width also comes as the engine stores it (``-aligned``): the corpus
 at the kernel's aligned shape, its real count below its rows; the compiled
 program must not pad or copy it.
 
-16 queries, V=123 terms, top_s=8, k=16.  The topology is described in a
+16 queries, V=123 terms, top_s=8, k=16.  Stage 1 over the tiled pool
+(`stage1_select`, and the engine's whole route program around it) is
+compiled at the served chunk: 8 queries, 15 templates, s_keep 8, 10^6
+replicas.  The topology is described in a
 fixture, so only the worker that runs these tests loads the TPU library;
 the persistent compile cache is off around the compiles (a compile for
 a described chip cannot be read back without one).
@@ -30,6 +33,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels import score_fuse as scf
+from repro.kernels import stage1_select as s1
 
 NQ, V, TOP_S, K = 16, 123, 8, 16
 # name -> (n_tools, n_servers, telemetry window)
@@ -188,3 +192,47 @@ def test_stripe_flags_smem_fits_large_batch(shape):
         shape((n_q, v_pad)), shape((t_pad, v_pad), BF16),
         shape((1, t_pad), I32), shape((n_q, TOP_S), I32), row,
         shape((n_q // scf.QUERY_TILE, n_st), I32))
+
+
+@pytest.mark.parametrize("dead_rows", [8, 1], ids=["per-query", "shared"])
+def test_stage1_select_compiles(shape, dead_rows):
+    """Stage 1 over the 10^6-replica tiled shard: the template map stored
+    at the kernel's width, the dead row at the engine's (unaligned)."""
+    n = WIDTHS["mega"][1]
+    _assert_kernel(
+        lambda s, m, d: ops.stage1_select(s, m, d, k=8, interpret=False),
+        shape((8, 15)), shape((1, 1, s1.map_width(n)), I32),
+        shape((1, dead_rows, n)))
+
+
+def test_tiled_route_sorts_no_replica_row(shape):
+    """The pool's route program (SONAR-FT over the tiled 10^6-replica
+    index, the sharded engine's kernel path, one 8-query chunk with its
+    health row) runs stage 1 in `stage1_select` and holds no sort with a
+    dimension of 10^5 or more."""
+    import numpy as np
+
+    from repro.core.mesh_routing import ShardedRoutingEngine
+    from repro.core.routing import RoutingConfig
+    from repro.traffic import fleet
+
+    n = WIDTHS["mega"][1]
+    eng = ShardedRoutingEngine(
+        cfg=RoutingConfig(top_s=8, top_k=8), algo="sonar_ft",
+        index=fleet.mega_fleet_index(n, weights_dtype="bfloat16"),
+        use_kernels=True, interpret=False,
+    )
+    fn, args, kw = eng._program(
+        eng.encode(["search the web for the latest news"] * 8),
+        server_load=np.zeros(n, np.float32),
+        failed_mask=np.zeros((8, n), bool),
+        telemetry_templates=(np.zeros((16, 64), np.float32),
+                             np.arange(n) % 16),
+    )
+    args = jax.tree.map(lambda a: shape(a.shape, a.dtype), args)
+    text = fn.lower(*args, **kw).compile().as_text()
+    assert re.search(r"%stage1_select\S* = .* custom-call\(", text)
+    sorts = re.findall(r"= \(?([^=]*?)\)? sort\(", text)
+    big = [s for s in sorts
+           if any(int(d) >= 100_000 for d in re.findall(r"\d+", s))]
+    assert not big, big
